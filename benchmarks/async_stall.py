@@ -32,13 +32,11 @@ def main() -> None:
     from bench_utils import report
 
     import jax
-
-    # The ambient environment may have pre-imported jax pointed at an
-    # experimental TPU platform; the env var alone is too late by then —
-    # re-apply it through jax.config (takes effect at backend init).
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax.numpy as jnp
+
+    from torchsnapshot_tpu.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     from torchsnapshot_tpu import Snapshot, StateDict
     from torchsnapshot_tpu.models import transformer as T

@@ -28,6 +28,10 @@ def main() -> None:
     if args.cpu_devices:
         force_cpu_devices(args.cpu_devices)
     import jax
+
+    from torchsnapshot_tpu.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot, StateDict

@@ -57,8 +57,8 @@ from bench_utils import report  # noqa: E402
 THROTTLE_BPS = 50e6
 
 # Sustained-overhead budget used to EXPRESS costs as RPO seconds. The
-# ratio is budget-independent; 1% is the fleet-typical checkpoint
-# overhead BENCHMARKS.md quotes.
+# ratio is budget-independent; 1% is a fleet-typical checkpoint
+# overhead.
 OVERHEAD_BUDGET = 0.01
 FULL_TRIALS = 2
 EPOCH_TRIALS = 3

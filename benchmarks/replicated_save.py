@@ -33,6 +33,10 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from torchsnapshot_tpu.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
+
     from torchsnapshot_tpu import Snapshot, StateDict
 
     per_param = int(args.gb * 1e9) // args.params

@@ -23,10 +23,11 @@ def main() -> None:
     from bench_utils import report
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax.numpy as jnp
+
+    from torchsnapshot_tpu.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     gib = float(sys.argv[1]) if len(sys.argv) > 1 else 1.0
     total = int(gib * (1 << 30))

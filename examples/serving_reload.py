@@ -40,9 +40,9 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 import jax
 
-from _example_utils import force_cpu_if_requested
+from torchsnapshot_tpu.compile_cache import enable_compilation_cache
 
-force_cpu_if_requested()
+enable_compilation_cache()
 
 import jax.numpy as jnp
 import numpy as np

@@ -23,15 +23,13 @@ if "--cpu-devices" in sys.argv:
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={_n}"
     )
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 
-from _example_utils import force_cpu_if_requested
+from torchsnapshot_tpu.compile_cache import enable_compilation_cache
 
-force_cpu_if_requested()
+enable_compilation_cache()
 
 import numpy as np
 

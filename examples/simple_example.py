@@ -18,9 +18,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-from _example_utils import force_cpu_if_requested
+from torchsnapshot_tpu.compile_cache import enable_compilation_cache
 
-force_cpu_if_requested()
+enable_compilation_cache()
 import jax.numpy as jnp
 import numpy as np
 import optax

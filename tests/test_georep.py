@@ -426,6 +426,33 @@ def test_enqueue_coalesces_keeping_oldest_timestamp(replicated):
         rep.close(0)
 
 
+def test_commit_that_races_a_sync_is_shipped_not_retired(replicated, monkeypatch):
+    """An epoch committed while its step is being synced keeps the step
+    pending (the coalesced entry keeps its OLD timestamp, so the
+    timestamp cannot show the race): drain may not report idle until a
+    second sync has covered it."""
+    root, remote = replicated
+    del root, remote
+    rep = georep.GeoReplicator("/nonexistent/remote", interval=3600.0)
+    syncs = []
+
+    def sync_step(path, step):
+        syncs.append(step)
+        if len(syncs) == 1:
+            rep.enqueue(path, step)  # the racing commit
+        return {"epoch": len(syncs)}
+
+    monkeypatch.setattr(rep, "_sync_step", sync_step)
+    monkeypatch.setattr(rep, "_publish_gauges", lambda: None)
+    try:
+        rep.enqueue("/primary/step_0000000001", 1)
+        assert rep.drain(timeout=30.0)
+        assert syncs == [1, 1]
+        assert not rep._pending and not rep._tickets
+    finally:
+        rep.close(0)
+
+
 def test_preemption_consume_drains_the_shipper(replicated):
     """The grace window: consume() runs the registered bounded drain so
     the final flushed epoch reaches the remote tier before teardown."""

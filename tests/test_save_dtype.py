@@ -189,9 +189,9 @@ def test_warmup_sizes_follow_save_dtype():
     real save misses the exact-size free list entirely."""
     from torchsnapshot_tpu.io_preparers import array as array_mod
 
-    if not array_mod._BUFFER_PROTOCOL_OK or not __import__(
-        "torchsnapshot_tpu._native", fromlist=["native_available"]
-    ).native_available():
+    from torchsnapshot_tpu._native import native_available
+
+    if not native_available():
         pytest.skip("staging pool inactive on this host")
 
     state = {"m": StateDict(w=np.ones(100_000, np.float32))}

@@ -388,6 +388,7 @@ def _device_consumer(arr, entry):
     sharding = SingleDeviceSharding(jax.devices()[0])
     dest = DeviceMaterializer(
         sharding=sharding,
+        committed=True,
         dst_dtype=arr.dtype,
         needs_cast=False,
         callback=restored.append,

@@ -530,8 +530,7 @@ def test_retry_strategy_emits_events_and_enriches_exception():
     assert exc.retry_backoff_slept_s == pytest.approx(slept, abs=0.01)
     assert exc.retry_fleet_attempts == 2
     assert len(sleeps) == 2
-    if sys.version_info >= (3, 11):
-        assert any("gave up after 3 attempt" in n for n in exc.__notes__)
+    assert any("gave up after 3 attempt" in n for n in exc.__notes__)
     events = [e for e in telemetry.events() if e["cat"] == "retry"]
     kinds = [e["name"] for e in events]
     assert kinds.count("storage_retry") == 2

@@ -23,42 +23,59 @@ tests and the CI native-absent leg to cover both paths).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
 import threading
 import uuid
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 logger = logging.getLogger(__name__)
 
 DISABLE_NATIVE_ENV_VAR = "TORCHSNAPSHOT_TPU_DISABLE_NATIVE"
 
 _SRC = os.path.join(os.path.dirname(__file__), "native.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "_ts_native.so")
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17", "-msse4.2")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 _load_lock = threading.Lock()
+# What _try_load did, for entry points that report it (chip_smoke.py).
+_build_info: Dict[str, Any] = {"so": None, "compiled_now": False}
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The cached build's path, keyed on the CONTENT of native.cpp and the
+    compile flags. ``*.so`` is git-ignored and copies of the tree do not
+    keep mtimes, so a binary is trusted only under the name its source
+    hashes to: a stale or foreign ``.so`` is never loaded, the matching
+    one is simply built again."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    # tsalint: allow[restricted-context] unreachable from UringEngine.__del__ in practice: an engine only exists after the lib loaded, so _load_attempted is True and _load's fast path returns before _try_load can be reached
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(
+        os.path.dirname(_SRC), f"_ts_native.{h.hexdigest()[:16]}.so"
+    )
+
+
+def _build(so: str) -> bool:
     # Compile to a unique temp path (first use can race across executor
     # THREADS of one process as well as across processes — pid alone is not
     # unique enough) and publish atomically with os.replace: a CDLL() must
     # never observe a half-written .so.
-    tmp = f"{_SO}.{os.getpid()}.{uuid.uuid4().hex[:8]}.tmp"
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-msse4.2",
-        _SRC, "-o", tmp,
-    ]
+    tmp = f"{so}.{os.getpid()}.{uuid.uuid4().hex[:8]}.tmp"
+    cmd = ["g++", *_CXXFLAGS, _SRC, "-o", tmp]
     try:
         # tsalint: allow[restricted-context] unreachable from UringEngine.__del__ in practice: an engine only exists after the lib loaded, so _load_attempted is True and _load's fast path returns before _build can be reached
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
         return True
     except (OSError, subprocess.SubprocessError) as e:
-        logger.info("native extension build failed (%s); using Python fallbacks", e)
+        logger.warning(
+            "native extension build failed (%s); using Python fallbacks", e
+        )
         try:
             os.unlink(tmp)
         except OSError:
@@ -95,14 +112,19 @@ def _load_locked() -> Optional[ctypes.CDLL]:
 def _try_load() -> Optional[ctypes.CDLL]:
     if os.environ.get(DISABLE_NATIVE_ENV_VAR, "0") not in ("0", "", "false"):
         return None
-    fresh = os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-    if not fresh and not _build():
-        return None
+    so = _so_path()
+    if not os.path.exists(so):
+        if not _build(so):
+            return None
+        _build_info["compiled_now"] = True
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
     except OSError as e:  # pragma: no cover
-        logger.info("native extension load failed (%s); using Python fallbacks", e)
+        logger.warning(
+            "native extension load failed (%s); using Python fallbacks", e
+        )
         return None
+    _build_info["so"] = so
     lib.ts_crc32c.restype = ctypes.c_uint32
     lib.ts_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32]
     lib.ts_has_hw_crc.restype = ctypes.c_int
@@ -148,6 +170,20 @@ def _try_load() -> Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def build_info() -> Dict[str, Any]:
+    """How the extension got here: ``available``; ``built_from_source``
+    (the loaded binary is the one native.cpp + the compile flags hash to,
+    the only kind the loader accepts); ``compiled_now`` (this process ran
+    g++ rather than finding that binary cached); ``so`` (its path)."""
+    available = native_available()
+    return {
+        "available": available,
+        "built_from_source": available,
+        "compiled_now": _build_info["compiled_now"],
+        "so": _build_info["so"],
+    }
 
 
 # ------------------------------------------------------------------ crc32c

@@ -404,9 +404,8 @@ def fingerprints_match(
     their logical size, and must say so or a window of them would
     transiently reach ~2x the documented bound. A window of slices is
     dispatched together before the first 16-byte fetch — ~one
-    host<->device roundtrip per window, not per slice (the roundtrip,
-    not the hash, dominates for small/medium slices on tunneled links) —
-    then the slice references are dropped before the next window
+    host<->device roundtrip per window, not per slice — then the slice
+    references are dropped before the next window
     materializes. A window closes at ``window`` slices or before the
     slice that would push it past ``window_bytes`` of COST (a single
     over-budget slice still goes alone); the budget check runs BEFORE

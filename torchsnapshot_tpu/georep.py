@@ -504,6 +504,11 @@ class GeoReplicator:
         self._lock = threading.Lock()
         #: step -> (primary_path, oldest un-shipped commit, monotonic)
         self._pending: Dict[int, Tuple[str, float]] = {}
+        # Ticket of the newest enqueue of each pending step. The
+        # timestamp cannot tell the daemon that a commit raced its sync:
+        # coalescing keeps the OLDEST one.
+        self._tickets: Dict[int, int] = {}
+        self._last_ticket = 0
         self._wake = threading.Event()
         self._idle = threading.Event()
         self._idle.set()
@@ -531,11 +536,14 @@ class GeoReplicator:
         with self._lock:
             prev = self._pending.get(step)
             self._pending[step] = (primary_path, prev[1] if prev else now)
+            self._last_ticket += 1
+            self._tickets[step] = self._last_ticket
             while len(self._pending) > self.backlog_limit:
                 victim = min(self._pending)
                 if victim == step and len(self._pending) == 1:
                     break
                 self._pending.pop(victim, None)
+                self._tickets.pop(victim, None)
                 self.dropped_steps += 1
                 telemetry.counter_add("georep_steps_dropped", 1)
             self._idle.clear()
@@ -614,7 +622,8 @@ class GeoReplicator:
                     self._idle.set()
                     break
                 step = min(self._pending)
-                path, enq_ts = self._pending[step]
+                path, _enq_ts = self._pending[step]
+                ticket = self._tickets.get(step)
             try:
                 cursor = self._sync_step(path, step)
             except Exception as e:  # noqa: BLE001
@@ -639,10 +648,11 @@ class GeoReplicator:
             self.last_error = None
             with self._lock:
                 self._synced[step] = cursor
-                # A commit that raced the sync re-stamped the entry;
+                # A commit that raced the sync enqueued the step again;
                 # only retire the task if nothing new arrived.
-                if self._pending.get(step, (None, None))[1] == enq_ts:
+                if self._tickets.get(step) == ticket:
                     self._pending.pop(step, None)
+                    self._tickets.pop(step, None)
             self._publish_gauges()
         self._publish_gauges()
 

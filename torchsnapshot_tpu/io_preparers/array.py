@@ -20,7 +20,6 @@ import contextvars
 import ctypes
 import logging
 import os
-import sys
 import threading
 import weakref
 from collections import deque
@@ -89,12 +88,6 @@ def streaming_enabled() -> bool:
     return os.environ.get(STREAM_WRITES_ENV_VAR, "1") not in ("0", "false", "")
 
 
-# Pure-Python buffer exporters (__buffer__) are honored from CPython 3.12
-# (PEP 688); earlier interpreters cannot express the holder pattern below,
-# so they skip pooling entirely — correctness over recycling.
-_BUFFER_PROTOCOL_OK = sys.version_info >= (3, 12)
-
-
 class _SlabHolder:
     """Weakref-able buffer exporter that owns a pooled slab (PEP 688).
 
@@ -141,10 +134,8 @@ class _StagingPool:
     pinned allocator: page-aligned for O_DIRECT/io_uring, pre-faulted
     deterministically at allocation — never lazily inside a timed
     staging copy — THP-hinted, mlock'd best-effort), recycled through a
-    ``from_address`` ctypes holder, which works on every supported
-    interpreter. The PEP 688 ``_SlabHolder`` path remains for
-    native-absent 3.12+ hosts; pre-3.12 without the extension degrades
-    to unpooled ``np.empty``. Pool traffic is published to the
+    ``from_address`` ctypes holder. The PEP 688 ``_SlabHolder`` path
+    remains for native-absent hosts. Pool traffic is published to the
     telemetry bus (``staging_pool_hits``/``_misses`` counters,
     ``staging_pool_free_bytes``/``_outstanding_bytes`` gauges) for
     ``stats`` and the ``/metrics`` exporter."""
@@ -297,8 +288,6 @@ class _StagingPool:
     # --------------------------------------------------- PEP 688 path
 
     def _get_py(self, nbytes: int) -> np.ndarray:
-        if not _BUFFER_PROTOCOL_OK:
-            return np.empty(nbytes, np.uint8)
         with self._lock:
             slabs = self._free.get(nbytes)
             base = slabs.pop() if slabs else None
@@ -341,11 +330,6 @@ class _StagingPool:
 
     # ---------------------------------------------------------- warmup
 
-    def can_recycle(self) -> bool:
-        """True when ``get`` actually draws from (and returns to) the
-        free lists — native slabs anywhere, PEP 688 holders on 3.12+."""
-        return self._native_ok() or _BUFFER_PROTOCOL_OK
-
     def prewarm(self, sizes: Sequence[int]) -> int:
         """Pre-fault slabs so the FIRST staging pass doesn't pay them.
 
@@ -361,8 +345,6 @@ class _StagingPool:
 
         self._integrate_deferred()
         native = self._native_ok()
-        if not native and not _BUFFER_PROTOCOL_OK:
-            return 0  # pool is never drawn from: warming would pin waste
         want = Counter(
             int(s)
             for s in sizes
@@ -576,9 +558,7 @@ def warmup_staging(app_state, pg=None, replicated=None, save_dtype=None) -> int:
 
     No-op (returns 0) whenever staging cannot draw from the pool: the
     pool feeds only the fused copy+CRC path (``_stage_fused``), which
-    needs the native extension (whose pinned slab allocator also makes
-    the pool recycle on every interpreter — the PEP 688 holder covers
-    native-absent 3.12+ hosts) and checksums enabled — warming slabs no
+    needs the native extension and checksums enabled — warming slabs no
     save will ever draw would pin pool-limit bytes for nothing. Dedup
     (incremental) and compression also bypass the pool;
     CheckpointManager.warmup checks those, since they are its
@@ -602,11 +582,7 @@ def warmup_staging(app_state, pg=None, replicated=None, save_dtype=None) -> int:
     from .._native import native_available
     from ..integrity import checksums_enabled
 
-    if (
-        not _staging_pool.can_recycle()
-        or not native_available()
-        or not checksums_enabled()
-    ):
+    if not native_available() or not checksums_enabled():
         return 0
 
     sizes: List[int] = [
@@ -989,10 +965,9 @@ class ArrayBufferStager(BufferStager):
 
         def _kick(lo: int, hi: int):
             piece = arr[lo:hi]
-            try:
-                piece.copy_to_host_async()
-            except Exception:
-                pass
+            # A kick that fails must raise: swallowed, the stream would
+            # silently lose its one-slice-ahead DMA and run serial.
+            piece.copy_to_host_async()
             return piece
 
         def _materialize(piece, st):
@@ -1047,10 +1022,9 @@ class ArrayBufferStager(BufferStager):
                 # roundtrip ever sits ahead of the staging copy.
                 record_fp = True
         if _is_jax_array(arr):
-            try:
-                arr.copy_to_host_async()  # kick off the DMA before blocking
-            except Exception:
-                pass
+            # Kick off the DMA before blocking. No except: a failed kick
+            # would silently turn the overlapped DtoH into a serial one.
+            arr.copy_to_host_async()
         pending_fp = None
         if record_fp:
             from ..device_digest import _dispatch
@@ -1079,6 +1053,8 @@ class DeviceMaterializer:
     and the host never holds more than the in-flight window."""
 
     sharding: object
+    # False: the destination was uncommitted, and so is what lands.
+    committed: bool
     dst_dtype: object
     needs_cast: bool
     callback: Optional[Callable]
@@ -1157,7 +1133,7 @@ class _DeviceRowSink:
         block = np.frombuffer(
             src, dtype=self.np_dtype, count=rows * self.row_elems
         ).reshape((rows,) + self.shape[1:])
-        if self._device is None:
+        if self._device is None and self.dest.committed:
             self._device = next(iter(self.dest.sharding.device_set))
         # device_put returns immediately (transfer proceeds in the
         # background) and `src` stays alive through the block's buffer
@@ -1185,7 +1161,9 @@ class _DeviceRowSink:
             self.blocks, axis=0
         )
         self.blocks = []
-        restored = jax.device_put(full, self.dest.sharding)
+        restored = (
+            jax.device_put(full, self.dest.sharding) if self.dest.committed else full
+        )
         if self.dest.needs_cast:
             restored = restored.astype(self.dest.dst_dtype)
         if self.dest.callback is not None:

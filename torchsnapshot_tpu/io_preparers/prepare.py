@@ -258,6 +258,13 @@ def prepare_read(
                 f"{list(entry.shape)}, destination has {list(obj_out.shape)}."
             )
         sharding = obj_out.sharding
+        # The destination is the spec, committed-ness included: a
+        # destination left uncommitted (plain jnp creation on the default
+        # device) comes back uncommitted. A committed copy makes the
+        # caller's jitted step lower with explicit argument shardings — a
+        # different module from the one the first run compiled, so every
+        # resume would miss the persistent compile cache.
+        committed = obj_out.committed
         needs_cast = check_restore_cast(
             entry.dtype, obj_out.dtype, "into jax.Array"
         )
@@ -270,8 +277,12 @@ def prepare_read(
         # DEVICE after the transfer: the wire moves the snapshot's (often
         # narrower) bytes and the VPU does the widening, not the host.
 
-        def _materialize(host: np.ndarray, _cb=callback, _sharding=sharding) -> None:
-            restored = jax.device_put(host, _sharding)
+        def _materialize(
+            host: np.ndarray,
+            _cb=callback,
+            _placement=sharding if committed else None,
+        ) -> None:
+            restored = jax.device_put(host, _placement)
             if needs_cast:
                 restored = restored.astype(dst_dtype)
             if _cb is not None:
@@ -287,6 +298,7 @@ def prepare_read(
 
         device_dest = DeviceMaterializer(
             sharding=sharding,
+            committed=committed,
             dst_dtype=dst_dtype,
             needs_cast=needs_cast,
             callback=callback,

@@ -157,6 +157,77 @@ def _flash_mesh_ok(cfg: TransformerConfig, mesh: Mesh, B: int, S: int) -> bool:
     return S > 0 and pick_block_size(S, 512) is not None
 
 
+_ATTN_IMPLS = ("auto", "dense", "blockwise", "flash", "ring", "zigzag", "ulysses")
+_CP_SELECTIONS = ("ring", "ring_flash", "zigzag", "zigzag_flash", "ulysses")
+
+
+def select_attention(
+    cfg: TransformerConfig, mesh: Optional[Mesh], B: int, S: int
+) -> str:
+    """The attention path ``forward`` runs for this config, mesh and shape.
+
+    ``cfg.attn_impl`` is a request; what runs also depends on things the
+    code observes (backend, mesh axes, whether S tiles), and a request
+    that cannot be met gives way to the next-best path. This function is
+    that whole decision, so callers (chip_smoke.py, tests) can read what
+    was selected instead of inferring it from the config string. Returns
+    one of ``dense``, ``blockwise``, ``flash`` (bare Pallas kernel),
+    ``flash_sharded`` (the kernel shard_mapped over batch/heads),
+    ``ring``, ``ring_flash``, ``zigzag``, ``zigzag_flash``, ``ulysses``.
+    """
+    from ..ops.attention import pick_block_size
+
+    c = cfg
+    if c.attn_impl not in _ATTN_IMPLS:
+        raise ValueError(f"unknown attn_impl {c.attn_impl!r}")
+    on_tpu = jax.default_backend() == "tpu"
+    impl = c.attn_impl
+    if impl == "auto":
+        # Backend-aware kernel choice: the Pallas flash kernel on TPU —
+        # bare on a single device, shard_mapped over batch/heads under a
+        # mesh when the preconditions hold (_flash_mesh_ok; a bare
+        # pallas_call has no partitioning rule, so it must never see
+        # sharded operands); blockwise once S outgrows one block
+        # (O(S*block) memory); dense for short sequences. Never selects a
+        # cp impl — ring/zigzag/ulysses are mesh topology decisions for
+        # the caller.
+        if on_tpu and (mesh is None or _flash_mesh_ok(c, mesh, B, S)):
+            impl = "flash"
+        elif S > c.attn_block_size:
+            impl = "blockwise"
+        else:
+            impl = "dense"
+    if impl in ("ring", "zigzag", "ulysses"):
+        if mesh is None:
+            # Single-device run of a cp-configured model: same math, no
+            # axis to communicate over.
+            return "dense"
+        if "seq" not in mesh.axis_names:
+            raise ValueError(
+                f"attn_impl={impl!r} needs a mesh with a 'seq' axis; got "
+                f"{mesh.axis_names}. Build one via make_mesh({{'data': ..., "
+                f"'seq': ..., 'model': ...}})."
+            )
+        if impl == "ulysses":
+            return impl
+        # The ring's inner compute dominates long-context cost; run it
+        # through the Pallas kernel when the LOCAL shard (the half-shard
+        # for zigzag) satisfies the flash preconditions.
+        parts = mesh.shape["seq"] * (2 if impl == "zigzag" else 1)
+        if on_tpu and S % parts == 0 and _flash_mesh_ok(c, mesh, B, S // parts):
+            return impl + "_flash"
+        return impl
+    if impl in ("blockwise", "flash"):
+        if pick_block_size(S, c.attn_block_size) is None:
+            return "dense"
+        if impl == "flash" and mesh is not None:
+            # Under a mesh the bare pallas_call would make GSPMD gather
+            # the sharded operands; shard_map the kernel instead, or give
+            # way to blockwise when the preconditions don't hold.
+            return "flash_sharded" if _flash_mesh_ok(c, mesh, B, S) else "blockwise"
+    return impl
+
+
 def forward(
     params: Params,
     tokens: jax.Array,
@@ -180,40 +251,12 @@ def forward(
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
-    impls = ("auto", "dense", "blockwise", "flash", "ring", "zigzag", "ulysses")
-    if c.attn_impl not in impls:
-        raise ValueError(f"unknown attn_impl {c.attn_impl!r}")
-    if c.attn_impl == "auto":
-        # Backend-aware kernel choice: the Pallas flash kernel on TPU
-        # (11.7x over the blockwise scan fwd+bwd, measured) — bare on a
-        # single device, shard_mapped over batch/heads under a mesh when
-        # the preconditions hold (_flash_mesh_ok; a bare pallas_call has
-        # no partitioning rule, so it must never see sharded operands);
-        # blockwise once S outgrows one block (O(S*block) memory); dense
-        # for short sequences. Never selects a cp impl — ring/zigzag/
-        # ulysses are mesh topology decisions for the caller.
-        if jax.default_backend() == "tpu" and (
-            mesh is None or _flash_mesh_ok(c, mesh, B, S)
-        ):
-            impl = "flash"
-        elif S > c.attn_block_size:
-            impl = "blockwise"
-        else:
-            impl = "dense"
-        c = dataclasses.replace(c, attn_impl=impl)
+    impl = select_attention(c, mesh, B, S)
     # cp (ring/ulysses) keeps the sequence dim sharded over 'seq' end-to-end;
     # the Megatron-sp fallback seq-shards the residual over the tp axis
     # instead and gathers around attention/ffn.
     has_seq = mesh is not None and "seq" in mesh.axis_names
-    if c.attn_impl in ("ring", "zigzag", "ulysses") and mesh is not None and not has_seq:
-        raise ValueError(
-            f"attn_impl={c.attn_impl!r} needs a mesh with a 'seq' axis; got "
-            f"{mesh.axis_names}. Build one via make_mesh({{'data': ..., "
-            f"'seq': ..., 'model': ...}})."
-        )
-    # mesh=None (single-device run of a cp-configured model) falls back to
-    # dense attention — same math, no axis to communicate over.
-    cp = c.attn_impl in ("ring", "zigzag", "ulysses") and has_seq
+    cp = impl in _CP_SELECTIONS
     res_seq_ax = "seq" if has_seq else "model"  # residual-stream seq sharding
     act_seq_ax = "seq" if cp else None  # in-block activation seq sharding
 
@@ -234,8 +277,7 @@ def forward(
     # whose capacity overflow drops tokens in token order — hoisting would
     # make training numerics depend on the parallelism layout. MoE configs
     # therefore keep the per-layer permuting wrapper.
-    zz = cp and c.attn_impl == "zigzag"
-    zz_hoist = zz and c.n_experts == 0
+    zz_hoist = impl in ("zigzag", "zigzag_flash") and c.n_experts == 0
     if zz_hoist:
         from ..ops.ring_attention import zigzag_layout_indices
 
@@ -245,83 +287,45 @@ def forward(
 
     def attention(q, k, v):
         # q, k, v: (B, S, H, hd) — logical shapes; sharding via constraints.
-        if cp:
-            if c.attn_impl == "ulysses":
-                from ..ops.ulysses import ulysses_attention_sharded
+        from ..ops import attention as A
 
-                return ulysses_attention_sharded(
-                    q, k, v, mesh, causal=True,
-                    inner_block_size=c.attn_block_size,
-                )
-            if c.attn_impl == "zigzag":
-                ring_size = mesh.shape["seq"]
-                # Half-shard length is the zigzag kernels' tile unit.
-                if (
-                    jax.default_backend() == "tpu"
-                    and S % (2 * ring_size) == 0
-                    and _flash_mesh_ok(c, mesh, B, S // (2 * ring_size))
-                ):
-                    from ..ops.ring_flash import (
-                        zigzag_ring_flash_attention_sharded,
-                    )
+        if impl == "ulysses":
+            from ..ops.ulysses import ulysses_attention_sharded
 
-                    return zigzag_ring_flash_attention_sharded(
-                        q, k, v, mesh, in_layout=zz_hoist
-                    )
-                from ..ops.ring_attention import zigzag_ring_attention_sharded
+            return ulysses_attention_sharded(
+                q, k, v, mesh, causal=True, inner_block_size=c.attn_block_size
+            )
+        if impl == "zigzag_flash":
+            from ..ops.ring_flash import zigzag_ring_flash_attention_sharded
 
-                return zigzag_ring_attention_sharded(
-                    q, k, v, mesh, in_layout=zz_hoist
-                )
-            if c.attn_impl == "ring" and jax.default_backend() == "tpu":
-                # The ring's inner compute dominates long-context cost;
-                # run it through the Pallas flash kernel when the LOCAL
-                # shard satisfies the same preconditions as the non-ring
-                # flash path.
-                ring_size = mesh.shape["seq"]
-                if S % ring_size == 0 and _flash_mesh_ok(
-                    c, mesh, B, S // ring_size
-                ):
-                    from ..ops.ring_flash import ring_flash_attention_sharded
+            return zigzag_ring_flash_attention_sharded(
+                q, k, v, mesh, in_layout=zz_hoist
+            )
+        if impl == "zigzag":
+            from ..ops.ring_attention import zigzag_ring_attention_sharded
 
-                    return ring_flash_attention_sharded(
-                        q, k, v, mesh, causal=True
-                    )
+            return zigzag_ring_attention_sharded(q, k, v, mesh, in_layout=zz_hoist)
+        if impl == "ring_flash":
+            from ..ops.ring_flash import ring_flash_attention_sharded
+
+            return ring_flash_attention_sharded(q, k, v, mesh, causal=True)
+        if impl == "ring":
             from ..ops.ring_attention import ring_attention_sharded
 
             return ring_attention_sharded(q, k, v, mesh, causal=True)
-        if c.attn_impl in ("blockwise", "flash"):
-            from ..ops.attention import pick_block_size
+        if impl == "flash_sharded":
+            from ..ops.pallas_attention import flash_attention_sharded
 
-            bs = pick_block_size(S, c.attn_block_size)
-            if bs is not None:
-                if c.attn_impl == "flash":
-                    if mesh is not None:
-                        # Under a mesh the bare pallas_call would make
-                        # GSPMD gather the sharded operands; shard_map the
-                        # kernel over batch/heads instead (attention is
-                        # embarrassingly parallel there). Falls through to
-                        # blockwise when the preconditions don't hold.
-                        if _flash_mesh_ok(c, mesh, B, S):
-                            from ..ops.pallas_attention import (
-                                flash_attention_sharded,
-                            )
+            return flash_attention_sharded(q, k, v, mesh, causal=True)
+        if impl == "flash":
+            from ..ops.pallas_attention import flash_attention
 
-                            return flash_attention_sharded(
-                                q, k, v, mesh, causal=True
-                            )
-                    else:
-                        from ..ops.pallas_attention import flash_attention
-
-                        return flash_attention(
-                            q, k, v, causal=True, block_q=bs, block_k=bs
-                        )
-                from ..ops.attention import blockwise_attention
-
-                return blockwise_attention(q, k, v, block_size=bs, causal=True)
-        from ..ops.attention import dense_attention
-
-        return dense_attention(q, k, v, causal=True)
+            bs = A.pick_block_size(S, c.attn_block_size)
+            return flash_attention(q, k, v, causal=True, block_q=bs, block_k=bs)
+        if impl == "blockwise":
+            bs = A.pick_block_size(S, c.attn_block_size)
+            return A.blockwise_attention(q, k, v, block_size=bs, causal=True)
+        return A.dense_attention(q, k, v, causal=True)
 
     def block(carry, layer):
         x, aux = carry
@@ -411,11 +415,25 @@ def make_train_step(
         )
         updates, opt_state = tx.update(grads, state["opt_state"], state["params"])
         params = optax.apply_updates(state["params"], updates)
-        return {
+        new_state = {
             "params": params,
             "opt_state": opt_state,
             "step": state["step"] + 1,
-        }, loss
+        }
+        if mesh is not None:
+            # Pin the state the step returns to the layout init_state
+            # placed it in. Left to GSPMD, replicated leaves come back
+            # sharded (ln_f_scale over 'model'): step 2 then recompiles
+            # for the drifted input, and what gets saved is no longer
+            # the layout state_specs declares.
+            new_state = jax.tree_util.tree_map(
+                lambda x, spec: jax.lax.with_sharding_constraint(
+                    x, NamedSharding(mesh, spec)
+                ),
+                new_state,
+                state_specs(cfg, new_state),
+            )
+        return new_state, loss
 
     return train_step
 

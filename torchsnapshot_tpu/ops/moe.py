@@ -259,19 +259,6 @@ def moe_ffn_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    import inspect
-
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    # The replication-check kwarg was renamed check_rep -> check_vma.
-    _check_kwarg = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
-
     n_dev = mesh.shape[expert_axis]
     E = params["router"].shape[1]
     if E % n_dev:
@@ -312,11 +299,11 @@ def moe_ffn_sharded(
         aux = (jnp.sum(frac_tokens * frac_probs) * E).astype(jnp.float32)
         return y_l, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(param_specs, P(expert_axis, None)),
         out_specs=(P(expert_axis, None), P()),
-        **{_check_kwarg: False},
+        check_vma=False,
     )(params, x)
     return y, aux

@@ -38,7 +38,7 @@ def _ring_acc_init(q: jax.Array, axis_name: str):
     plus the ring axis (masks depend on ``axis_index``); shard_map tracks
     this in the type system, so the initializers must declare it.
     """
-    vma = getattr(jax.typeof(q), "vma", frozenset())
+    vma = jax.typeof(q).vma
     qv = q if axis_name in vma else jax.lax.pcast(q, (axis_name,), to="varying")
     qz = qv.astype(jnp.float32) * 0.0
     zrow = qz[..., 0].transpose(0, 2, 1)  # (B, H, S) of zeros

@@ -3,7 +3,8 @@
 ``ring_attention.py`` rotates K/V shards around a mesh axis and merges
 online-softmax statistics with a pure-JAX block update. That inner
 compute is the hot loop of long-context training, and the Pallas flash
-kernel runs it ~10× faster on TPU (BENCHMARKS.md). This module fuses the
+kernel is the TPU path for it (speed on the chip: not measured,
+ROADMAP A5). This module fuses the
 two: each ring hop runs the flash kernel on the resident Q shard against
 the currently-held K/V shard, and hops are merged by their log-sum-exp
 statistics — o = Σ exp(lse_i − m)·o_i / Σ exp(lse_i − m), the exact
@@ -55,7 +56,7 @@ def _merge(o, lse, o_s, lse_s):
 
 
 def _varying(x, axis_name: str):
-    vma = getattr(jax.typeof(x), "vma", frozenset())
+    vma = jax.typeof(x).vma
     return x if axis_name in vma else lax.pcast(x, (axis_name,), to="varying")
 
 

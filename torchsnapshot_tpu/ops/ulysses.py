@@ -30,9 +30,9 @@ from .attention import blockwise_attention, dense_attention, pick_block_size
 
 
 def _resolve_inner(inner: str) -> str:
-    """inner="auto" picks the Pallas flash kernel on TPU (measured 11.7x
-    over the blockwise path fwd+bwd on a v5e) and the pure-JAX blockwise
-    scan elsewhere (flash would run in slow interpret mode off-TPU)."""
+    """inner="auto" picks the Pallas flash kernel on TPU and the pure-JAX
+    blockwise scan elsewhere (flash would run in slow interpret mode
+    off-TPU)."""
     if inner != "auto":
         return inner
     return "flash" if jax.default_backend() == "tpu" else "blockwise"

@@ -42,7 +42,7 @@ def _varying_over(axes):
     outputs will have (ppermute/axis_index make carries varying)."""
 
     def cast(v):
-        vma = getattr(jax.typeof(v), "vma", frozenset())
+        vma = jax.typeof(v).vma
         missing = tuple(a for a in axes if a not in vma)
         if missing:
             return jax.lax.pcast(v, missing, to="varying")

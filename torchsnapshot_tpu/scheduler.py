@@ -1113,6 +1113,10 @@ class _ProgressReporter:
         if self._task is not None:
             self._task.cancel()
             self._task = None
+            # One last table: the heartbeat and the log end on the final
+            # counts, not on those of whichever tick happened to land
+            # (none at all, when the loop was busy for the whole phase).
+            self.log_table()
 
     async def _loop(self) -> None:
         try:

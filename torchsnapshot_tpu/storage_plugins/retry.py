@@ -205,8 +205,8 @@ def attach_retry_history(
 
     The original exception object (and type) is preserved — callers
     catching transport-specific exceptions keep working — with the
-    history attached as attributes and (Python 3.11+) a ``__notes__``
-    line, so a post-mortem shows how hard the fleet tried before the
+    history attached as attributes and a ``__notes__`` line, so a
+    post-mortem shows how hard the fleet tried before the
     shared deadline gave up."""
     exc.retry_attempts = attempts
     exc.retry_error_kind = kind
@@ -219,12 +219,10 @@ def attach_retry_history(
         f"{kind}); fleet totals this operation: {fleet_attempts} retry "
         f"attempt(s), {fleet_backoff_s:.1f}s backoff"
     )
-    add_note = getattr(exc, "add_note", None)
-    if callable(add_note):
-        try:
-            add_note(note)
-        except TypeError:  # pragma: no cover - exotic BaseException subclass
-            pass
+    try:
+        exc.add_note(note)
+    except TypeError:  # pragma: no cover - exotic BaseException subclass
+        pass
     return exc
 
 
