@@ -434,9 +434,14 @@ def array_nbytes(arr) -> int:
 
 
 def to_host(arr) -> np.ndarray:
-    """Synchronous DtoH materialization (numpy passthrough)."""
+    """Synchronous DtoH materialization (numpy passthrough). For a jax
+    array the ``stage_dtoh`` span is the wait for the leaf's bytes on the
+    host: the DMA plus the runtime's de-tiling (which runs on the
+    runtime's own threads and ends inside this call). A zero-copy view,
+    and microseconds, on the CPU backend."""
     if _is_jax_array(arr):
-        return np.asarray(arr)
+        with telemetry.span("stage_dtoh", cat="stager", bytes=array_nbytes(arr)):
+            return np.asarray(arr)
     return np.asarray(arr)
 
 
@@ -595,6 +600,11 @@ def warmup_staging(app_state, pg=None, replicated=None, save_dtype=None) -> int:
     return _staging_pool.prewarm(sizes)
 
 
+def _stage_crc_span(buf: memoryview):
+    """The checksum / digest pass over a leaf's staged bytes."""
+    return telemetry.span("stage_crc", cat="stager", bytes=buf.nbytes)
+
+
 class ArrayBufferStager(BufferStager):
     """Stages one array into a host buffer *owned by the snapshot*.
 
@@ -630,9 +640,12 @@ class ArrayBufferStager(BufferStager):
         return needs_consistency_copy(arr)
 
     def _stage_sync(self, arr) -> np.ndarray:
-        host = np.asarray(arr)
+        host = to_host(arr)
         if self._needs_consistency_copy(arr):
-            host = np.array(host, copy=True)
+            # Host to host. Never runs for a TPU array (DtoH already
+            # produced host-owned memory), so no chip run has this span.
+            with telemetry.span("stage_hostcopy", cat="stager", bytes=host.nbytes):
+                host = np.array(host, copy=True)
         return host
 
     def _device_dedup_candidate(self, arr) -> bool:
@@ -715,12 +728,17 @@ class ArrayBufferStager(BufferStager):
             return None
         if not self._needs_consistency_copy(arr):
             return None
-        src = np.asarray(arr)
+        src = to_host(arr)
         if not src.flags["C_CONTIGUOUS"]:
             return None
         src_bytes = array_as_memoryview(src)
         dst = _staging_pool.get(src_bytes.nbytes)
-        crc = copy_crc32c(dst, src_bytes)
+        # The host-to-host copy, here fused with the CRC: one pass over
+        # the bytes does both, so it is open under both names.
+        with _stage_crc_span(src_bytes), telemetry.span(
+            "stage_hostcopy", cat="stager", bytes=src_bytes.nbytes
+        ):
+            crc = copy_crc32c(dst, src_bytes)
         if crc is None:
             return None
         self.entry.checksum = f"crc32c:{crc:08x}"
@@ -765,7 +783,8 @@ class ArrayBufferStager(BufferStager):
 
                 # Digest covers the UNCOMPRESSED bytes: incremental
                 # chains stay stable across codec/level changes.
-                digest = compute_digest(buf)
+                with _stage_crc_span(buf):
+                    digest = compute_digest(buf)
                 self.entry.digest = digest
                 # Slab-batched payloads (byte_range) never dedup: the
                 # entry's offsets index the SLAB, not the base's file —
@@ -795,7 +814,8 @@ class ArrayBufferStager(BufferStager):
                         self.entry.location = ref.location
                     if ref.checksum is None and ref.codec is None:
                         if checksums_enabled():
-                            self.entry.checksum = compute_checksum(buf)
+                            with _stage_crc_span(buf):
+                                self.entry.checksum = compute_checksum(buf)
                     else:
                         self.entry.checksum = ref.checksum
                     self.io_skipped = True
@@ -812,7 +832,8 @@ class ArrayBufferStager(BufferStager):
             if checksums_enabled():
                 # Checksum covers the STORED bytes — verification reads
                 # exactly what storage returns, before decompression.
-                self.entry.checksum = compute_checksum(buf)
+                with _stage_crc_span(buf):
+                    self.entry.checksum = compute_checksum(buf)
         return buf
 
     # ----------------------------------------------------- streaming path
@@ -1157,17 +1178,23 @@ class _DeviceRowSink:
                 f"short read stream: produced {self.rows} of "
                 f"{self.shape[0]} rows"
             )
-        full = self.blocks[0] if len(self.blocks) == 1 else jnp.concatenate(
-            self.blocks, axis=0
-        )
-        self.blocks = []
-        restored = (
-            jax.device_put(full, self.dest.sharding) if self.dest.committed else full
-        )
-        if self.dest.needs_cast:
-            restored = restored.astype(self.dest.dst_dtype)
-        if self.dest.callback is not None:
-            self.dest.callback(restored)
+        # Device-side assembly: concatenate, placement, cast, hand-over.
+        with telemetry.span(
+            "consume_assemble", cat="consumer", blocks=len(self.blocks)
+        ):
+            full = self.blocks[0] if len(self.blocks) == 1 else jnp.concatenate(
+                self.blocks, axis=0
+            )
+            self.blocks = []
+            restored = (
+                jax.device_put(full, self.dest.sharding)
+                if self.dest.committed
+                else full
+            )
+            if self.dest.needs_cast:
+                restored = restored.astype(self.dest.dst_dtype)
+            if self.dest.callback is not None:
+                self.dest.callback(restored)
 
 
 class _IncrementalEntryDecoder:

@@ -37,6 +37,7 @@ from __future__ import annotations
 import contextvars
 import math
 import os
+import sys
 import threading
 import time
 import weakref
@@ -139,6 +140,42 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# Every span is ALSO written into the profiler's trace, as a
+# ``jax.profiler.TraceAnnotation`` named ``tsnap:<name>``: a trace taken
+# with ``jax.profiler`` then shows the pipeline beside the device, on the
+# profiler's own clock, with no offset arithmetic between two clocks. The
+# bus is unchanged by it (``ts``/``dur`` stay on :data:`monotonic`).
+# The class is looked up once jax is in the process: this module is also
+# imported by the jax-free coordination plane, and a process that never
+# imported jax has no profiler to write to. None = not looked up yet,
+# False = jax has no such class (never asked again).
+_trace_annotation: Any = None
+
+
+def _profiler_note(name: str) -> Any:
+    """An open profiler annotation for one span, or None. A ``TraceMe``
+    takes its start time at construction and records one complete event
+    at exit, on the thread that exits it, so each span owns its own:
+    spans that interleave across ``await``s on the event-loop thread and
+    spans on executor threads both come out whole. Costs one small
+    object when no trace is being taken. Never raises."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except Exception:  # pragma: no cover - a jax without the class
+            cls = False
+        _trace_annotation = cls
+    if not cls:
+        return None
+    try:
+        return cls("tsnap:" + name).__enter__()
+    except Exception:
+        return None
+
 
 class Span:
     """A timed region. Use as a context manager::
@@ -148,10 +185,14 @@ class Span:
 
     Nesting is thread-local: spans entered on the same thread while this
     one is open become its children (``parent`` in the event record).
-    The event is appended at exit with monotonic ``ts``/``dur`` seconds.
+    The event is appended at exit with monotonic ``ts``/``dur`` seconds;
+    the same region lands in a ``jax.profiler`` trace, where one is being
+    taken, as ``tsnap:<name>`` (:func:`_profiler_note`).
     """
 
-    __slots__ = ("name", "cat", "args", "_ts", "_parent", "_tid", "_id", "_tok")
+    __slots__ = (
+        "name", "cat", "args", "_ts", "_parent", "_tid", "_id", "_tok", "_note",
+    )
 
     def __init__(self, name: str, cat: str, args: Optional[Dict[str, Any]]):
         self.name = name
@@ -177,11 +218,17 @@ class Span:
             _next_id += 1
             self._id = _next_id
         self._tok = _span_stack.set(stack + (self._id,))
+        self._note = _profiler_note(self.name)
         self._ts = monotonic()
         return self
 
     def __exit__(self, *exc: object) -> None:
         dur = monotonic() - self._ts
+        if self._note is not None:
+            try:
+                self._note.__exit__(None, None, None)
+            except Exception:  # pragma: no cover - the bus still records
+                pass
         try:
             _span_stack.reset(self._tok)
         except ValueError:  # pragma: no cover - exit in a foreign context
