@@ -78,10 +78,12 @@ def init_params(rng: jax.Array, cfg: SSMConfig) -> Params:
 
 def param_specs(cfg: SSMConfig) -> Params:
     """PartitionSpecs for a ('data','model'[,'seq']) mesh: FFN tp-sharded,
-    SSM params replicated (they are tiny: O(d_model * d_state))."""
+    the tied embedding sharded over the vocabulary (as the transformer's:
+    a local head matmul onto vocabulary-sharded logits), SSM params
+    replicated (they are tiny: O(d_model * d_state))."""
     none2 = P(None, None)
     return {
-        "embed": P(None, "model"),
+        "embed": P("model", None),
         "layers": {
             "ssm": {
                 "log_a": none2,

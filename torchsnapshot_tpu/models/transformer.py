@@ -111,9 +111,19 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 def param_specs(cfg: TransformerConfig) -> Params:
     """PartitionSpecs for each param on a ('data','model') mesh (tp layout).
 
-    Column-parallel (output dim on 'model'): qkv, ff_in, embed.
+    Column-parallel (output dim on 'model'): qkv, ff_in.
     Row-parallel (input dim on 'model'): attn_out, ff_out.
     Norm scales replicated.
+
+    The tied embedding (vocab, D) shards the vocabulary, dim 0: the head
+    ``x @ embed.T`` is then a local matmul onto vocabulary-sharded logits
+    (what the sharded log-softmax in ``loss_fn`` reduces over with two
+    (B, S) reductions), and the lookup is a masked local gather plus one
+    (B, S, D) reduction. Sharding D instead makes the head contract over
+    a sharded dimension: full-vocabulary partial logits, all-reduced and
+    gathered again in the backward pass. Needs ``vocab_size %
+    mesh.shape["model"] == 0``; ``shard_pytree`` refuses placement
+    otherwise.
     """
     layers = {
         "attn_qkv": P(None, None, "model"),
@@ -130,7 +140,7 @@ def param_specs(cfg: TransformerConfig) -> Params:
         layers["ff_in"] = P(None, None, "model")
         layers["ff_out"] = P(None, "model", None)
     return {
-        "embed": P(None, "model"),
+        "embed": P("model", None),
         "layers": layers,
         "ln_f_scale": P(None),
     }

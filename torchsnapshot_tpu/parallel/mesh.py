@@ -11,7 +11,8 @@ machinery the models/benchmarks need to produce such state.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Sequence, Tuple
+import re
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -65,14 +66,27 @@ def shard_pytree(tree: Any, specs: Any, mesh: Mesh) -> Any:
     """device_put every leaf of `tree` with the matching PartitionSpec leaf.
 
     `specs` is a pytree with the same treedef whose leaves are
-    PartitionSpec (or None for fully replicated).
+    PartitionSpec (or None for fully replicated). A dimension that its
+    mesh axes do not divide is refused here, by the leaf's name (e.g. a
+    vocabulary of 50 with `embed` sharded `P("model", None)` over 4).
     """
 
-    def _put(x, spec):
+    def _put(path, x, spec):
         spec = spec if spec is not None else P()
+        for dim, axes in enumerate(spec):
+            if axes is None:
+                continue
+            axes = (axes,) if isinstance(axes, str) else tuple(axes)
+            n = math.prod(mesh.shape[a] for a in axes)
+            if x.shape[dim] % n:
+                raise ValueError(
+                    f"cannot place {jax.tree_util.keystr(path)} {tuple(x.shape)} as "
+                    f"{spec}: dimension {dim} ({x.shape[dim]}) is not divisible by "
+                    f"the {n} devices of mesh axes {axes}"
+                )
         return jax.device_put(x, NamedSharding(mesh, spec))
 
-    return jax.tree_util.tree_map(
+    return jax.tree_util.tree_map_with_path(
         _put, tree, specs, is_leaf=lambda x: x is None
     )
 
@@ -94,3 +108,115 @@ def optax_state_specs(p_specs: Any, opt_state: Any) -> Tuple[Any, ...]:
         return jax.tree_util.tree_map(lambda _: P(), entry)
 
     return tuple(map_entry(e) for e in opt_state)
+
+
+# ------------------------------------------------- collectives of a program
+
+_COLLECTIVE_KINDS = (
+    "all-reduce",
+    "all-gather",
+    "reduce-scatter",
+    "all-to-all",
+    "collective-permute",
+    "collective-broadcast",
+)
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*(?:e[0-9]m[0-9][a-z]*)?)\[([0-9,]*)\]")
+_CALLEE = re.compile(
+    r"\b(body|condition|calls|to_apply|true_computation|false_computation)=%([^\s,)}]+)"
+)
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+
+
+def _array_bytes(dtype: str, dims: Tuple[int, ...]) -> int:
+    bits = re.search(r"[0-9]+", dtype)  # bf16, f8e4m3fn, c64; pred has none
+    return max(1, int(bits.group()) // 8 if bits else 1) * math.prod(dims)
+
+
+def collectives(hlo_text: str) -> List[Dict[str, Any]]:
+    """The collectives of a compiled program, from ``compiled.as_text()``.
+
+    One record per collective instruction: ``kind`` (all-reduce, all-gather,
+    reduce-scatter, all-to-all, collective-permute, collective-broadcast),
+    ``name``, ``shapes`` (``(dtype, dims)`` of each array it produces on one
+    device; a combined all-reduce produces several), ``bytes`` (their sum)
+    and ``times``, how often one run of the program executes it: the
+    product of the trip counts of the loops around it. A trip count is the
+    loop's ``known_trip_count``, else the constant its condition compares
+    the counter with (`lax.scan` lowers to ``i < n``), else 1. Every branch
+    of a conditional counts as taken.
+
+    Static: a count of what the partitioner put in, not a time. The async
+    form counts at its ``-start`` (whose result is ``(operand, result,
+    context)``: the result half is taken) and not at its ``-done``.
+    """
+    comps: Dict[str, List[str]] = {}
+    entry = cur = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) \(.*\{\s*$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+
+    def trip_count(line: str, cond: Optional[str]) -> int:
+        known = re.search(r'"known_trip_count":\{"n":"([0-9]+)"', line)
+        if known:
+            return int(known.group(1))
+        body = comps.get(cond or "", [])
+        bounds = [m for m in (re.search(r"\bs32\[\][^ ]* constant\(([0-9]+)\)", b) for b in body) if m]
+        if len(bounds) == 1 and any("ROOT" in b and "direction=LT" in b for b in body):
+            return int(bounds[0].group(1))
+        return 1
+
+    found: List[Dict[str, Any]] = []
+
+    def walk(comp: str, times: int) -> None:
+        for line in comps.get(comp, ()):
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            name, result, op = m.groups()
+            callees = dict(_CALLEE.findall(line))
+            for role, callee in callees.items():
+                loop = trip_count(line, callees.get("condition")) if role == "body" else 1
+                walk(callee, times * loop)
+            for branches in _BRANCHES.findall(line):
+                for callee in re.findall(r"%([^\s,]+)", branches):
+                    walk(callee, times)
+            kind = op.removesuffix("-start")
+            if kind not in _COLLECTIVE_KINDS:
+                continue
+            shapes = [
+                (d, tuple(int(x) for x in dims.split(",") if x))
+                for d, dims in _ARRAY.findall(result)
+            ]
+            if op.endswith("-start") and kind != "all-reduce":
+                arrays = [s for s in shapes if s[1]]
+                shapes = arrays[len(arrays) // 2:]
+            found.append(
+                {
+                    "kind": kind,
+                    "name": name,
+                    "shapes": shapes,
+                    "bytes": sum(_array_bytes(*s) for s in shapes),
+                    "times": times,
+                }
+            )
+
+    if entry is not None:
+        walk(entry, 1)
+    return found
+
+
+def collective_bytes(hlo_text: str) -> Dict[str, int]:
+    """Bytes each kind of collective produces on one device in one run of
+    the compiled program (``bytes`` x ``times`` of `collectives`), by kind."""
+    out: Dict[str, int] = {}
+    for c in collectives(hlo_text):
+        out[c["kind"]] = out.get(c["kind"], 0) + c["bytes"] * c["times"]
+    return out
