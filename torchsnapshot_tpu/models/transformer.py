@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.attention import CP_ROUTES, causal_attention_route
+
 Params = Dict[str, Any]
 
 
@@ -151,91 +153,19 @@ def _rmsnorm(x: jax.Array, scale: jax.Array) -> jax.Array:
     return (x * jax.lax.rsqrt(var + 1e-6).astype(x.dtype)) * scale.astype(x.dtype)
 
 
-def _flash_mesh_ok(cfg: TransformerConfig, mesh: Mesh, B: int, S: int) -> bool:
-    """Preconditions for routing attention through the shard_mapped flash
-    kernel under a mesh: heads divide the 'model' axis when one exists,
-    batch divides the 'data' axis, and S (the kernel's local sequence
-    length — pass S_local for ring-flash) has a kernel-viable tile
-    divisor (the kernel picks its own 512-target tiling, so the gate must
-    agree with that pick). Shared by the flash and ring-flash routes."""
-    from ..ops.attention import pick_block_size
-
-    if "model" in mesh.axis_names and cfg.n_heads % mesh.shape["model"]:
-        return False
-    if "data" in mesh.axis_names and B % mesh.shape["data"]:
-        return False
-    return S > 0 and pick_block_size(S, 512) is not None
-
-
-_ATTN_IMPLS = ("auto", "dense", "blockwise", "flash", "ring", "zigzag", "ulysses")
-_CP_SELECTIONS = ("ring", "ring_flash", "zigzag", "zigzag_flash", "ulysses")
+def _attention_route(cfg: TransformerConfig, mesh: Optional[Mesh], B: int, S: int):
+    return causal_attention_route(
+        cfg.attn_impl, cfg.attn_block_size, cfg.n_heads, mesh, B, S
+    )
 
 
 def select_attention(
     cfg: TransformerConfig, mesh: Optional[Mesh], B: int, S: int
 ) -> str:
-    """The attention path ``forward`` runs for this config, mesh and shape.
-
-    ``cfg.attn_impl`` is a request; what runs also depends on things the
-    code observes (backend, mesh axes, whether S tiles), and a request
-    that cannot be met gives way to the next-best path. This function is
-    that whole decision, so callers (chip_smoke.py, tests) can read what
-    was selected instead of inferring it from the config string. Returns
-    one of ``dense``, ``blockwise``, ``flash`` (bare Pallas kernel),
-    ``flash_sharded`` (the kernel shard_mapped over batch/heads),
-    ``ring``, ``ring_flash``, ``zigzag``, ``zigzag_flash``, ``ulysses``.
-    """
-    from ..ops.attention import pick_block_size
-
-    c = cfg
-    if c.attn_impl not in _ATTN_IMPLS:
-        raise ValueError(f"unknown attn_impl {c.attn_impl!r}")
-    on_tpu = jax.default_backend() == "tpu"
-    impl = c.attn_impl
-    if impl == "auto":
-        # Backend-aware kernel choice: the Pallas flash kernel on TPU —
-        # bare on a single device, shard_mapped over batch/heads under a
-        # mesh when the preconditions hold (_flash_mesh_ok; a bare
-        # pallas_call has no partitioning rule, so it must never see
-        # sharded operands); blockwise once S outgrows one block
-        # (O(S*block) memory); dense for short sequences. Never selects a
-        # cp impl — ring/zigzag/ulysses are mesh topology decisions for
-        # the caller.
-        if on_tpu and (mesh is None or _flash_mesh_ok(c, mesh, B, S)):
-            impl = "flash"
-        elif S > c.attn_block_size:
-            impl = "blockwise"
-        else:
-            impl = "dense"
-    if impl in ("ring", "zigzag", "ulysses"):
-        if mesh is None:
-            # Single-device run of a cp-configured model: same math, no
-            # axis to communicate over.
-            return "dense"
-        if "seq" not in mesh.axis_names:
-            raise ValueError(
-                f"attn_impl={impl!r} needs a mesh with a 'seq' axis; got "
-                f"{mesh.axis_names}. Build one via make_mesh({{'data': ..., "
-                f"'seq': ..., 'model': ...}})."
-            )
-        if impl == "ulysses":
-            return impl
-        # The ring's inner compute dominates long-context cost; run it
-        # through the Pallas kernel when the LOCAL shard (the half-shard
-        # for zigzag) satisfies the flash preconditions.
-        parts = mesh.shape["seq"] * (2 if impl == "zigzag" else 1)
-        if on_tpu and S % parts == 0 and _flash_mesh_ok(c, mesh, B, S // parts):
-            return impl + "_flash"
-        return impl
-    if impl in ("blockwise", "flash"):
-        if pick_block_size(S, c.attn_block_size) is None:
-            return "dense"
-        if impl == "flash" and mesh is not None:
-            # Under a mesh the bare pallas_call would make GSPMD gather
-            # the sharded operands; shard_map the kernel instead, or give
-            # way to blockwise when the preconditions don't hold.
-            return "flash_sharded" if _flash_mesh_ok(c, mesh, B, S) else "blockwise"
-    return impl
+    """The attention path ``forward`` runs for this config, mesh and shape:
+    the name of the route ``ops.attention.causal_attention_route`` selects
+    (that function is the whole decision, shared with the other models)."""
+    return _attention_route(cfg, mesh, B, S)[0]
 
 
 def forward(
@@ -261,12 +191,12 @@ def forward(
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
-    impl = select_attention(c, mesh, B, S)
+    impl, attend = _attention_route(c, mesh, B, S)
     # cp (ring/ulysses) keeps the sequence dim sharded over 'seq' end-to-end;
     # the Megatron-sp fallback seq-shards the residual over the tp axis
     # instead and gathers around attention/ffn.
     has_seq = mesh is not None and "seq" in mesh.axis_names
-    cp = impl in _CP_SELECTIONS
+    cp = impl in CP_ROUTES
     res_seq_ax = "seq" if has_seq else "model"  # residual-stream seq sharding
     act_seq_ax = "seq" if cp else None  # in-block activation seq sharding
 
@@ -295,48 +225,6 @@ def forward(
         zz_inv = jnp.argsort(zz_idx)
         x = jnp.take(x, zz_idx, axis=1)
 
-    def attention(q, k, v):
-        # q, k, v: (B, S, H, hd) — logical shapes; sharding via constraints.
-        from ..ops import attention as A
-
-        if impl == "ulysses":
-            from ..ops.ulysses import ulysses_attention_sharded
-
-            return ulysses_attention_sharded(
-                q, k, v, mesh, causal=True, inner_block_size=c.attn_block_size
-            )
-        if impl == "zigzag_flash":
-            from ..ops.ring_flash import zigzag_ring_flash_attention_sharded
-
-            return zigzag_ring_flash_attention_sharded(
-                q, k, v, mesh, in_layout=zz_hoist
-            )
-        if impl == "zigzag":
-            from ..ops.ring_attention import zigzag_ring_attention_sharded
-
-            return zigzag_ring_attention_sharded(q, k, v, mesh, in_layout=zz_hoist)
-        if impl == "ring_flash":
-            from ..ops.ring_flash import ring_flash_attention_sharded
-
-            return ring_flash_attention_sharded(q, k, v, mesh, causal=True)
-        if impl == "ring":
-            from ..ops.ring_attention import ring_attention_sharded
-
-            return ring_attention_sharded(q, k, v, mesh, causal=True)
-        if impl == "flash_sharded":
-            from ..ops.pallas_attention import flash_attention_sharded
-
-            return flash_attention_sharded(q, k, v, mesh, causal=True)
-        if impl == "flash":
-            from ..ops.pallas_attention import flash_attention
-
-            bs = A.pick_block_size(S, c.attn_block_size)
-            return flash_attention(q, k, v, causal=True, block_q=bs, block_k=bs)
-        if impl == "blockwise":
-            bs = A.pick_block_size(S, c.attn_block_size)
-            return A.blockwise_attention(q, k, v, block_size=bs, causal=True)
-        return A.dense_attention(q, k, v, causal=True)
-
     def block(carry, layer):
         x, aux = carry
         x = cs(x, P("data", res_seq_ax, None))
@@ -350,7 +238,8 @@ def forward(
             t = t.reshape(B, S, c.n_heads, c.head_dim)
             return cs(t, P("data", act_seq_ax, "model", None))
 
-        attn = attention(heads(q), heads(k), heads(v))  # (B,S,H,hd)
+        # (B,S,H,hd) — logical shapes; sharding via constraints.
+        attn = attend(heads(q), heads(k), heads(v), in_layout=zz_hoist)
         attn = attn.reshape(B, S, c.d_model)
         attn = cs(attn, P("data", act_seq_ax, "model"))
         x = x + cs(attn @ layer["attn_out"].astype(c.dtype), P("data", res_seq_ax, None))

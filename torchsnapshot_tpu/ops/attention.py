@@ -13,7 +13,7 @@ compiled program size flat as sequence length grows.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -158,3 +158,135 @@ def blockwise_attention(
         (kb.transpose(1, 0, 2, 3, 4), vb.transpose(1, 0, 2, 3, 4), jnp.arange(n_blocks)),
     )
     return _finalize(acc, q.dtype)
+
+
+# ------------------------------------------------- which path a model runs
+
+ATTN_IMPLS = ("auto", "dense", "blockwise", "flash", "ring", "zigzag", "ulysses")
+CP_ROUTES = ("ring", "ring_flash", "zigzag", "zigzag_flash", "ulysses")
+
+
+def flash_mesh_ok(n_heads: int, mesh, B: int, S: int) -> bool:
+    """Preconditions for routing attention through the shard_mapped flash
+    kernel under a mesh: heads divide the 'model' axis when one exists,
+    batch divides the 'data' axis, and S (the kernel's local sequence
+    length — pass S_local for ring-flash) has a kernel-viable tile
+    divisor (the kernel picks its own 512-target tiling, so the gate must
+    agree with that pick). Shared by the flash and ring-flash routes."""
+    if "model" in mesh.axis_names and n_heads % mesh.shape["model"]:
+        return False
+    if "data" in mesh.axis_names and B % mesh.shape["data"]:
+        return False
+    return S > 0 and pick_block_size(S, 512) is not None
+
+
+def _select_route(attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S: int) -> str:
+    if attn_impl not in ATTN_IMPLS:
+        raise ValueError(f"unknown attn_impl {attn_impl!r}")
+    on_tpu = jax.default_backend() == "tpu"
+    impl = attn_impl
+    if impl == "auto":
+        # Backend-aware kernel choice: the Pallas flash kernel on TPU —
+        # bare on a single device, shard_mapped over batch/heads under a
+        # mesh when the preconditions hold (flash_mesh_ok; a bare
+        # pallas_call has no partitioning rule, so it must never see
+        # sharded operands); blockwise once S outgrows one block
+        # (O(S*block) memory); dense for short sequences. Never selects a
+        # cp impl — ring/zigzag/ulysses are mesh topology decisions for
+        # the caller.
+        if on_tpu and (mesh is None or flash_mesh_ok(n_heads, mesh, B, S)):
+            impl = "flash"
+        elif S > block_size:
+            impl = "blockwise"
+        else:
+            impl = "dense"
+    if impl in ("ring", "zigzag", "ulysses"):
+        if mesh is None:
+            # Single-device run of a cp-configured model: same math, no
+            # axis to communicate over.
+            return "dense"
+        if "seq" not in mesh.axis_names:
+            raise ValueError(
+                f"attn_impl={impl!r} needs a mesh with a 'seq' axis; got "
+                f"{mesh.axis_names}. Build one via make_mesh({{'data': ..., "
+                f"'seq': ..., 'model': ...}})."
+            )
+        if impl == "ulysses":
+            return impl
+        # The ring's inner compute dominates long-context cost; run it
+        # through the Pallas kernel when the LOCAL shard (the half-shard
+        # for zigzag) satisfies the flash preconditions.
+        parts = mesh.shape["seq"] * (2 if impl == "zigzag" else 1)
+        if on_tpu and S % parts == 0 and flash_mesh_ok(n_heads, mesh, B, S // parts):
+            return impl + "_flash"
+        return impl
+    if impl in ("blockwise", "flash"):
+        if pick_block_size(S, block_size) is None:
+            return "dense"
+        if impl == "flash" and mesh is not None:
+            # Under a mesh the bare pallas_call would make GSPMD gather
+            # the sharded operands; shard_map the kernel instead, or give
+            # way to blockwise when the preconditions don't hold.
+            return "flash_sharded" if flash_mesh_ok(n_heads, mesh, B, S) else "blockwise"
+    return impl
+
+
+def causal_attention_route(
+    attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S: int
+) -> Tuple[str, Callable[..., jax.Array]]:
+    """The causal attention a model runs for this request, mesh and shape:
+    the route's name and ``attend(q, k, v, in_layout=False)`` on logical
+    ``(B, S, H, hd)`` operands (sharding via the caller's constraints).
+
+    ``attn_impl`` is a request; what runs also depends on things the code
+    observes (backend, mesh axes, whether S tiles), and a request that
+    cannot be met gives way to the next-best path. This function is that
+    whole decision and the only dispatch: every model's block calls it, so
+    callers (chip_smoke.py, tests, the benchmark) read what was selected
+    instead of inferring it from a config string. The name is one of
+    ``dense``, ``blockwise``, ``flash`` (bare Pallas kernel),
+    ``flash_sharded`` (the kernel shard_mapped over batch/heads),
+    ``ring``, ``ring_flash``, ``zigzag``, ``zigzag_flash``, ``ulysses``.
+    ``in_layout`` tells the zigzag routes that the caller already applied
+    the folded layout.
+    """
+    route = _select_route(attn_impl, block_size, n_heads, mesh, B, S)
+
+    def attend(q, k, v, in_layout: bool = False):
+        if route == "ulysses":
+            from .ulysses import ulysses_attention_sharded
+
+            return ulysses_attention_sharded(
+                q, k, v, mesh, causal=True, inner_block_size=block_size
+            )
+        if route == "zigzag_flash":
+            from .ring_flash import zigzag_ring_flash_attention_sharded
+
+            return zigzag_ring_flash_attention_sharded(q, k, v, mesh, in_layout=in_layout)
+        if route == "zigzag":
+            from .ring_attention import zigzag_ring_attention_sharded
+
+            return zigzag_ring_attention_sharded(q, k, v, mesh, in_layout=in_layout)
+        if route == "ring_flash":
+            from .ring_flash import ring_flash_attention_sharded
+
+            return ring_flash_attention_sharded(q, k, v, mesh, causal=True)
+        if route == "ring":
+            from .ring_attention import ring_attention_sharded
+
+            return ring_attention_sharded(q, k, v, mesh, causal=True)
+        if route == "flash_sharded":
+            from .pallas_attention import flash_attention_sharded
+
+            return flash_attention_sharded(q, k, v, mesh, causal=True)
+        if route == "flash":
+            from .pallas_attention import flash_attention
+
+            bs = pick_block_size(S, block_size)
+            return flash_attention(q, k, v, causal=True, block_q=bs, block_k=bs)
+        if route == "blockwise":
+            bs = pick_block_size(S, block_size)
+            return blockwise_attention(q, k, v, block_size=bs, causal=True)
+        return dense_attention(q, k, v, causal=True)
+
+    return route, attend
