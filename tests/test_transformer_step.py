@@ -3,13 +3,17 @@ path: the attention selection is readable, the train step keeps the
 layout it was given, and a restored state lowers to the same program as
 the one the first run compiled (or every resume misses the compile cache).
 And what the four-chip step needed: the tied embedding sharded over the
-vocabulary, so that no logits-sized tensor crosses the 'model' axis.
+vocabulary, so that no logits-sized tensor crosses the 'model' axis; and
+every matrix's gradient summed over 'data' once a step, after the backward
+scan, through a replica dimension that exists only inside the step.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import json
 import os
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from torchsnapshot_tpu import Snapshot, StateDict
 from torchsnapshot_tpu.models import ssm_lm, transformer as T
 from torchsnapshot_tpu.parallel import make_mesh
-from torchsnapshot_tpu.parallel.mesh import collective_bytes, collectives
+from torchsnapshot_tpu.parallel.mesh import collective_bytes, collectives, spanned_axes, with_replica_dim
 
 CFG = T.TransformerConfig(
     vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_seq_len=16
@@ -204,7 +208,8 @@ def test_the_old_layout_moved_the_logits_and_the_counter_sees_it(monkeypatch, me
     specs = T.param_specs
     monkeypatch.setattr(T, "param_specs", lambda cfg: {**specs(cfg), "embed": OLD_EMBED})
     old = _compiled_step_text(T, DENSE, mesh)
-    logits = [shape for _, shape in _vocab_sized(old, mesh, DENSE) if len(shape) == 3]
+    # (B, S, V), or (R, B/R, S, V) where the head runs replica by replica
+    logits = [shape for _, shape in _vocab_sized(old, mesh, DENSE) if len(shape) >= 3]
     assert logits and all(B * S * V // 4 <= np.prod(s) <= B * S * V for s in logits)
     assert sum(collective_bytes(new).values()) < sum(collective_bytes(old).values())
 
@@ -260,8 +265,8 @@ def test_collectives_reads_loops_and_async_pairs():
 %body (p.1: (s32[], bf16[2,8])) -> (s32[], bf16[2,8]) {
   %p.1 = (s32[]{:T(128)}, bf16[2,8]{1,0}) parameter(0)
   %x = bf16[2,8]{1,0:T(8,128)(2,1)} get-tuple-element(%p.1), index=1
-  %all-reduce.1 = (bf16[2,8]{1,0:T(8,128)(2,1)}, f32[4]{0}) all-reduce(%x, %y), channel_id=1, to_apply=%add
-  %collective-permute-start = (bf16[2,8]{1,0}, bf16[2,8]{1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), channel_id=2
+  %all-reduce.1 = (bf16[2,8]{1,0:T(8,128)(2,1)}, f32[4]{0}) all-reduce(%x, %y), channel_id=1, replica_groups=[2,2]<=[2,2]T(1,0), use_global_device_ids=true, to_apply=%add
+  %collective-permute-start = (bf16[2,8]{1,0}, bf16[2,8]{1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), channel_id=2, source_target_pairs={{0,1},{2,3}}
   %collective-permute-done = bf16[2,8]{1,0} collective-permute-done(%collective-permute-start)
   ROOT %t = (s32[]{:T(128)}, bf16[2,8]{1,0}) tuple(%i.1, %collective-permute-done)
 }
@@ -270,9 +275,14 @@ ENTRY %main (a.1: bf16[2,8]) -> bf16[4,8] {
   %while.1 = (s32[]{:T(128)}, bf16[2,8]{1,0}) while(%init), condition=%cond, body=%body
   %while.2 = (s32[], bf16[2,8]{1,0}) while(%init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"3"}}
   %conditional.1 = (s32[], bf16[2,8]{1,0}) conditional(%k, %init, %init), branch_computations={%body, %body}
-  ROOT %all-gather.1 = bf16[4,8]{1,0} all-gather(%a.1), channel_id=3, dimensions={0}
+  ROOT %all-gather.1 = bf16[4,8]{1,0} all-gather(%a.1), channel_id=3, replica_groups=[2,2]<=[4], dimensions={0}
 }
 """
+    assert {c["name"]: c["groups"] for c in collectives(text)} == {
+        "all-reduce.1": [(0, 2), (1, 3)],
+        "collective-permute-start": [(0, 1), (2, 3)],
+        "all-gather.1": [(0, 1), (2, 3)],
+    }
     got = {(c["name"], c["times"]): (c["kind"], c["shapes"], c["bytes"]) for c in collectives(text)}
     reduce = ("all-reduce", [("bf16", (2, 8)), ("f32", (4,))], 48)
     permute = ("collective-permute", [("bf16", (2, 8))], 32)
@@ -288,6 +298,19 @@ ENTRY %main (a.1: bf16[2,8]) -> bf16[4,8] {
     assert collective_bytes(text) == {"all-reduce": 48 * 13, "collective-permute": 32 * 13, "all-gather": 64}
 
 
+def _one_device_and_sharded_grads(cfg, mesh):
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    want_loss, want = jax.jit(jax.value_and_grad(lambda p, b: T.loss_fn(p, b, cfg)))(
+        params, _random_batch()
+    )
+    sharded = T.init_state(jax.random.PRNGKey(0), cfg, T.make_optimizer(), mesh=mesh)["params"]
+    assert sharded["embed"].sharding.spec == P("model", None)
+    got_loss, got = jax.jit(jax.value_and_grad(lambda p, b: T.loss_fn(p, b, cfg, mesh=mesh)))(
+        sharded, _random_batch(mesh)
+    )
+    return want_loss, want, got_loss, got
+
+
 # Tolerances, as shares of the largest entry of the reference gradient.
 # float32 compute leaves only the order of the additions: 2.0e-7 to 3.5e-7
 # read here, held to 2e-6. bf16 compute: every sharded reduction adds in
@@ -300,16 +323,7 @@ ENTRY %main (a.1: bf16[2,8]) -> bf16[4,8] {
 @pytest.mark.parametrize("mesh_name", list(MESHES))
 def test_sharded_loss_and_embed_gradient_match_one_device(mesh_name, family, dtype, tol):
     cfg = dataclasses.replace(FAMILIES[family][1], dtype=dtype)
-    mesh = _mesh(mesh_name)
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(lambda p, b: T.loss_fn(p, b, cfg)))(
-        params, _random_batch()
-    )
-    sharded = T.init_state(jax.random.PRNGKey(0), cfg, T.make_optimizer(), mesh=mesh)["params"]
-    assert sharded["embed"].sharding.spec == P("model", None)
-    got_loss, got = jax.jit(jax.value_and_grad(lambda p, b: T.loss_fn(p, b, cfg, mesh=mesh)))(
-        sharded, _random_batch(mesh)
-    )
+    want_loss, want, got_loss, got = _one_device_and_sharded_grads(cfg, _mesh(mesh_name))
     np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=tol)
     want_g = np.asarray(want["embed"])
     rows = np.abs(np.asarray(got["embed"]) - want_g).max(axis=1) / np.abs(want_g).max()
@@ -352,3 +366,192 @@ def test_a_snapshot_of_the_old_embed_layout_restores_under_the_new_one(tmp_path)
     ):
         assert b.sharding.is_equivalent_to(w, b.ndim), jax.tree_util.keystr(path)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path))
+
+
+# ------------------------------- the data-parallel gradient, once a step
+
+FULL = T.TransformerConfig(  # the four-chip cell's step: OLMo-1B's widths, 8 layers, batch 4
+    vocab_size=50304, d_model=2048, n_heads=16, n_layers=8, d_ff=8192, max_seq_len=2048
+)
+MB = 1e6
+
+
+def _data_reductions(text, mesh):
+    return [
+        c for c in collectives(text)
+        if c["kind"] == "all-reduce" and "data" in spanned_axes(c["groups"], mesh)
+    ]
+
+
+def _assert_reduced_once(text, mesh, cfg, in_loop_limit):
+    """No all-reduce over 'data' of more than ``in_loop_limit`` bytes runs
+    more than once a step, and exactly one carries the embedding's shard."""
+    over_data = _data_reductions(text, mesh)
+    assert over_data, "a data-parallel step without a gradient reduction: nothing was read"
+    in_loop = [(c["name"], c["times"], c["shapes"]) for c in over_data if c["times"] > 1 and c["bytes"] > in_loop_limit]
+    assert in_loop == []
+    embed = (cfg.vocab_size // mesh.shape["model"], cfg.d_model)
+    assert sum(embed in [shape for _, shape in c["shapes"]] for c in over_data) == 1
+
+
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_matrix_gradients_cross_data_once_a_step_on_the_cpu_partitioner(monkeypatch, family):
+    """The stacked matrices and the embedding take the replica dimension;
+    what stays in the scans over 'data' is the norm scales (two D-vectors a
+    layer) and, in an MoE, the router's and the dispatch's own traffic:
+    ``moe_ffn`` takes no replica dimension, so its leaves are reduced where
+    they are produced, as before."""
+    cfg, mesh = FAMILIES[family][1], _mesh("2x2")
+    taken = []
+    monkeypatch.setattr(
+        T, "with_replica_dim", lambda w, *a, **k: taken.append(w.shape) or with_replica_dim(w, *a, **k)
+    )
+    text = _compiled_step_text(T, cfg, mesh)
+    L, F = cfg.n_layers, cfg.d_ff
+    matrices = [(L, D, 3 * D), (L, D, D)] + ([] if cfg.n_experts else [(L, D, F), (L, F, D)])
+    assert sorted(taken) == sorted([*matrices, (V, D)])
+    attn_shard = 4 * D * D // 2  # one layer's smallest matrix shard, float32
+    if family == "dense":
+        _assert_reduced_once(text, mesh, cfg, in_loop_limit=attn_shard - 1)
+    else:  # the dispatch moves (E, capacity, D) activations over 'data' in the scan
+        in_loop = [s for c in _data_reductions(text, mesh) if c["times"] > 1 for _, s in c["shapes"]]
+        assert not {(1, D, 3 * D // 2), (1, D // 2, D), (1, D, 3 * D), (1, D, D)} & set(in_loop)
+        assert any(set(s) == {cfg.n_experts, D} for s in in_loop), "the router's gradient left the scan"
+
+
+def test_no_replica_dimension_without_data_parallelism(monkeypatch):
+    """``data`` 1, no mesh, or a batch 'data' does not divide: the plain path."""
+    monkeypatch.setattr(T, "with_replica_dim", lambda *a, **k: pytest.fail("replica dimension taken"))
+    params = jax.eval_shape(lambda k: T.init_params(k, DENSE), jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    for mesh, tokens in [(_mesh("1x4"), toks), (None, toks), (_mesh("2x2"), jax.ShapeDtypeStruct((3, S), jnp.int32))]:
+        jaxpr = str(jax.make_jaxpr(lambda p, t: T.forward(p, t, DENSE, mesh=mesh))(params, tokens))
+        assert f"[1,{V},{D}]" not in jaxpr and f"[2,{V},{D}]" not in jaxpr
+        # and the head still casts the embedding before it transposes it: the
+        # other order cost olmo1b.save 2.9 ms a step (chip call B, PR 29)
+        assert f"f32[{D},{V}]" not in jaxpr and f"bf16[{D},{V}]" in jaxpr
+
+
+def test_the_replica_dimension_is_in_the_jaxpr_under_data_parallelism():
+    """The detector above is alive: under {data 2, model 2} the embedding
+    is there as (R, V, D)."""
+    params = jax.eval_shape(lambda k: T.init_params(k, DENSE), jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    mesh = _mesh("2x2")
+    assert f"[2,{V},{D}]" in str(jax.make_jaxpr(lambda p, t: T.forward(p, t, DENSE, mesh=mesh))(params, toks))
+
+
+def test_data_gradient_is_reduced_once_at_full_width_on_the_tpu_partitioner(v5e_2x2, monkeypatch):
+    """The four-chip cell's train layout, compiled for the chip: no
+    reduction over 'data' of more than 1 MB in either scan, one reduction of
+    the embedding's gradient, and the bytes that go with that: 1076.2 MB of
+    all-reduce per device and step (1179.3 with the embedding reduced twice;
+    1581.9 if the float32 parameter is broadcast before the cast)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = _mesh("2x2", v5e_2x2)
+    text = _compiled_step_text(T, FULL, mesh, batch=(4, 2048))
+    _assert_reduced_once(text, mesh, FULL, in_loop_limit=1 * MB)
+    assert 900 * MB < collective_bytes(text)["all-reduce"] <= 1080 * MB
+    after_scan = sum(c["bytes"] for c in _data_reductions(text, mesh) if c["times"] == 1)
+    assert abs(after_scan - 2 * (FULL.param_count // 2)) < 1 * MB  # every parameter's shard once, in bf16
+
+
+def test_the_resume_layout_compiles_to_what_it_did_on_the_tpu_partitioner(v5e_2x2, monkeypatch):
+    """{data 1, model 4}: R is 1, and the collectives are the parent's
+    (4161.0 MB per device and step, PERF.md section 6, PR 27)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(T, "with_replica_dim", lambda *a, **k: pytest.fail("replica dimension taken"))
+    mesh = _mesh("1x4", v5e_2x2)
+    text = _compiled_step_text(T, FULL, mesh, batch=(4, 2048))
+    assert round(sum(collective_bytes(text).values()) / MB, 1) == 4161.0
+    assert _data_reductions(text, mesh) == []
+
+
+CP = {"data": 2, "seq": 2, "model": 2}
+
+
+# Tolerances as above. An MoE in float32 reads 1.7e-6 on the router's
+# gradient, with the replica dimension as without it: held to 4e-6. In bf16
+# it reroutes tokens (above), which moves every leaf: not compared here.
+@pytest.mark.parametrize(
+    "family,dtype,tol",
+    [("dense", jnp.bfloat16, 2e-2), ("dense", jnp.float32, 2e-6), ("moe", jnp.float32, 4e-6)],
+)
+@pytest.mark.parametrize("axes,impl", [(MESHES["2x2"], "auto"), (CP, "ring")], ids=["2x2", "cp-ring"])
+def test_every_gradient_leaf_of_the_sharded_step_matches_one_device(axes, impl, family, dtype, tol):
+    """R = 2 with two rows of the batch a replica."""
+    cfg = dataclasses.replace(FAMILIES[family][1], dtype=dtype, attn_impl=impl)
+    mesh = make_mesh(axes, devices=jax.devices()[: int(np.prod(list(axes.values())))])
+    assert mesh.shape["data"] == 2 and B // 2 > 1
+    want_loss, want, got_loss, got = _one_device_and_sharded_grads(cfg, mesh)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=tol)
+    for (path, w), g in zip(jax.tree_util.tree_flatten_with_path(want)[0], jax.tree_util.tree_leaves(got)):
+        w = np.asarray(w)
+        err = np.abs(np.asarray(g) - w).max() / np.abs(w).max()
+        assert err <= tol, (jax.tree_util.keystr(path), err)
+
+
+# ------------------------------------- the saved state did not change
+
+FIXTURE = os.path.join(os.path.dirname(__file__), "data", "transformer_2x2_snapshot")
+
+
+def _fixture_run():
+    """What tests/data/gen_transformer_2x2_snapshot.py ran, on today's code."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "gen", os.path.join(os.path.dirname(FIXTURE), "gen_transformer_2x2_snapshot.py")
+    )
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    return gen.build()
+
+
+def _layout(manifest):
+    """A manifest without what the values decide: checksums."""
+
+    def strip(x):
+        if isinstance(x, dict):
+            return {k: strip(v) for k, v in x.items() if k != "checksum"}
+        return [strip(v) for v in x] if isinstance(x, list) else x
+
+    return strip(json.loads(json.dumps(manifest, default=dataclasses.asdict)))
+
+
+def test_state_specs_are_the_layout_they_were():
+    dense = T.state_specs(DENSE, jax.eval_shape(lambda k: T.init_state(k, DENSE, T.make_optimizer()), jax.random.PRNGKey(0)))
+    want = {
+        "embed": P("model", None),
+        "layers": {
+            "attn_qkv": P(None, None, "model"), "attn_out": P(None, "model", None),
+            "ff_in": P(None, None, "model"), "ff_out": P(None, "model", None),
+            "ln1_scale": P(None, None), "ln2_scale": P(None, None),
+        },
+        "ln_f_scale": P(None),
+    }
+    assert dense["params"] == want and dense["step"] == P()
+    adam = dense["opt_state"][0]
+    assert adam.mu == want and adam.nu == want and adam.count == P()
+
+
+def test_a_snapshot_taken_before_the_replica_dimension_restores_and_steps(tmp_path):
+    """The fixture was saved by the parent commit after one sharded step.
+    Today's save of the same run has the same manifest (paths, shapes,
+    pieces; 41 payload files), and the old snapshot restores into today's
+    state and steps to the loss the parent stepped to."""
+    cfg, tx, mesh, batch, step = _fixture_run()
+    # A copy: a restore writes its history beside the snapshot it reads.
+    fixture = shutil.copytree(FIXTURE, str(tmp_path / "old" / "snap"))
+    with open(os.path.join(fixture, "expected.json")) as f:
+        expected = json.load(f)
+    state, before = step(T.init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh), batch)
+    np.testing.assert_allclose(float(before), expected["loss_before"], rtol=1e-6)
+    Snapshot.take(str(tmp_path / "snap"), {"train": StateDict(**state)})
+    old, new = Snapshot(fixture).get_manifest(), Snapshot(str(tmp_path / "snap")).get_manifest()
+    assert _layout(new) == _layout(old)
+
+    dst = StateDict(**T.init_state(jax.random.PRNGKey(1), cfg, tx, mesh=mesh))
+    Snapshot(fixture).restore({"train": dst})
+    _, after = step(dict(dst), batch)
+    np.testing.assert_allclose(float(after), expected["loss_after"], rtol=2e-3)

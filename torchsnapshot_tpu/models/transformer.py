@@ -7,7 +7,9 @@ produce realistic distributed state to checkpoint. This module is the
 TPU-native analogue: a pure-JAX decoder-only transformer whose parameters
 and training step are annotated for a ('data','model') mesh:
 
-- dp: batch sharded over 'data'
+- dp: batch sharded over 'data'; each matrix's gradient crosses 'data'
+  once a step, after the backward scan (the replica dimension in
+  ``forward``)
 - tp: hidden/ffn/vocab dims sharded over 'model' (Megatron-style
   column->row parallel pairs; XLA inserts the all-reduces)
 - sp: the residual stream between blocks is sequence-sharded over 'model'
@@ -34,6 +36,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import CP_ROUTES, causal_attention_route
+from ..parallel.mesh import data_replicas, with_replica_dim
 
 Params = Dict[str, Any]
 
@@ -200,7 +203,41 @@ def forward(
     res_seq_ax = "seq" if has_seq else "model"  # residual-stream seq sharding
     act_seq_ax = "seq" if cp else None  # in-block activation seq sharding
 
-    x = params["embed"].astype(c.dtype)[tokens]  # (B, S, D)
+    layers, embed = params["layers"], params["embed"]
+    R = data_replicas(mesh, B)
+
+    def mm(h, w):
+        """(B, S, K) times a matrix, or replica by replica times its R copies."""
+        w = w.astype(c.dtype)
+        if w.ndim == 2:
+            return h @ w
+        y = jnp.einsum("rbsk,rkn->rbsn", h.reshape(R, B // R, S, -1), w)
+        return y.reshape(B, S, -1)
+
+    if R > 1:
+        # dp: every replica multiplies by its own copy of each matrix (the
+        # replica dimension: sharded over 'data', so a view of the shard a
+        # device already holds). A matrix's gradient then stays local
+        # through the backward scan, the tied embedding's two contributions
+        # (lookup and head) add up locally, and the sum over that dimension,
+        # the only gradient traffic over 'data', runs once per leaf after
+        # the scan, on the stacked (L, ...) gradient. Cast first: the sum
+        # runs in the dtype that is broadcast. The norm scales (two
+        # D-vectors a layer) and the MoE leaves (``moe_ffn`` takes no
+        # replica dimension) stay on the plain path: reduced where each
+        # use produces them.
+        p_specs = param_specs(c)
+        layers = dict(layers)
+        for name in ("attn_qkv", "attn_out", "ff_in", "ff_out"):
+            if name in layers:
+                layers[name] = with_replica_dim(
+                    layers[name].astype(c.dtype), p_specs["layers"][name], mesh, dim=1
+                )
+        embed = with_replica_dim(embed.astype(c.dtype), p_specs["embed"], mesh)
+        x = jax.vmap(lambda e, t: e[t])(embed, tokens.reshape(R, B // R, S))
+        x = x.reshape(B, S, c.d_model)
+    else:
+        x = embed.astype(c.dtype)[tokens]  # (B, S, D)
     pos = jnp.arange(S)[None, :, None]
     dims = jnp.arange(c.d_model // 2)[None, None, :]
     inv_freq = 10000.0 ** (-2.0 * dims / c.d_model)
@@ -230,7 +267,7 @@ def forward(
         x = cs(x, P("data", res_seq_ax, None))
         h = _rmsnorm(x, layer["ln1_scale"])
         h = cs(h, P("data", act_seq_ax, None))
-        qkv = h @ layer["attn_qkv"].astype(c.dtype)  # (B,S,3D)
+        qkv = mm(h, layer["attn_qkv"])  # (B,S,3D)
         qkv = cs(qkv, P("data", act_seq_ax, "model"))
         q, k, v = jnp.split(qkv, 3, axis=-1)
 
@@ -242,7 +279,7 @@ def forward(
         attn = attend(heads(q), heads(k), heads(v), in_layout=zz_hoist)
         attn = attn.reshape(B, S, c.d_model)
         attn = cs(attn, P("data", act_seq_ax, "model"))
-        x = x + cs(attn @ layer["attn_out"].astype(c.dtype), P("data", res_seq_ax, None))
+        x = x + cs(mm(attn, layer["attn_out"]), P("data", res_seq_ax, None))
 
         h = _rmsnorm(x, layer["ln2_scale"])
         if c.n_experts > 0:
@@ -262,15 +299,15 @@ def forward(
             aux = aux + l_aux
         else:
             h = cs(h, P("data", act_seq_ax, None))
-            h = jax.nn.gelu(h @ layer["ff_in"].astype(c.dtype))
+            h = jax.nn.gelu(mm(h, layer["ff_in"]))
             h = cs(h, P("data", act_seq_ax, "model"))
-            x = x + cs(h @ layer["ff_out"].astype(c.dtype), P("data", res_seq_ax, None))
+            x = x + cs(mm(h, layer["ff_out"]), P("data", res_seq_ax, None))
         return (x, aux), None
 
-    (x, aux), _ = jax.lax.scan(block, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    (x, aux), _ = jax.lax.scan(block, (x, jnp.zeros((), jnp.float32)), layers)
     x = cs(x, P("data", act_seq_ax, None))
     x = _rmsnorm(x, params["ln_f_scale"])
-    logits = x @ params["embed"].astype(c.dtype).T
+    logits = mm(x, jnp.swapaxes(embed.astype(c.dtype), -1, -2))
     if zz_hoist:
         logits = jnp.take(logits, zz_inv, axis=1)  # back to global order
     logits = cs(logits, P("data", act_seq_ax, "model"))

@@ -15,6 +15,7 @@ import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -110,6 +111,33 @@ def optax_state_specs(p_specs: Any, opt_state: Any) -> Tuple[Any, ...]:
     return tuple(map_entry(e) for e in opt_state)
 
 
+# ------------------------------------------------------- replica dimension
+
+
+def data_replicas(mesh: Optional[Mesh], batch: int) -> int:
+    """How many data-parallel replicas a step on ``mesh`` gives a batch of
+    ``batch`` rows: the size of the 'data' axis, or 1 without a mesh,
+    without the axis, or where it does not divide the batch."""
+    n = 1 if mesh is None else mesh.shape.get("data", 1)
+    return n if batch % n == 0 else 1
+
+
+def with_replica_dim(w: jax.Array, spec: P, mesh: Mesh, *, dim: int = 0) -> jax.Array:
+    """``w`` (laid out as ``spec``) with a replica dimension inserted at
+    ``dim``: one copy per member of the 'data' axis, sharded over it, so
+    every device's piece is the shard of ``w`` it already holds and no
+    bytes move. A matmul batched over that dimension against the replica's
+    rows of the batch is local, and so is its transpose: the weight's
+    gradient comes out per replica, and autodiff's transpose of the
+    broadcast, a sum over the dimension, is the one reduction over 'data',
+    wherever the caller's program puts this call (outside a `lax.scan`:
+    once for the stacked leaf, after the backward scan)."""
+    n = mesh.shape["data"]
+    w = jnp.broadcast_to(jnp.expand_dims(w, dim), (*w.shape[:dim], n, *w.shape[dim:]))
+    spec = tuple(spec) + (None,) * (w.ndim - 1 - len(spec))
+    return jax.lax.with_sharding_constraint(w, NamedSharding(mesh, P(*spec[:dim], "data", *spec[dim:])))
+
+
 # ------------------------------------------------- collectives of a program
 
 _COLLECTIVE_KINDS = (
@@ -126,11 +154,50 @@ _CALLEE = re.compile(
     r"\b(body|condition|calls|to_apply|true_computation|false_computation)=%([^\s,)}]+)"
 )
 _BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_GROUPS = re.compile(
+    r"\b(?:replica_groups|source_target_pairs)="
+    r"(?:\{([0-9,{} ]*)\}|\[([0-9,]+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?)"
+)
 
 
 def _array_bytes(dtype: str, dims: Tuple[int, ...]) -> int:
     bits = re.search(r"[0-9]+", dtype)  # bf16, f8e4m3fn, c64; pred has none
     return max(1, int(bits.group()) // 8 if bits else 1) * math.prod(dims)
+
+
+def _ints(text: str) -> List[int]:
+    return [int(x) for x in re.findall(r"[0-9]+", text)]
+
+
+def _groups(line: str) -> List[Tuple[int, ...]]:
+    """The device groups of a collective instruction: ``{{0,2},{1,3}}``
+    written out, or the iota form ``[groups,size]<=[dims]T(perm)``: the
+    ids ``0..n`` reshaped to ``dims``, transposed, cut into rows."""
+    m = _GROUPS.search(line)
+    if not m:
+        return []
+    listed, shape, dims, perm = m.groups()
+    if shape is None:
+        return [tuple(_ints(g)) for g in re.findall(r"\{([0-9, ]*)\}", listed)]
+    ids = np.arange(math.prod(_ints(dims))).reshape(_ints(dims))
+    if perm:
+        ids = ids.transpose(_ints(perm))
+    return [tuple(int(i) for i in row) for row in ids.reshape(_ints(shape))]
+
+
+def spanned_axes(groups: Sequence[Sequence[int]], mesh: Mesh) -> Tuple[str, ...]:
+    """The mesh axes along which the members of any of ``groups`` (a
+    `collectives` record's ``groups``) differ: the axes the collective
+    moves data across. An id is a position in ``mesh.devices.flat``, the
+    order `jax.jit` assigns partitions in. No groups: every axis larger
+    than 1 (``replica_groups={}`` is all devices)."""
+    if not groups:
+        return tuple(a for a in mesh.axis_names if mesh.shape[a] > 1)
+    spans = np.zeros(len(mesh.axis_names), bool)
+    for group in groups:
+        coords = np.stack(np.unravel_index(list(group), mesh.devices.shape), axis=1)
+        spans |= (coords != coords[0]).any(axis=0)
+    return tuple(a for a, s in zip(mesh.axis_names, spans) if s)
 
 
 def collectives(hlo_text: str) -> List[Dict[str, Any]]:
@@ -139,7 +206,10 @@ def collectives(hlo_text: str) -> List[Dict[str, Any]]:
     One record per collective instruction: ``kind`` (all-reduce, all-gather,
     reduce-scatter, all-to-all, collective-permute, collective-broadcast),
     ``name``, ``shapes`` (``(dtype, dims)`` of each array it produces on one
-    device; a combined all-reduce produces several), ``bytes`` (their sum)
+    device; a combined all-reduce produces several), ``bytes`` (their sum),
+    ``groups`` (the devices it runs among, as tuples of partition ids; the
+    (source, target) pairs of a permute; ``[]`` where the instruction names
+    none, which is all of them: `spanned_axes` turns them into mesh axes)
     and ``times``, how often one run of the program executes it: the
     product of the trip counts of the loops around it. A trip count is the
     loop's ``known_trip_count``, else the constant its condition compares
@@ -204,6 +274,7 @@ def collectives(hlo_text: str) -> List[Dict[str, Any]]:
                     "name": name,
                     "shapes": shapes,
                     "bytes": sum(_array_bytes(*s) for s in shapes),
+                    "groups": _groups(line),
                     "times": times,
                 }
             )
