@@ -328,11 +328,6 @@ _SUMMARY_LEGS = (
         {"TORCHSNAPSHOT_TPU_LAZY_RESTORE": "always"},
     ),
     _SummaryLeg(
-        "autotune", "autotune.py", "autotune", "BENCH_r16.json",
-        "take throughput vs hand-tuned p50 (x) / takes to convergence",
-        {"TORCHSNAPSHOT_TPU_AUTOTUNE": "fresh/auto per leg"},
-    ),
-    _SummaryLeg(
         "georep", "georep_rpo.py", "georep_rpo", "BENCH_r17.json",
         "seconds of remote-tier recovery point vs journal cadence on a "
         "20 MB/s WAN",

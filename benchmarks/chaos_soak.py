@@ -1130,111 +1130,6 @@ def tenancy_overhead(trials: int = 5) -> None:
     )
 
 
-def autotune_overhead(trials: int = 5) -> None:
-    """Closed-loop autotune overhead on a ~2 GiB save: the shipping
-    default (``TORCHSNAPSHOT_TPU_AUTOTUNE=auto``) vs hard-disabled
-    (``=never``, one env check per election). Telemetry is enabled on
-    BOTH legs so the attribution verdict exists — the delta isolates
-    the tuner machinery, not the bus. The governor is reset before
-    EVERY save (both legs): each save models a fresh process's FIRST
-    take, which on the auto leg walks the full plane — mode parse,
-    profile probe against the root journal, election resolution,
-    post-commit verdict scoring, and the profile journal append —
-    while excluding cross-save learning drift (on this host's
-    page-cache-noisy disk the walls swing 20x for identical settings;
-    what the tuner LEARNS from such a signal is benchmarks/autotune.py's
-    problem, gated there under a deterministic storage model — this
-    gate prices the machinery). Asserts best-vs-best delta < 1% with a
-    50 ms floor (ISSUE 19 acceptance; same paired/alternating recipe
-    as the gates above)."""
-    import numpy as np
-
-    from torchsnapshot_tpu import Snapshot, StateDict, telemetry
-    from torchsnapshot_tpu.scheduler import reset_io_governor
-
-    nbytes = 2 << 30
-    n_arrays = 8
-    per = nbytes // n_arrays // 4
-    state = {
-        "model": StateDict(
-            **{
-                f"p{i}": np.random.default_rng(i)
-                .standard_normal(per)
-                .astype(np.float32)
-                for i in range(n_arrays)
-            }
-        )
-    }
-
-    def timed_save() -> float:
-        reset_io_governor()  # every save is a fresh process's first take
-        root = tempfile.mkdtemp(prefix="autotune_overhead_")
-        try:
-            t0 = time.perf_counter()
-            Snapshot.take(os.path.join(root, "s"), state)
-            return time.perf_counter() - t0
-        finally:
-            shutil.rmtree(root, ignore_errors=True)
-
-    def leg(mode: str) -> float:
-        saved = os.environ.get("TORCHSNAPSHOT_TPU_AUTOTUNE")
-        os.environ["TORCHSNAPSHOT_TPU_AUTOTUNE"] = mode
-        try:
-            return timed_save()
-        finally:
-            if saved is None:
-                os.environ.pop("TORCHSNAPSHOT_TPU_AUTOTUNE", None)
-            else:
-                os.environ["TORCHSNAPSHOT_TPU_AUTOTUNE"] = saved
-
-    telemetry.set_enabled(True)
-    try:
-        # Fresh governor + discarded warmup (staging-pool first touch).
-        reset_io_governor()
-        leg("never")
-        off_walls, auto_walls = [], []
-        max_pairs = 2 * trials
-        for pair in range(max_pairs):
-            if pair % 2 == 0:
-                off = leg("never")
-                auto = leg("auto")
-            else:
-                auto = leg("auto")
-                off = leg("never")
-            off_walls.append(off)
-            auto_walls.append(auto)
-            budget_s = max(0.01 * min(off_walls), 0.05)
-            if pair + 1 >= trials and (
-                min(auto_walls) - min(off_walls)
-            ) < budget_s:
-                break
-    finally:
-        telemetry.set_enabled(False)
-        reset_io_governor()
-    off_best = min(off_walls)
-    auto_best = min(auto_walls)
-    budget_s = max(0.01 * off_best, 0.05)
-    delta = (auto_best - off_best) / off_best
-    report(
-        "autotune_overhead",
-        {
-            "gib": round(nbytes / (1 << 30), 2),
-            "pairs": len(off_walls),
-            "never_trials_s": [round(t, 3) for t in off_walls],
-            "auto_trials_s": [round(t, 3) for t in auto_walls],
-            "never_best_s": round(off_best, 3),
-            "auto_best_s": round(auto_best, 3),
-            "overhead_pct": round(delta * 100, 3),
-        },
-        data_bytes=nbytes,
-    )
-    assert (auto_best - off_best) < budget_s, (
-        f"autotune overhead {delta * 100:.2f}% over the 1% budget "
-        f"(never best {off_best:.3f}s vs auto best {auto_best:.3f}s, "
-        f"floor 50 ms)"
-    )
-
-
 def georep_overhead(trials: int = 5) -> None:
     """Disabled-path overhead of the geo-replication tier (ISSUE 20): a
     ~2 GiB CheckpointManager save with no remote configured (the
@@ -1347,7 +1242,6 @@ def main() -> None:
         journal_overhead(args.trials)
         distrib_overhead(args.trials)
         tenancy_overhead(args.trials)
-        autotune_overhead(args.trials)
         georep_overhead(args.trials)
 
 

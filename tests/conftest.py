@@ -45,13 +45,3 @@ def _default_write_batching_off(monkeypatch):
     in-test setenv runs after this autouse fixture)."""
     monkeypatch.setenv("TORCHSNAPSHOT_TPU_ENABLE_BATCHING", "0")
 
-
-@pytest.fixture(autouse=True)
-def _default_autotune_off(monkeypatch):
-    """Integration tests assert deterministic election outcomes (chunk
-    layouts, binding verdicts, exact file counts) — a live perturbation
-    trial changes those by design, and the process-global governor would
-    carry learned profiles ACROSS tests. Pin the tuner off suite-wide;
-    autotune tests opt back in with monkeypatch.delenv/setenv (their
-    in-test patch runs after this autouse fixture)."""
-    monkeypatch.setenv("TORCHSNAPSHOT_TPU_AUTOTUNE", "never")

@@ -73,7 +73,7 @@ def _take_and_restore(tmp, enabled):
     # streamed reads (the large leaf goes through the device row sink).
     env = {"TORCHSNAPSHOT_TPU_SUB_CHUNK_BYTES": str(SUB_CHUNK), "TORCHSNAPSHOT_TPU_STREAM_READS": "always",
            "TORCHSNAPSHOT_TPU_STREAM_WRITES": "never",
-           "TORCHSNAPSHOT_TPU_ENABLE_BATCHING": "0", "TORCHSNAPSHOT_TPU_AUTOTUNE": "never"}
+           "TORCHSNAPSHOT_TPU_ENABLE_BATCHING": "0"}
     telemetry.reset()
     telemetry.set_enabled(enabled)
     try:

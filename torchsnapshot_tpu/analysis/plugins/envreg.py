@@ -34,10 +34,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from ..core import Finding, FunctionInfo, Module, PACKAGE_DIR, REPO_DIR, Project, dotted
 
-RULES = (
-    "env-unregistered", "env-undocumented", "env-dead", "env-dynamic",
-    "env-ungoverned",
-)
+RULES = ("env-unregistered", "env-undocumented", "env-dead", "env-dynamic")
 
 ENV_PREFIX = "TORCHSNAPSHOT_TPU_"
 
@@ -109,37 +106,11 @@ ENV_REGISTRY = frozenset({
     "TORCHSNAPSHOT_TPU_TREND_THRESHOLD",
     "TORCHSNAPSHOT_TPU_UPDATE_PUSH",
     "TORCHSNAPSHOT_TPU_VERIFY",
-    "TORCHSNAPSHOT_TPU_AUTOTUNE",
     "TORCHSNAPSHOT_TPU_GEOREP",
     "TORCHSNAPSHOT_TPU_GEOREP_INTERVAL_S",
     "TORCHSNAPSHOT_TPU_GEOREP_BACKLOG",
     "TORCHSNAPSHOT_TPU_GEOREP_DRAIN_S",
 })
-
-#: Election-site governance (rule ``env-ungoverned``). Every knob the
-#: IOGovernor's elections consult (scheduler.ELECTION_KNOBS) MUST
-#: declare here how it interacts with the closed-loop autotuner
-#: (ISSUE 19): ``override`` — a set value pins the election and the
-#: tuner never perturbs that dimension; ``bound`` — constrains the
-#: tuner's search range, never pins a value; ``switch`` — selects the
-#: autotune mode itself. A knob added to an election site without a row
-#: here has UNDEFINED precedence against learned profiles — exactly the
-#: ambiguity the env-override > learned-profile > heuristic contract
-#: (docs/source/utilities.rst) exists to rule out.
-ENV_GOVERNANCE: Dict[str, str] = {
-    "TORCHSNAPSHOT_TPU_SUB_CHUNK_BYTES": "override",
-    "TORCHSNAPSHOT_TPU_SUB_CHUNK_MIN_BYTES": "bound",
-    "TORCHSNAPSHOT_TPU_SUB_CHUNK_MAX_BYTES": "bound",
-    "TORCHSNAPSHOT_TPU_IO_CONCURRENCY": "override",
-    "TORCHSNAPSHOT_TPU_PREVERIFY": "override",
-    "TORCHSNAPSHOT_TPU_STREAM_READS": "override",
-    "TORCHSNAPSHOT_TPU_STREAM_WRITES": "override",
-    "TORCHSNAPSHOT_TPU_NATIVE_IO": "override",
-    "TORCHSNAPSHOT_TPU_COOP_RESTORE": "override",
-    "TORCHSNAPSHOT_TPU_RESHARD": "override",
-    "TORCHSNAPSHOT_TPU_SEED_RESTORE": "override",
-    "TORCHSNAPSHOT_TPU_AUTOTUNE": "switch",
-}
 
 UTILITIES_RST = os.path.join(REPO_DIR, "docs", "source", "utilities.rst")
 
@@ -308,55 +279,6 @@ def run_pass(project: Project) -> List[Finding]:
         self_mod = project.module(
             os.path.join("analysis", "plugins", "envreg.py").replace(os.sep, "/")
         )
-
-        def _self_line(needle: str) -> int:
-            if self_mod is not None:
-                for i, text in enumerate(self_mod.lines, start=1):
-                    if needle in text:
-                        return i
-            return 1
-
-        self_rel = (
-            self_mod.rel if self_mod is not None
-            else "torchsnapshot_tpu/analysis/plugins/envreg.py"
-        )
-        # Governance closure against the scheduler's authoritative
-        # election-knob set. A lazy import: the analysis runner also
-        # lints forks/vendored copies where the import may not resolve.
-        try:
-            from ...scheduler import ELECTION_KNOBS
-        except ImportError:
-            ELECTION_KNOBS = frozenset()
-        for name in sorted(ELECTION_KNOBS - set(ENV_GOVERNANCE)):
-            findings.setdefault(
-                ("env-ungoverned", name, 0),
-                Finding(
-                    rule="env-ungoverned", file=self_rel,
-                    line=_self_line("ENV_GOVERNANCE"),
-                    message=(
-                        f"{name} feeds an IOGovernor election site "
-                        "(scheduler.ELECTION_KNOBS) but declares no "
-                        "override-vs-tuned status in ENV_GOVERNANCE — add "
-                        "a row ('override', 'bound', or 'switch') so its "
-                        "precedence against learned profiles is pinned"
-                    ),
-                ),
-            )
-        if ELECTION_KNOBS:
-            for name in sorted(set(ENV_GOVERNANCE) - ELECTION_KNOBS):
-                findings.setdefault(
-                    ("env-ungoverned", name, 1),
-                    Finding(
-                        rule="env-ungoverned", file=self_rel,
-                        line=_self_line(f'"{name}"'),
-                        message=(
-                            f"{name} declares governance but is not in "
-                            "scheduler.ELECTION_KNOBS — the election site "
-                            "was removed; delete the stale ENV_GOVERNANCE "
-                            "row (or re-register the knob)"
-                        ),
-                    ),
-                )
         for name in sorted(ENV_REGISTRY - seen_names):
             line = 1
             if self_mod is not None:

@@ -46,7 +46,6 @@ ALLOWLIST = {
 BENCHMARK_ALLOWLIST = {
     "async_stall.py",
     "attention_bench.py",
-    "autotune.py",  # hand-tuned vs learned take walls time wall clock
     "bench_utils.py",
     "chaos_soak.py",  # soak wall + the disabled-injector overhead gate
     "coop_restore.py",  # fan-out vs direct restore walls time wall clock

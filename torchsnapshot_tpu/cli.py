@@ -1468,36 +1468,6 @@ def cmd_explain(args: argparse.Namespace) -> int:
 
     from .telemetry import TELEMETRY_SUMMARY_FNAME, critpath
 
-    if getattr(args, "profiles", False):
-        # The governor's learned-profile story for this root: per
-        # profile key the converged settings, smoothed score, and the
-        # recent perturbation trail — the full closed-loop decision
-        # trail (autotune.py; persisted by scheduler.observe_verdict
-        # into the history journal).
-        from .telemetry import history
-
-        target = args.path.rstrip("/")
-        records = history.load_profiles(target)
-        if not records:
-            # ``explain --profiles <snapshot>`` should work too: the
-            # journal lives in the PARENT directory (the manager root).
-            records = history.load_profiles(
-                os.path.dirname(os.path.abspath(target))
-            )
-        if not records:
-            print(
-                f"error: no learned profiles at {args.path} (expected "
-                "profile records in .telemetry_history.jsonl — recorded "
-                "when takes/restores run with TORCHSNAPSHOT_TPU_AUTOTUNE "
-                "unset or 'auto').",
-                file=sys.stderr,
-            )
-            return 2
-        if args.json:
-            print(json.dumps(records, indent=1))
-        else:
-            print(history.render_profiles(records))
-        return 0
     doc, err = _read_snapshot_json(args.path, critpath.ATTRIBUTION_FNAME)
     if doc is None or not doc.get("fleet"):
         # Fallback: re-derive from the telemetry summary document's
@@ -1955,10 +1925,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="dump the raw attribution document")
     p.add_argument("-v", "--verbose", action="store_true",
                    help="include the governor's recorded elections")
-    p.add_argument("--profiles", action="store_true",
-                   help="render the autotuner's learned I/O profiles for "
-                        "this root instead: per profile key the converged "
-                        "settings, score, and recent perturbation trail")
     p.set_defaults(fn=cmd_explain)
 
     p = sub.add_parser(

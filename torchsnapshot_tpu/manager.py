@@ -193,22 +193,6 @@ class CheckpointManager:
             journal.register_commit_hook(_georep_on_epoch)
             if self.preemption is not None:
                 self.preemption.add_consume_hook(rep.drain)
-        # Warm-start the IOGovernor's learned I/O profiles from this
-        # root's history journal (autotune.py) so the FIRST managed save
-        # already runs converged elections. Local roots only; one env
-        # check when TORCHSNAPSHOT_TPU_AUTOTUNE=never; never raises.
-        try:
-            from .scheduler import autotune_mode, io_governor
-            from .storage_plugin import local_fs_root
-
-            if autotune_mode() != "never":
-                governor = io_governor()
-                governor.note_world(PGWrapper(self.pg).get_world_size())
-                local = local_fs_root(self.root)
-                if local is not None:
-                    governor.load_profiles(os.path.abspath(local))
-        except Exception:  # noqa: BLE001 - warm start is advisory
-            logger.debug("profile warm start skipped", exc_info=True)
 
     def _register_tenant(self) -> None:
         """Publish this tenant's registry row (rank 0, once, best

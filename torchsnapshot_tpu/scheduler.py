@@ -81,7 +81,6 @@ from typing import Any, Dict, List, Optional, Set
 import psutil
 
 from . import faultinject, telemetry
-from . import autotune as _autotune
 from .telemetry import forensics
 from .io_types import (
     ReadIO,
@@ -180,36 +179,6 @@ _NATIVE_FALLBACK_MARGIN = 0.75
 # it back — a rate hovering at the knee (EWMA jitter) must not flip-flop
 # a fast path on and off between consecutive ops.
 _KNEE_MARGIN = 0.10
-# Hard cap for tuned/heuristic I/O concurrency: the autotuner's climb
-# must stay inside the range the pipeline was designed for (an explicit
-# env pin may still exceed it).
-_IO_CONCURRENCY_CAP = 32
-
-# The closed-loop autotune mode parser lives with the controller
-# (autotune.py); re-exported here because the governor is its consumer.
-AUTOTUNE_ENV_VAR = _autotune.AUTOTUNE_ENV_VAR
-autotune_mode = _autotune.autotune_mode
-
-#: Every env knob consulted by an IOGovernor election site — the knobs
-#: whose role shifted from "the tuning interface" to "operator override
-#: above the learned profiles". The envreg tsalint pass cross-checks
-#: this set against ENV_GOVERNANCE (analysis/plugins/envreg.py): each
-#: knob must declare whether it overrides elections, bounds them, or
-#: switches the tuner itself.
-ELECTION_KNOBS = frozenset({
-    "TORCHSNAPSHOT_TPU_SUB_CHUNK_BYTES",
-    "TORCHSNAPSHOT_TPU_SUB_CHUNK_MIN_BYTES",
-    "TORCHSNAPSHOT_TPU_SUB_CHUNK_MAX_BYTES",
-    "TORCHSNAPSHOT_TPU_IO_CONCURRENCY",
-    "TORCHSNAPSHOT_TPU_PREVERIFY",
-    "TORCHSNAPSHOT_TPU_STREAM_READS",
-    "TORCHSNAPSHOT_TPU_STREAM_WRITES",
-    "TORCHSNAPSHOT_TPU_NATIVE_IO",
-    "TORCHSNAPSHOT_TPU_COOP_RESTORE",
-    "TORCHSNAPSHOT_TPU_RESHARD",
-    "TORCHSNAPSHOT_TPU_SEED_RESTORE",
-    "TORCHSNAPSHOT_TPU_AUTOTUNE",
-})
 
 
 class IOGovernor:
@@ -243,15 +212,9 @@ class IOGovernor:
     (page-cache flush, noisy neighbor) moves a tunable halfway at most,
     and the next clean measurement pulls it back.
 
-    **Closed loop** (ROADMAP item 4, ``TORCHSNAPSHOT_TPU_AUTOTUNE``):
-    every election site resolves env override -> learned profile ->
-    measured-rate heuristic, through one shared :class:`autotune.
-    Election` record. The controller (autotune.AutoTuner) perturbs at
-    most one tunable per operation, scores it against the critical-path
-    verdict fed back by ``observe_verdict`` after commit, and persists
-    converged settings per ``(storage class, world size, binding
-    category)`` into the root's history journal — ``load_profiles``
-    warm-starts a fresh process from them.
+    Every election resolves env override > measured-rate heuristic: a
+    function of the environment, the recorded rates and the knee band's
+    one bit of memory, the same with telemetry on or off.
     """
 
     _EWMA_ALPHA = 0.5
@@ -261,15 +224,12 @@ class IOGovernor:
         self._write_bps: Dict[str, float] = {}
         self._read_bps: Dict[str, float] = {}
         self._hash_bps: Optional[float] = None
-        self._tuner = _autotune.AutoTuner()
-        #: Last Election per (dim, plugin): the decision-change detector
-        #: that keeps ``governor.elect`` flight events to transitions
-        #: (io_concurrency is consulted inside dispatch loops).
-        self._elections: Dict[Any, _autotune.Election] = {}
+        #: Last (value, source) per (dim, plugin): the decision-change
+        #: detector that keeps ``governor.elect`` flight events to
+        #: transitions (io_concurrency is consulted inside dispatch loops).
+        self._elections: Dict[Any, Any] = {}
         #: Boolean gate memory for the knee dead band (_banded).
         self._gate_state: Dict[Any, bool] = {}
-        #: Roots whose profile records were already loaded (once each).
-        self._profile_roots: Set[str] = set()
 
     # ------------------------------------------------------- recording
 
@@ -342,27 +302,20 @@ class IOGovernor:
         **inputs: Any,
     ) -> Any:
         """Every election site funnels its decision through here: one
-        shared :class:`autotune.Election` record per (dim, plugin), a
-        ``governor.elect`` flight event WHEN THE DECISION CHANGES (the
-        hot dispatch loops re-consult io_concurrency; steady-state
-        re-elections must not flood the ring), and the profile key
-        attached when a learned profile or trial made the call."""
-        profile = None
-        if source in ("profile", "trial"):
-            op = dim.rsplit(".", 1)[1] if "." in dim else "read"
-            profile = self._tuner.key_for(plugin or "", op)
-        election = _autotune.Election(
-            site, dim, plugin, value, source, profile=profile, inputs=inputs
-        )
+        ``governor.elect`` flight event per (dim, plugin) WHEN THE
+        DECISION CHANGES (the hot dispatch loops re-consult
+        io_concurrency; steady-state re-elections must not flood the
+        ring). ``source`` is ``env`` (operator override) or
+        ``heuristic`` (sized from the measured rates)."""
         key = (dim, plugin or "")
         with self._lock:
-            prev = self._elections.get(key)
-            changed = (
-                prev is None or prev.value != value or prev.source != source
-            )
-            self._elections[key] = election
+            changed = self._elections.get(key) != (value, source)
+            self._elections[key] = (value, source)
         if changed:
-            telemetry.record_election(**election.as_fields())
+            named = {"plugin": plugin} if plugin else {}
+            telemetry.record_election(
+                site=site, dim=dim, value=value, source=source, **named, **inputs
+            )
         return value
 
     def _banded(
@@ -384,21 +337,13 @@ class IOGovernor:
             self._gate_state[key] = decision
         return decision
 
-    def _tuned(self, dim: str, plugin: Optional[str], op: str):
-        """Learned-profile / armed-trial resolution for one dimension,
-        or None (cold start / autotune off). The ``never`` mode costs
-        exactly this one env check."""
-        if _autotune.autotune_mode() == "never":
-            return None
-        return self._tuner.resolve(dim, plugin or "", op)
-
     # ---------------------------------------------------------- tunables
 
     def sub_chunk_bytes(self, plugin: Optional[str] = None, op: str = "write") -> int:
-        """Streaming sub-chunk size for ``op`` ("write"/"read") —
-        env override > learned profile > sized from the MATCHING
-        measured bandwidth (a fast local save must not size a later
-        network restore's read windows, and vice versa)."""
+        """Streaming sub-chunk size for ``op`` ("write"/"read") — env
+        override > sized from the MATCHING measured bandwidth (a fast
+        local save must not size a later network restore's read
+        windows, and vice versa)."""
         dim = f"sub_chunk.{op}"
         pinned = os.environ.get(SUB_CHUNK_ENV_VAR, "").strip()
         if pinned:
@@ -415,19 +360,6 @@ class IOGovernor:
         lo = _env_int(SUB_CHUNK_MIN_ENV_VAR, _DEFAULT_SUB_CHUNK_MIN_BYTES)
         hi = _env_int(SUB_CHUNK_MAX_ENV_VAR, _DEFAULT_SUB_CHUNK_MAX_BYTES)
         hi = max(lo, hi)
-        tuned = self._tuned(dim, plugin, op)
-        if tuned is not None:
-            value, source = tuned
-            try:
-                value = int(value)
-            except (TypeError, ValueError):
-                value = _DEFAULT_SUB_CHUNK_BYTES
-            # Learned values stay inside the env bounds (trials were
-            # generated inside them; a profile learned under different
-            # bounds is clamped into today's).
-            return self._resolved(
-                "sub_chunk", dim, plugin, min(max(value, lo), hi), source
-            )
         bps = self.read_bps(plugin) if op == "read" else self.write_bps(plugin)
         if bps is None:
             return self._resolved(
@@ -446,11 +378,11 @@ class IOGovernor:
     def io_concurrency(
         self, op: str = "write", plugin: Optional[str] = None
     ) -> int:
-        """In-flight storage requests for ``op`` ("write"/"read") —
-        env override > learned profile > tuned from the MATCHING
-        measured rate (a fast local save must not clamp concurrency for
-        a later latency-bound network restore, and vice versa), for
-        ``plugin`` when it has a recorded rate."""
+        """In-flight storage requests for ``op`` ("write"/"read") — env
+        override > tuned from the MATCHING measured rate (a fast local
+        save must not clamp concurrency for a later latency-bound
+        network restore, and vice versa), for ``plugin`` when it has a
+        recorded rate."""
         dim = f"io_concurrency.{op}"
         raw = os.environ.get(IO_CONCURRENCY_ENV_VAR, "").strip()
         if raw:
@@ -460,18 +392,6 @@ class IOGovernor:
                 pass  # warned at import time by _env_int
             else:
                 return self._resolved("io_concurrency", dim, plugin, value, "env")
-        tuned = self._tuned(dim, plugin, op)
-        if tuned is not None:
-            value, source = tuned
-            try:
-                value = int(value)
-            except (TypeError, ValueError):
-                value = 0
-            if value >= 1:
-                return self._resolved(
-                    "io_concurrency", dim, plugin,
-                    min(value, _IO_CONCURRENCY_CAP), source,
-                )
         default = min(16, max(8, 2 * _CPU_COUNT))
         table = self.read_bps if op == "read" else self.write_bps
         bps = table(plugin)
@@ -505,12 +425,6 @@ class IOGovernor:
             return self._resolved("preverify", "preverify", plugin, True, "env")
         if mode == "never":
             return self._resolved("preverify", "preverify", plugin, False, "env")
-        tuned = self._tuned("preverify", plugin, "read")
-        if tuned is not None:
-            value, source = tuned
-            return self._resolved(
-                "preverify", "preverify", plugin, bool(value), source
-            )
         hash_bps = self.hash_bps()
         read_bps = self.read_bps(plugin) if plugin is not None else self.read_bps()
         if hash_bps is None or read_bps is None:
@@ -547,14 +461,8 @@ class IOGovernor:
           memcpy-speed local reads (page cache) the engine measurably
           loses to the mmap/pread paths, so native reads engage only on
           measured latency-bound storage (no measurement = no evidence
-          = Python path, the read-side status quo bias). The engine choice
-        is a tunable dimension (``native.write``/``native.read``): a
-        learned profile or armed trial overrides the margin logic."""
+          = Python path, the read-side status quo bias)."""
         dim = f"native.{op}"
-        tuned = self._tuned(dim, plugin, op)
-        if tuned is not None:
-            value, source = tuned
-            return self._resolved("native", dim, plugin, bool(value), source)
         table = self._read_bps if op == "read" else self._write_bps
         with self._lock:
             native = table.get(f"{plugin}.native") if plugin else None
@@ -616,12 +524,8 @@ class IOGovernor:
 
     def _knee_gate(self, gate: str, plugin: Optional[str]) -> bool:
         """The shared latency-bound election (coop restore, planned
-        reshard, seed restore): learned profile > the measured-rate
-        knee with the flip-flop dead band."""
-        tuned = self._tuned(gate, plugin, "read")
-        if tuned is not None:
-            value, source = tuned
-            return self._resolved(gate, gate, plugin, bool(value), source)
+        reshard, seed restore): the measured-rate knee with the
+        flip-flop dead band."""
         bps = self.read_bps(plugin) if plugin is not None else self.read_bps()
         value = bps is not None and self._banded(
             gate, plugin, bps, _STREAM_READ_LATENCY_BPS
@@ -630,171 +534,6 @@ class IOGovernor:
             gate, gate, plugin, value, "heuristic",
             read_bps=round(bps) if bps is not None else None,
         )
-
-    # ------------------------------------------------ closed-loop autotune
-
-    def note_world(self, world_size: int) -> None:
-        self._tuner.note_world(world_size)
-
-    def _trial_dims(self, op: str, plugin: str) -> Dict[str, Dict[str, Any]]:
-        """The dimensions this op direction may perturb, with their
-        current incumbent values and env bounds. An env-pinned knob is
-        never perturbed — overrides remove the dimension from the
-        experiment entirely."""
-        dims: Dict[str, Dict[str, Any]] = {}
-        if not os.environ.get(SUB_CHUNK_ENV_VAR, "").strip():
-            lo = _env_int(SUB_CHUNK_MIN_ENV_VAR, _DEFAULT_SUB_CHUNK_MIN_BYTES)
-            hi = max(
-                lo, _env_int(SUB_CHUNK_MAX_ENV_VAR, _DEFAULT_SUB_CHUNK_MAX_BYTES)
-            )
-            dims[f"sub_chunk.{op}"] = {
-                "value": self.sub_chunk_bytes(plugin, op=op),
-                "kind": "geom", "lo": lo, "hi": hi, "quantum": 1 << 20,
-            }
-        if not os.environ.get(IO_CONCURRENCY_ENV_VAR, "").strip():
-            dims[f"io_concurrency.{op}"] = {
-                "value": self.io_concurrency(op, plugin),
-                "kind": "geom", "lo": 1, "hi": _IO_CONCURRENCY_CAP,
-                "quantum": 1,
-            }
-        # Engine choice joins the experiment only once the native engine
-        # has a measured per-stream rate for this plugin — toggling an
-        # engine that never ran would score nothing.
-        with self._lock:
-            table = self._read_bps if op == "read" else self._write_bps
-            has_native = f"{plugin}.native" in table
-        if has_native:
-            dims[f"native.{op}"] = {
-                "value": self.should_native_io(plugin, op=op),
-                "kind": "toggle",
-            }
-        return dims
-
-    def begin_io_op(self, op: str, plugin: str) -> None:
-        """Scheduler entry hook (execute_write_reqs / execute_read_reqs):
-        publishes this op's profile key to the heartbeat plane and —
-        learning modes only, scored incumbent permitting — arms at most
-        one perturbation trial, so the elections that follow inside the
-        op resolve it. ``never`` costs one env check."""
-        mode = _autotune.autotune_mode()
-        if mode == "never":
-            return
-        key = self._tuner.key_for(plugin, op)
-        if mode in ("auto", "fresh") and key is not None:
-            self._tuner.maybe_arm(op, plugin, self._trial_dims(op, plugin))
-        active = self._tuner.active_trial()
-        trial_dim = (
-            active["dim"]
-            if active is not None
-            and active["op"] == op
-            and active["plugin"] == plugin
-            else None
-        )
-        # The watch `profile` column (health plane): profile key plus
-        # whether this rank is running a perturbation trial. Not part of
-        # the stall fingerprint (health._PROGRESS_FIELDS).
-        telemetry.health.update(profile=key or "-", trial=trial_dim)
-
-    def observe_verdict(
-        self,
-        op: str,
-        plugin: str,
-        world_size: int,
-        attribution: Optional[Dict[str, Any]],
-        aggregate: Optional[Dict[str, Any]] = None,
-        root: Optional[str] = None,
-        rank: int = 0,
-    ) -> None:
-        """Post-commit feedback: score the critical-path verdict of one
-        committed take/restore against the incumbent profile. Called on
-        EVERY rank (the in-memory learning must agree fleet-wide — all
-        ranks saw the same merged attribution); rank 0 additionally
-        persists the updated profile record into ``root``'s history
-        journal. Never raises into the committed op."""
-        mode = _autotune.autotune_mode()
-        if mode == "never":
-            return
-        op_kind = "read" if op == "restore" else "write"
-        self._tuner.note_world(world_size)
-        binding = (attribution or {}).get("binding") or {}
-        category = binding.get("category")
-        # Score by the fleet's achieved end-to-end rate (bytes over the
-        # op wall), not the binding window's busy rate: the busy rate is
-        # a RESIDUAL (fused-span accounting subtracts overlapped
-        # staging/hash windows), so finer chunking earns overlap credit
-        # and the residual optimum drifts below the wall optimum — the
-        # tuner would faithfully converge to settings the operator's
-        # clock disagrees with. The binding category still keys the
-        # profile and gates learning; its rate is only the fallback.
-        agg = aggregate or {}
-        gbps = agg.get("read_gbps" if op_kind == "read" else "write_gbps")
-        if not isinstance(gbps, (int, float)) or gbps <= 0:
-            gbps = binding.get("gbps")
-        if (
-            not isinstance(category, str)
-            or not category
-            or not isinstance(gbps, (int, float))
-            or gbps <= 0
-        ):
-            # Bus-off / unattributed op: skip EXPLICITLY — a None
-            # binding category must never become a learned profile key.
-            telemetry.counter_add("profile_skips", 1)
-            aborted = self._tuner.abort_trial(op_kind, plugin)
-            telemetry.record_learn(
-                op=op, plugin=plugin, skipped=True, trial_aborted=aborted
-            )
-            return
-        # Trials only arm off storage-class verdicts: when the pipeline
-        # (staging, hashing) gates the op, perturbing storage knobs is
-        # noise-chasing — the score still tracks, the experiment waits.
-        storage_bound = (
-            telemetry.critpath.classify_category(category) == "storage"
-        )
-        result = self._tuner.observe(
-            op_kind,
-            plugin,
-            category,
-            float(gbps),
-            learn=(mode != "pin"),
-            arm=storage_bound,
-        )
-        telemetry.record_learn(
-            op=op,
-            **{k: v for k, v in result.items() if k not in ("settings", "op")},
-        )
-        if root is not None and rank == 0 and mode != "pin":
-            record = self._tuner.profile_record(result["key"])
-            if record is not None:
-                record["op"] = op_kind
-                telemetry.history.append_record(root, record)
-
-    def load_profiles(self, root: str) -> int:
-        """Warm-start from ``root``'s history journal: adopt the last
-        persisted profile per key so the first op of this process elects
-        the learned optimum, not the static default. Once per root per
-        governor; ``fresh`` (relearn) and ``never`` skip."""
-        mode = _autotune.autotune_mode()
-        if mode in ("never", "fresh") or not root:
-            return 0
-        with self._lock:
-            if root in self._profile_roots:
-                return 0
-            self._profile_roots.add(root)
-        try:
-            records = telemetry.history.load_profiles(root)
-        except Exception:  # noqa: BLE001 - profiles are advisory
-            logger.debug("profile load skipped", exc_info=True)
-            return 0
-        loaded = self._tuner.load(records)
-        if loaded:
-            logger.debug(
-                "autotune: warm-started %d profile(s) from %s", loaded, root
-            )
-        return loaded
-
-    def profiles(self) -> Dict[str, Dict[str, Any]]:
-        """Live convergence state per profile key (introspection)."""
-        return self._tuner.profiles()
 
 
 def preverify_mode() -> str:
@@ -825,37 +564,13 @@ def io_governor() -> IOGovernor:
 
 def reset_io_governor() -> IOGovernor:
     """Replace the process governor with a fresh instance. Test/bench
-    hook: the warm-start benchmark simulates "a new process on a known
-    host" with it (fresh EWMA tables + profile reload). The bus rate
-    listener resolves the current instance per call, so the swap is
-    safe mid-process."""
+    hook: "a new process on this host" (fresh EWMA tables, no gate
+    memory). The bus rate listener resolves the current instance per
+    call, so the swap is safe mid-process."""
     global _governor
     with _governor_lock:
         _governor = IOGovernor()
         return _governor
-
-
-def preload_profiles(path: str, world_size: Optional[int] = None) -> None:
-    """Load the learned profiles governing ``path``'s root (the
-    snapshot's parent directory — where the history journal lives) into
-    the process governor, before the op's first election. Cheap no-op
-    when autotuning is off or the path has no local filesystem root;
-    never raises into the op."""
-    if _autotune.autotune_mode() == "never":
-        return
-    governor = io_governor()
-    if world_size:
-        governor.note_world(world_size)
-    try:
-        from .storage_plugin import local_fs_root
-
-        local = local_fs_root(path)
-        if local is None:
-            return
-        root = os.path.dirname(os.path.abspath(local.rstrip("/")))
-        governor.load_profiles(root)
-    except Exception:  # noqa: BLE001 - profiles are advisory
-        logger.debug("profile preload skipped", exc_info=True)
 
 
 def _feed_governor_rates(
@@ -1377,10 +1092,6 @@ async def execute_write_reqs(
 
     governor = io_governor()
     plugin_key = type(storage).__name__
-    # Closed-loop hook: publish the profile key and (learning modes)
-    # arm at most one perturbation trial BEFORE the elections below, so
-    # this op runs it and the post-commit verdict scores it.
-    governor.begin_io_op("write", plugin_key)
     # Streaming fuses staging with storage I/O, so a streamed entry's
     # write completes before this function returns — callers that rely on
     # the staging-complete consistency point RETURNING EARLY (async_take)
@@ -2062,9 +1773,6 @@ async def execute_read_reqs(
 
     governor = io_governor()
     plugin_key = type(storage).__name__
-    # Closed-loop hook (see execute_write_reqs): trial arming must
-    # precede the elections below.
-    governor.begin_io_op("read", plugin_key)
     # Streamed-read election mirrors the write side: only plugins that
     # produce chunks incrementally are eligible (the buffered read_stream
     # fallback would hold a full entry while the budget charged a

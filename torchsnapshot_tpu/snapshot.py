@@ -82,7 +82,6 @@ from .scheduler import (
     PendingIOWork,
     execute_write_reqs,
     get_process_memory_budget_bytes,
-    preload_profiles,
     sync_execute_read_reqs,
     sync_execute_write_reqs,
 )
@@ -261,10 +260,6 @@ class Snapshot:
         storage = url_to_storage_plugin_in_event_loop(
             path, event_loop, storage_options
         )
-        # Warm-start the IOGovernor from this root's learned profiles
-        # (autotune.py) BEFORE the first election of the op. Once per
-        # root per process; one env check when autotuning is off.
-        preload_profiles(path, pg_wrapper.get_world_size())
         timer = _PhaseTimer("Snapshot.take")
         recorder = telemetry.begin_op("take", pg_wrapper.get_rank())
         telemetry.flightrec.record(
@@ -425,7 +420,6 @@ class Snapshot:
         storage = url_to_storage_plugin_in_event_loop(
             path, event_loop, storage_options
         )
-        preload_profiles(path, pg_wrapper.get_world_size())
         timer = _PhaseTimer("Snapshot.async_take")
         recorder = telemetry.begin_op("take", pg_wrapper.get_rank())
         telemetry.flightrec.record(
@@ -900,10 +894,6 @@ class Snapshot:
         storage = url_to_storage_plugin_in_event_loop(
             self.path, event_loop, self._storage_options
         )
-        # Warm-start learned I/O profiles for the restore-side elections
-        # (stream-read knee, preverify, coop restore) — same journal the
-        # take side persists into.
-        preload_profiles(self.path, pg_wrapper.get_world_size())
         # Fleet seeding tier (distrib.py, TORCHSNAPSHOT_TPU_SEED_RESTORE):
         # shareable buffered reads source from peers that already hold the
         # chunk before touching storage, and chunks this restore obtains
@@ -1245,12 +1235,9 @@ class Snapshot:
             # this rank is about to raise. Restores never write into the
             # snapshot directory — the fleet view is logged and exposed
             # via telemetry.last_fleet() only.
-            # ``path`` rides along for the autotuner's restore-side
-            # profile persistence only — persist=False still means no
-            # telemetry documents are written into the snapshot.
             self._publish_telemetry(
                 "restore", recorder, timer, pg_wrapper, storage, event_loop,
-                persist=False, path=self.path,
+                persist=False,
             )
             if exc is not None:
                 raise exc
@@ -2134,40 +2121,6 @@ class Snapshot:
             except Exception:
                 logger.exception(
                     "critical-path merge failed; continuing without it"
-                )
-            # Closed-loop autotune feedback: the governor scores this
-            # op's merged critical-path verdict against its incumbent
-            # profile (autotune.AutoTuner.observe) on EVERY rank — the
-            # merged attribution is identical fleet-wide, so learning
-            # stays consistent without a collective — and rank 0
-            # persists the updated profile record into the history
-            # journal. One env check when autotuning is off; guarded —
-            # learning must never fail a committed op.
-            try:
-                from .scheduler import autotune_mode, io_governor
-
-                if autotune_mode() != "never":
-                    tune_root = None
-                    if path is not None:
-                        from .storage_plugin import local_fs_root
-
-                        local = local_fs_root(path)
-                        if local is not None:
-                            tune_root = os.path.dirname(
-                                os.path.abspath(local.rstrip("/"))
-                            )
-                    io_governor().observe_verdict(
-                        op,
-                        type(storage).__name__,
-                        world_size,
-                        attribution,
-                        aggregate=(fleet or {}).get("aggregate"),
-                        root=tune_root,
-                        rank=pg_wrapper.get_rank(),
-                    )
-            except Exception:
-                logger.exception(
-                    "autotune verdict observation failed; continuing"
                 )
             if persist and path is not None and pg_wrapper.get_rank() == 0:
                 # History works with the bus OFF too (fleet None): wall

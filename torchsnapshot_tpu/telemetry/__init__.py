@@ -91,13 +91,3 @@ def record_election(**fields) -> None:
     One helper so an election site can never wire half the pair."""
     flightrec.record("governor.elect", **fields)
     event("governor_elect", cat="governor", **fields)
-
-
-def record_learn(**fields) -> None:
-    """Record one autotuner verdict (scheduler.IOGovernor.
-    observe_verdict) on the same two planes as elections: the flight
-    recorder (``governor.learn`` — the perturb/score/revert trail in
-    ``blackbox``) and, bus permitting, a ``cat="governor"`` instant that
-    rides ``summary["governor"]`` into ``explain -v``."""
-    flightrec.record("governor.learn", **fields)
-    event("governor_learn", cat="governor", **fields)

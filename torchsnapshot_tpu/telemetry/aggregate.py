@@ -63,11 +63,6 @@ _SUMMED_COUNTERS = (
     "pages_faulted",
     "pages_prefetched",
     "pagein_bytes",
-    # Closed-loop autotune (scheduler.IOGovernor / autotune.py): ops
-    # whose verdict carried no binding category and were therefore
-    # skipped by profile learning — a high count means the tuner is
-    # flying blind (telemetry bus off / attribution failing).
-    "profile_skips",
     # Cross-region geo-replication (georep.py): what the rank-0 shipper
     # moved, what it refused (CRC rejects, splice refusals), and what it
     # shed under backlog pressure — the DR-tier health in one row.

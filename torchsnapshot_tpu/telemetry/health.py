@@ -321,9 +321,6 @@ def render_fleet(
     # (pagein.py): a replica serving before fully restored climbs from
     # its hot-set fraction to 100% as the tail pages in; eager ops show
     # ``-``.
-    # The ``profile`` column is the autotuner's active profile key
-    # (scheduler.begin_io_op -> autotune.profile_key); a trailing ``*``
-    # marks a rank currently running a perturbation trial on that op.
     # The ``repl`` column is the geo-replication lag (georep.py,
     # rank-0-only): the age of the oldest committed-but-unshipped state
     # — the remote tier's live RPO exposure; ranks without a shipper
@@ -333,7 +330,7 @@ def render_fleet(
     lines.append(
         f"{'rank':>4}  {'op':<8} {'phase':<14} {'staged':>10} {'written':>10} "
         f"{'read':>10} {'seed':>10} {'total':>10} {'resid':>6} {'io':>3} "
-        f"{'eta':>7} {'wall':>8}  {'bound on':<15} {'profile':<28} "
+        f"{'eta':>7} {'wall':>8}  {'bound on':<15} "
         f"{'repl':>7} status"
     )
     walls = []
@@ -355,9 +352,6 @@ def render_fleet(
         binding = rec.get("binding") or "-"
         resid = rec.get("resident_frac")
         resid_txt = f"{resid * 100:.0f}%" if resid is not None else "-"
-        profile = str(rec.get("profile") or "-")
-        if rec.get("trial"):
-            profile += "*"
         repl_lag = rec.get("georep_lag_s")
         repl_txt = f"{repl_lag:.1f}s" if repl_lag is not None else "-"
         lines.append(
@@ -372,7 +366,7 @@ def render_fleet(
             f"{rec.get('inflight_io', 0):>3} "
             f"{(str(eta) + 's') if eta is not None else '?':>7} "
             f"{rec.get('wall_s', 0):>7.1f}s  {str(binding):<15} "
-            f"{profile:<28} {repl_txt:>7} {status}"
+            f"{repl_txt:>7} {status}"
         )
     if len(walls) > 1:
         wall_max, slowest = max(walls)

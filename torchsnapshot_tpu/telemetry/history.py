@@ -58,7 +58,6 @@ _RECORD_COUNTERS = (
     "pages_faulted",
     "pages_prefetched",
     "pagein_bytes",
-    "profile_skips",
     "georep_bases_shipped",
     "georep_epochs_shipped",
     "georep_bytes_shipped",
@@ -190,102 +189,12 @@ def load_history(path_or_root: str) -> List[Dict[str, Any]]:
                 rec = json.loads(line)
             except ValueError:
                 continue  # torn append from a killed writer
+            # Only operation records carry ``wall_s``: the ``type="profile"``
+            # records older versions appended to the same journal do not,
+            # and are skipped here.
             if isinstance(rec, dict) and "wall_s" in rec:
                 records.append(rec)
     return records
-
-
-def load_profiles(path_or_root: str) -> List[Dict[str, Any]]:
-    """Parse the journal's learned-profile records (``type="profile"``,
-    appended by the IOGovernor's closed loop — scheduler.observe_verdict
-    via autotune.AutoTuner.profile_record), newest last.
-
-    Profile records deliberately carry no ``wall_s``, so they are
-    invisible to :func:`load_history` and the trend math; this is their
-    reader. Records with no binding category are skipped here too — the
-    same bus-off-take rule the learner applies (a ``None`` category must
-    not poison a profile key)."""
-    path = path_or_root
-    if os.path.isdir(path):
-        path = history_path(path)
-    records: List[Dict[str, Any]] = []
-    if not os.path.isfile(path):
-        return records
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                rec = json.loads(line)
-            except ValueError:
-                continue  # torn append from a killed writer
-            if (
-                isinstance(rec, dict)
-                and rec.get("type") == "profile"
-                and isinstance(rec.get("binding"), str)
-                and rec.get("binding")
-            ):
-                records.append(rec)
-    return records
-
-
-def render_profiles(records: List[Dict[str, Any]]) -> str:
-    """The ``explain --profiles`` rendering: per profile key, the
-    converged settings, the smoothed verdict score, and the recent
-    perturbation trail (dim, from -> to, kept/reverted/neutral) — the
-    governor's full decision story for a root."""
-    latest: Dict[str, Dict[str, Any]] = {}
-    for rec in records:
-        key = (
-            f"{rec.get('plugin', '?')}|w{rec.get('world_size', '?')}|"
-            f"{rec.get('binding', '?')}"
-        )
-        latest[key] = rec  # newest last wins
-    lines = [
-        f"learned profiles: {len(latest)} key(s) "
-        f"({len(records)} journal record(s))"
-    ]
-    for key in sorted(latest):
-        rec = latest[key]
-        when = time.strftime(
-            "%Y-%m-%d %H:%M:%S", time.localtime(rec.get("ts", 0))
-        )
-        score = rec.get("score_gbps")
-        lines.append(
-            f"\n{key}  [{rec.get('op', '?')}]  "
-            f"score {score:.2f} GB/s" if isinstance(score, (int, float))
-            else f"\n{key}  [{rec.get('op', '?')}]  score ?"
-        )
-        lines.append(
-            f"  takes {rec.get('takes', 0)}, last updated {when}"
-        )
-        settings = rec.get("settings") or {}
-        if settings:
-            for dim in sorted(settings):
-                val = settings[dim]
-                if dim.startswith("sub_chunk") and isinstance(val, int):
-                    shown = f"{val >> 20} MB"
-                else:
-                    shown = str(val)
-                lines.append(f"  {dim:<22} {shown}")
-        else:
-            lines.append("  (no converged settings yet — heuristics hold)")
-        trials = rec.get("trials") or []
-        for t in trials[-MAX_RENDERED_TRIALS:]:
-            if not isinstance(t, dict):
-                continue
-            lines.append(
-                f"  trial {t.get('dim', '?'):<18} "
-                f"{t.get('from', '?')} -> {t.get('to', '?')}  "
-                f"{t.get('verdict', '?'):<8} "
-                f"({t.get('gbps', '?')} vs incumbent "
-                f"{t.get('incumbent_gbps', '?')} GB/s)"
-            )
-    return "\n".join(lines)
-
-
-MAX_RENDERED_TRIALS = 8
 
 
 def _p50(values: List[float]) -> float:

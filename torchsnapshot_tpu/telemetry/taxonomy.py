@@ -55,10 +55,6 @@ EVENTS: Dict[str, str] = {
     "measured rates at decision time) — recorded wherever the governor "
     "picks streaming on/off, sub-chunk size, I/O concurrency, the "
     "preverify gate, or cooperative restore",
-    "governor.learn": "the autotuner scored a committed op's critical-"
-    "path verdict against the incumbent profile (key, trial dim, "
-    "kept/reverted/neutral verdict, GB/s) — or skipped an unattributed "
-    "op (skipped=True, counted as profile_skips)",
     # native I/O engine (native_io.py / io_preparers/array.py)
     "native.degrade": "the native I/O tier degraded (site, cause) — the "
     "capability probe failed at startup or the staging pool fell back to "
