@@ -174,7 +174,7 @@ def test_async_take_all_or_nothing(tmp_path) -> None:
     assert not os.path.exists(os.path.join(snap_path, SNAPSHOT_METADATA_FNAME))
 
 
-def test_warmup_staging_prefaults_exact_sizes(tmp_path):
+def test_warmup_staging_prefaults_exact_sizes(tmp_path, monkeypatch):
     """warmup_staging must draw the same slab sizes the real staging pass
     will: a second warmup reports nothing left to fault, and an
     async_take after warmup recycles the warmed slabs instead of
@@ -184,7 +184,13 @@ def test_warmup_staging_prefaults_exact_sizes(tmp_path):
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot, StateDict, warmup_staging
-    from torchsnapshot_tpu.io_preparers.array import _staging_pool
+    from torchsnapshot_tpu.io_preparers import array as array_mod
+
+    # A pool of the test's own: the process's pool keeps the slabs of
+    # whatever test files this worker ran before (a free 512 KiB slab
+    # left by tests/test_governor.py made "everything faulted" false).
+    _staging_pool = array_mod._StagingPool(array_mod._pool_limit())
+    monkeypatch.setattr(array_mod, "_staging_pool", _staging_pool)
 
     state = {
         "app": StateDict(

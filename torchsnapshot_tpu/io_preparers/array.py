@@ -75,6 +75,80 @@ def zero_copy_staging():
         _copy_for_consistency.reset(token)
 
 
+# Bytes of device-to-host transfers one save keeps in flight. Found on a
+# v5e by a sweep over the benchmark's save cells (PERF.md, PR 31): kicked
+# all at once, 26-48 leaves arrive at a fifth to a third of one stream's
+# rate, the runtime de-tiling them against each other.
+_DTOH_WINDOW_BYTES = 2 << 30
+
+
+class DtoHWindow:
+    """Byte window on the DtoH transfers of one ``execute_write_reqs``
+    call: a device-backed leaf's ``copy_to_host_async`` is kicked when
+    the leaves ahead of it have left room, not for every leaf at once.
+
+    Admission is FIFO in the order stagers ask, which is the scheduler's
+    staging order and the order the executor's threads pick leaves up,
+    so the threads wait on the oldest transfers. A leaf wider than the
+    window is admitted alone, when nothing is in flight. A leaf holds
+    its bytes until it is staged. All state lives on the event-loop
+    thread."""
+
+    def __init__(self, width_bytes: Optional[int] = None) -> None:
+        self.width_bytes = (
+            _DTOH_WINDOW_BYTES if width_bytes is None else width_bytes
+        )
+        self.in_flight = 0
+        self._waiters: deque[Tuple[asyncio.Future, int]] = deque()
+
+    def _fits(self, nbytes: int) -> bool:
+        return self.in_flight == 0 or self.in_flight + nbytes <= self.width_bytes
+
+    def _take(self, nbytes: int) -> None:
+        self.in_flight += nbytes
+        telemetry.gauge_set("dtoh_inflight_bytes", self.in_flight)
+
+    async def admit(self, nbytes: int) -> None:
+        """Wait for room and take ``nbytes`` of it; the caller gives them
+        back with ``release``. The ``stage_dtoh_gate`` span opens whether
+        or not the leaf waits."""
+        with telemetry.span("stage_dtoh_gate", cat="stager", bytes=nbytes):
+            if not self._waiters and self._fits(nbytes):
+                self._take(nbytes)
+                return
+            telemetry.counter_add("dtoh_window_waits", 1)
+            fut = asyncio.get_running_loop().create_future()
+            self._waiters.append((fut, nbytes))
+            try:
+                await fut
+            except asyncio.CancelledError:
+                # Admitted in the tick the task was cancelled in: the
+                # bytes were taken and the caller will not return them.
+                # Either way the leaves behind this one move up.
+                admitted = fut.done() and not fut.cancelled()
+                self.release(nbytes if admitted else 0)
+                raise
+
+    def release(self, nbytes: int) -> None:
+        self.in_flight -= nbytes
+        while self._waiters:
+            fut, head = self._waiters[0]
+            if not fut.cancelled():
+                if not self._fits(head):
+                    break
+                self._take(head)
+                fut.set_result(None)
+            self._waiters.popleft()
+
+
+# The window of the execute_write_reqs call a stager is staged under (the
+# scheduler sets it around its staging tasks, which inherit it); None
+# outside one, where a stager kicks its transfer at once.
+dtoh_window: contextvars.ContextVar[Optional[DtoHWindow]] = contextvars.ContextVar(
+    "tsnap_dtoh_window", default=None
+)
+
+
 STAGING_POOL_ENV_VAR = "TORCHSNAPSHOT_TPU_STAGING_POOL_BYTES"
 _DEFAULT_STAGING_POOL_BYTES = 4 << 30
 
@@ -431,6 +505,11 @@ def _is_jax_array(arr) -> bool:
 def array_nbytes(arr) -> int:
     """Logical byte size of a numpy or jax array."""
     return array_size_bytes(arr.shape, dtype_to_string(arr.dtype))
+
+
+def _device_backed(arr) -> bool:
+    """A jax array whose bytes reach the host by DMA: off the CPU backend."""
+    return next(iter(arr.sharding.device_set)).platform != "cpu"
 
 
 def to_host(arr) -> np.ndarray:
@@ -953,9 +1032,7 @@ class ArrayBufferStager(BufferStager):
         arr = self.arr
         loop = asyncio.get_running_loop()
         state = self._stream_checksum_init()
-        device_backed = _is_jax_array(arr) and (
-            next(iter(arr.sharding.device_set)).platform != "cpu"
-        )
+        device_backed = _is_jax_array(arr) and _device_backed(arr)
         if not device_backed:
             host = np.asarray(arr)
             mv = array_as_memoryview(host)
@@ -1042,23 +1119,39 @@ class ArrayBufferStager(BufferStager):
                 # until after, so neither the device pass nor its
                 # roundtrip ever sits ahead of the staging copy.
                 record_fp = True
-        if _is_jax_array(arr):
-            # Kick off the DMA before blocking. No except: a failed kick
-            # would silently turn the overlapped DtoH into a serial one.
-            arr.copy_to_host_async()
-        pending_fp = None
-        if record_fp:
-            from ..device_digest import _dispatch
+        is_jax = _is_jax_array(arr)
+        # A DMA is kicked when the leaves ahead of this one in the save
+        # have left room in the DtoHWindow, and gives its bytes back when
+        # the leaf is staged: landed and checksummed (giving them back at
+        # landing, from the executor thread, bought nothing measurable on
+        # the chip: PERF.md, PR 31). CPU arrays have no DMA and bypass it.
+        window = dtoh_window.get() if is_jax and _device_backed(arr) else None
+        if window is not None:
+            nbytes = array_nbytes(arr)
+            await window.admit(nbytes)
+        try:
+            if is_jax:
+                # Kick off the DMA before blocking. No except: a failed
+                # kick would silently turn the overlapped DtoH into a
+                # serial one.
+                arr.copy_to_host_async()
+            pending_fp = None
+            if record_fp:
+                from ..device_digest import _dispatch
 
-            pending_fp = await loop.run_in_executor(executor, _dispatch, arr)
-        buf = await loop.run_in_executor(executor, self._stage_and_sum, arr)
-        if pending_fp is not None:
-            from ..device_digest import _finalize
+                pending_fp = await loop.run_in_executor(executor, _dispatch, arr)
+            buf = await loop.run_in_executor(executor, self._stage_and_sum, arr)
+            if pending_fp is not None:
+                from ..device_digest import _finalize
 
-            self.entry.device_digest = await loop.run_in_executor(
-                executor, _finalize, arr, pending_fp
-            )
-        return buf
+                self.entry.device_digest = await loop.run_in_executor(
+                    executor, _finalize, arr, pending_fp
+                )
+            return buf
+        finally:
+            # Staged, or a stage that raised or was cancelled.
+            if window is not None:
+                window.release(nbytes)
 
     def get_staging_cost_bytes(self) -> int:
         return array_nbytes(self.arr)
