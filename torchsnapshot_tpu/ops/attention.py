@@ -236,7 +236,8 @@ def causal_attention_route(
 ) -> Tuple[str, Callable[..., jax.Array]]:
     """The causal attention a model runs for this request, mesh and shape:
     the route's name and ``attend(q, k, v, in_layout=False)`` on logical
-    ``(B, S, H, hd)`` operands (sharding via the caller's constraints).
+    ``(B, S, H, hd)`` operands (sharding via the caller's constraints);
+    ``k`` and ``v`` may come with fewer heads, one a group of query heads.
 
     ``attn_impl`` is a request; what runs also depends on things the code
     observes (backend, mesh axes, whether S tiles), and a request that
@@ -253,6 +254,14 @@ def causal_attention_route(
     route = _select_route(attn_impl, block_size, n_heads, mesh, B, S)
 
     def attend(q, k, v, in_layout: bool = False):
+        if k.shape[2] != q.shape[2]:
+            # Grouped-query attention: ``k, v: (B, S, H_kv, hd)`` with H_kv
+            # dividing H. Each KV head is repeated over its group's query
+            # heads ahead of whichever route runs, so every route (the
+            # flash kernel among them) sees one KV head a query head, and
+            # the repeat's transpose sums a group's gradients.
+            group = q.shape[2] // k.shape[2]
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if route == "ulysses":
             from .ulysses import ulysses_attention_sharded
 

@@ -37,11 +37,13 @@ tokens per expert * mean router prob per expert) * E.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Above this many elements in the (T, E, cap) dispatch tensor, "auto"
 # switches to the sort-based dispatch (2**22 f32 elements = 16 MB).
@@ -307,3 +309,156 @@ def moe_ffn_sharded(
         check_vma=False,
     )(params, x)
     return y, aux
+
+
+# ------------------------------- sigmoid top-k, dropless, a share of the experts
+
+
+def relu2_ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """``relu(x w_up)^2 w_down``: the two-matrix expert without a gate.
+    Operands in the weights' dtype, both products accumulated in float32."""
+    h = jnp.matmul(x.astype(w_up.dtype), w_up, preferred_element_type=jnp.float32)
+    a = jnp.square(jax.nn.relu(h)).astype(w_down.dtype)
+    return jnp.matmul(a, w_down, preferred_element_type=jnp.float32)
+
+
+def sigmoid_topk_route(
+    x2: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, scale: float
+) -> Tuple[jax.Array, jax.Array]:
+    """``(T, D)`` tokens -> the ids ``(T, k)`` of the ``top_k`` experts with
+    the largest ``sigmoid(x router) + bias`` and their weights ``(T, k)``:
+    ``scale * s_i / (sum of the chosen s + 1e-20)``, from ``s`` and not from
+    ``s + bias``. The bias only selects and takes no gradient. All of it in
+    float32, the product at full precision (a bfloat16 pass moves scores by
+    1e-2, which is the distance between neighbours among 128 of them)."""
+    f32 = jnp.float32
+    logits = jnp.matmul(x2.astype(f32), router.astype(f32), precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(s + jax.lax.stop_gradient(bias.astype(f32)), top_k)
+    chosen = jnp.take_along_axis(s, ids, axis=-1)
+    return ids, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _held_experts(x2, w_held, order, counts, w_up, w_down, tile):
+    """``sum_e w_held[e, t] relu2_ffn(x2[t]; w_up[e], w_down[e])`` over the
+    tokens that chose expert e: ``(T, D) -> (T, D)`` float32.
+
+    ``order[e]`` lists the tokens, those of expert e first (``counts[e]`` of
+    them), and ``w_held[e]`` is 0 on everyone else. Each expert walks its
+    list in tiles of ``tile`` tokens and stops after the last tile that
+    holds one of its own (that tile's tail is computed at weight 0): rows
+    are gathered, multiplied and scatter-added a tile at a time, so a step
+    costs what its routing sends here and nothing of size ``E x T`` is ever
+    held. The trip counts are data, which
+    reverse-mode autodiff cannot transpose, so the backward pass is written
+    out below with the same loops."""
+    return _held_experts_fwd(x2, w_held, order, counts, w_up, w_down, tile)[0]
+
+
+def _held_experts_fwd(x2, w_held, order, counts, w_up, w_down, tile):
+    f32 = jnp.float32
+
+    def one(y, args):
+        w_e, order_e, n, up, down = args
+
+        def body(j, y):
+            idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
+            o = relu2_ffn(x2[idx], up, down)
+            return y.at[idx].add(w_e[idx][:, None] * o, unique_indices=True)
+
+        return jax.lax.fori_loop(0, (n + tile - 1) // tile, body, y), None
+
+    y, _ = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, w_up, w_down))
+    return y, (x2, w_held, order, counts, w_up, w_down)
+
+
+def _held_experts_bwd(tile, res, g):
+    x2, w_held, order, counts, w_up, w_down = res
+    f32, T = jnp.float32, x2.shape[0]
+    contract0 = (((0,), (0,)), ((), ()))  # a^T b
+    contract1 = (((1,), (1,)), ((), ()))  # a b^T
+
+    def one(dx, args):
+        w_e, order_e, n, up, down = args
+
+        def body(j, carry):
+            dx, dw, dup, ddown = carry
+            idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
+            rows, go = x2[idx], g[idx]
+            r = jax.nn.relu(jnp.matmul(rows, up, preferred_element_type=f32))
+            a = jnp.square(r).astype(down.dtype)
+            o = jnp.matmul(a, down, preferred_element_type=f32)
+            dw = dw.at[idx].set(jnp.sum(o * go, axis=-1), unique_indices=True)
+            do = (w_e[idx][:, None] * go).astype(down.dtype)
+            ddown = ddown + jax.lax.dot_general(a, do, contract0, preferred_element_type=f32)
+            dh = (jax.lax.dot_general(do, down, contract1, preferred_element_type=f32) * 2.0 * r).astype(up.dtype)
+            dup = dup + jax.lax.dot_general(rows, dh, contract0, preferred_element_type=f32)
+            drows = jax.lax.dot_general(dh, up, contract1, preferred_element_type=f32)
+            return dx.at[idx].add(drows, unique_indices=True), dw, dup, ddown
+
+        init = (dx, jnp.zeros((T,), f32), jnp.zeros(up.shape, f32), jnp.zeros(down.shape, f32))
+        dx, dw, dup, ddown = jax.lax.fori_loop(0, (n + tile - 1) // tile, body, init)
+        return dx, (dw, dup.astype(up.dtype), ddown.astype(down.dtype))
+
+    dx, (dw, dup, ddown) = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, w_up, w_down))
+    none = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
+    return dx.astype(x2.dtype), dw, none(order), none(counts), dup, ddown
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def sigmoid_topk_routed(
+    params: Dict[str, Any],
+    x: jax.Array,
+    *,
+    top_k: int,
+    held: Tuple[int, ...],
+    routed_scale: float,
+    tile: int = 512,
+) -> Tuple[jax.Array, jax.Array]:
+    """The routed part of a sigmoid top-k expert layer, for the experts held
+    here: ``x: (..., D)`` -> (float32 of the same shape, the chosen ids
+    ``(T, k)``).
+
+    ``params``: ``router (D, E)`` and ``router_bias (E,)`` over **all** E
+    experts, ``expert_up (n, D, F)`` and ``expert_down (n, F, D)`` of the
+    ``n = len(held)`` experts whose ids ``held`` names. Every token scores
+    and chooses among all E and its weights are normalised over all
+    ``top_k`` chosen; the result is ``sum of w_i f_i(x)`` over the chosen
+    experts that are held, and what the others would add is left out
+    (summing this over disjoint shares that cover E gives the whole layer).
+
+    **No token is dropped and every shape is static**: each held expert has
+    a list of all T tokens with its own sorted to the front (a token
+    chooses an expert at most once) and computes the tiles of it that hold
+    some (``_held_experts``). A layer that nobody chooses costs its routing;
+    one that everybody chooses costs ``min(top_k, n) * T`` rows. ``tile``
+    rows are multiplied at a time, the last tile of an expert part-filled:
+    512 because the MXU's time for the padding is cheap beside what every
+    tile pays whatever its size (two float32 weight-gradient accumulators
+    read and written, the gathers' and scatters' set-up), and because a
+    step then costs the same for any load up to 512 tokens an expert
+    (256 and 1024 were measured on the chip: PERF.md, PR 32).
+    """
+    orig_shape = x.shape
+    D = orig_shape[-1]
+    x2 = x.reshape(-1, D)
+    T, n = x2.shape[0], len(held)
+    if params["expert_up"].shape[0] != n:
+        raise ValueError(f"{n} expert ids held, {params['expert_up'].shape[0]} experts' weights given")
+    with jax.named_scope("moe_route"):
+        ids, weights = sigmoid_topk_route(x2, params["router"], params["router_bias"], top_k, routed_scale)
+        hit = ids[None] == jnp.asarray(held, ids.dtype)[:, None, None]  # (n, T, k)
+        member = jnp.any(hit, axis=-1)  # (n, T)
+        w_held = jnp.sum(jnp.where(hit, weights[None], 0.0), axis=-1)  # (n, T) float32, 0 off the expert
+        counts = jnp.sum(member, axis=-1, dtype=jnp.int32)
+        # Each expert's list of all T tokens, its own first, in token order.
+        order = jnp.argsort(~member, axis=-1, stable=True).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        y = _held_experts(
+            x2.astype(params["expert_up"].dtype), w_held, order, counts,
+            params["expert_up"], params["expert_down"], math.gcd(T, tile),  # tiles cover the list exactly
+        )
+    return y.reshape(orig_shape), ids

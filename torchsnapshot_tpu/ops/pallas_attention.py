@@ -250,7 +250,7 @@ def _make_flash_parts(causal, scale, block_q, block_k, interpret):
                 pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             ),
-            interpret=interpret,
+            interpret=interpret, **_vmem_room(S, D, q.dtype),
         )(q, k, v)
 
     def bwd_impl(q, k, v, g, lse, delta):
@@ -270,7 +270,7 @@ def _make_flash_parts(causal, scale, block_q, block_k, interpret):
                 pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # delta
             ],
             out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            interpret=interpret,
+            interpret=interpret, **_vmem_room(S, D, q.dtype),
         )(q, k, v, g, lse, delta)
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, **kern_opts(D, S)),
@@ -291,7 +291,7 @@ def _make_flash_parts(causal, scale, block_q, block_k, interpret):
                 pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
             ),
-            interpret=interpret,
+            interpret=interpret, **_vmem_room(S, D, q.dtype),
         )(q, k, v, g, lse, delta)
         return dq, dk, dv
 
@@ -422,3 +422,24 @@ def flash_attention_sharded(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=jax.default_backend() == "tpu",
     )(q, k, v)
+
+
+# The kernels keep one (batch*head)'s whole K and V (forward, dq) or Q, dO,
+# lse and delta (dk/dv) in VMEM, double-buffered; the two (S, 1) float32
+# rows are padded to 128 lanes there. Mosaic's scoped default is 16 MiB,
+# which S = 2048 at D = 128 uses 6 MiB of and S = 8192 overruns (21 MiB
+# asked for, compiled for a described v5e). A v5e core has 128 MiB.
+_VMEM_DEFAULT_ROOM = 12 << 20
+_VMEM_MOST = 96 << 20
+
+
+def _vmem_room(S: int, D: int, dtype) -> dict:
+    """``compiler_params`` for a ``pallas_call`` of these kernels: nothing
+    where the default limit holds the dk/dv kernel's operands (the largest
+    of the three), else a limit of twice what they take."""
+    need = 2 * (2 * S * D * jnp.dtype(dtype).itemsize + 2 * S * 128 * 4)
+    if need <= _VMEM_DEFAULT_ROOM:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=min(2 * need, _VMEM_MOST))}

@@ -211,3 +211,78 @@ def ssm_mix_sharded(
         out_specs=(spec, state_spec),
         check_vma=False,
     )(params, x, h0)
+
+
+# ------------------------------------------------ Mamba-2, chunked (SSD)
+
+
+def mamba2_chunked(
+    u: jax.Array,
+    delta: jax.Array,
+    a: jax.Array,
+    b: jax.Array,
+    c: jax.Array,
+    *,
+    chunk: int,
+) -> jax.Array:
+    """The Mamba-2 recurrence by its state-space duality, in chunks.
+
+    Per head, with a state ``h`` of ``(P, N)`` and a scalar decay::
+
+        h_t = exp(delta_t a) h_{t-1} + delta_t u_t b_t^T,      y_t = h_t c_t
+
+    ``u: (B, S, H, P)``, ``delta: (B, S, H)`` float32 (after the softplus),
+    ``a: (H,)`` float32 and negative, ``b, c: (B, S, G, N)`` with a group
+    serving ``H / G`` heads. Returns ``y: (B, S, H, P)`` float32; the skip
+    term ``D u`` is the caller's.
+
+    Inside a chunk of ``chunk`` positions the recurrence is one masked
+    matrix product (``(c_t . b_s) exp(sum_{s<r<=t} delta_r a)`` for
+    ``s <= t``, against ``delta_s u_s``), between chunks a ``lax.scan``
+    carries the ``(H, P, N)`` state, so nothing of size ``S x P x N`` is
+    ever held (``ssm_scan`` holds exactly that). The backward pass is
+    autodiff's of this form: chunked as well. Matmul operands keep the dtype
+    ``u``, ``b`` and ``c`` come in; decays, their cumulative sums and every
+    accumulation are float32. ``S`` must be a multiple of ``chunk``.
+    """
+    B, S, H, P = u.shape
+    G, N = b.shape[2], b.shape[3]
+    if S % chunk or H % G:
+        raise ValueError(f"mamba2_chunked needs S ({S}) % chunk ({chunk}) == 0 and H ({H}) % G ({G}) == 0")
+    n, L, Hg, f32 = S // chunk, chunk, H // G, jnp.float32
+
+    log_decay = (delta.astype(f32) * a.astype(f32)).reshape(B, n, L, G, Hg)
+    cs = jnp.cumsum(log_decay, axis=2)  # inclusive, within the chunk; <= 0
+    cs_h = jnp.moveaxis(cs, 2, -1)  # (B, n, G, Hg, L)
+    x = (u.astype(f32) * delta.astype(f32)[..., None]).astype(u.dtype).reshape(B, n, L, G, Hg, P)
+    bc, cc = b.reshape(B, n, L, G, N), c.reshape(B, n, L, G, N)
+
+    # Within a chunk: y_t += sum_{s<=t} (c_t . b_s) exp(cs_t - cs_s) x_s.
+    # The mask goes on before the exp: above the diagonal cs_t - cs_s > 0.
+    cb = jnp.einsum("bnlgk,bnsgk->bngls", cc, bc, preferred_element_type=f32)
+    seg = cs_h[..., :, None] - cs_h[..., None, :]  # (B, n, G, Hg, L, L): [t, s]
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    mix = (cb[:, :, :, None] * jnp.exp(jnp.where(causal, seg, -jnp.inf))).astype(u.dtype)
+    y = jnp.einsum("bnghls,bnsghp->bnlghp", mix, x, preferred_element_type=f32)
+
+    # What each chunk adds to the state by its end, and the state each
+    # chunk starts from: h_in[j+1] = exp(sum of chunk j) h_in[j] + states[j].
+    to_end = jnp.exp(cs[:, :, -1:] - cs)  # (B, n, L, G, Hg)
+    states = jnp.einsum(
+        "bnsgk,bnsghp->bnghpk", bc, (x.astype(f32) * to_end[..., None]).astype(u.dtype),
+        preferred_element_type=f32,
+    )
+    chunk_decay = jnp.exp(cs[:, :, -1])  # (B, n, G, Hg)
+
+    def carry(h, xs):
+        decay, add = xs
+        return decay[..., None, None] * h + add, h
+
+    _, h_in = jax.lax.scan(
+        carry, jnp.zeros((B, G, Hg, P, N), f32),
+        (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(states, 1, 0)),
+    )
+    h_in = jnp.moveaxis(h_in, 0, 1).astype(u.dtype)  # (B, n, G, Hg, P, N)
+    y_off = jnp.einsum("bnlgk,bnghpk->bnlghp", cc, h_in, preferred_element_type=f32)
+    y = y + y_off * jnp.exp(cs)[..., None]
+    return y.reshape(B, S, H, P)
