@@ -23,7 +23,7 @@ from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
 from torchsnapshot_tpu.models import hybrid_lm as M
 from torchsnapshot_tpu.ops.attention import causal_attention_route, dense_attention
 from torchsnapshot_tpu.ops.moe import relu2_ffn, sigmoid_topk_routed
-from torchsnapshot_tpu.ops.ssm import mamba2_chunked
+from torchsnapshot_tpu.ops.ssm import _within_chunk_sum, mamba2_chunked
 from torchsnapshot_tpu.parallel import make_mesh
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -146,6 +146,29 @@ def _recurrence(u, delta, a, b, c):
     return y
 
 
+def _stepwise(u, delta, a, b, c):
+    """The definition as a ``lax.scan`` over positions, for autodiff."""
+    H, G = u.shape[2], b.shape[2]
+
+    def step(h, xs):
+        d, u_t, b_t, c_t = xs
+        b_t, c_t = jnp.repeat(b_t, H // G, axis=1), jnp.repeat(c_t, H // G, axis=1)
+        h = jnp.exp(d * a)[..., None, None] * h + (d[..., None] * u_t)[..., None] * b_t[:, :, None, :]
+        return h, jnp.einsum("bhpn,bhn->bhp", h, c_t)
+
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (delta, u, b, c))
+    h0 = jnp.zeros((u.shape[0], H, u.shape[3], b.shape[3]))
+    return jnp.moveaxis(jax.lax.scan(step, h0, xs)[1], 0, 1)
+
+
+def _assert_the_gradients_are_the_recurrences(args, chunk, probe):
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda *t: jnp.sum(mamba2_chunked(*t, chunk=chunk) * probe), argnums=range(5)))(*args)
+        want = jax.jit(jax.grad(lambda *t: jnp.sum(_stepwise(*t) * probe), argnums=range(5)))(*args)
+    for name, g, r in zip("u delta a b c".split(), got, want):
+        assert np.isfinite(np.asarray(g)).all() and _rel(g, r) <= 5e-5, (name, _rel(g, r))
+
+
 def _ssm_inputs(seq, seed=0, heads=6, groups=3, head_dim=4, state=5):
     k = iter(jax.random.split(jax.random.PRNGKey(seed), 5))
     u = jax.random.normal(next(k), (2, seq, heads, head_dim))
@@ -176,25 +199,111 @@ def test_the_chunked_scans_backward_pass_is_the_recurrences(chunks):
     chunk = 8
     args = _ssm_inputs(chunks * chunk, seed=10 + chunks)
     probe = jax.random.normal(jax.random.PRNGKey(5), args[0].shape)
+    _assert_the_gradients_are_the_recurrences(args, chunk, probe)
 
-    def stepwise(u, delta, a, b, c):
-        H, G = u.shape[2], b.shape[2]
 
-        def step(h, xs):
-            d, u_t, b_t, c_t = xs
-            b_t, c_t = jnp.repeat(b_t, H // G, axis=1), jnp.repeat(c_t, H // G, axis=1)
-            h = jnp.exp(d * a)[..., None, None] * h + (d[..., None] * u_t)[..., None] * b_t[:, :, None, :]
-            return h, jnp.einsum("bhpn,bhn->bhp", h, c_t)
+# The layout must not lean on the cell's 8 groups x 8 heads x 64: one group,
+# a head a group, a head wider than the chunk, a state wider and narrower
+# than the head, each against the recurrence and its gradients (3 chunks of 8).
+_GEOMETRIES = {
+    "one_group": dict(heads=4, groups=1, head_dim=3, state=5),
+    "a_head_a_group": dict(heads=3, groups=3, head_dim=4, state=6),
+    "head_wider_than_chunk": dict(heads=4, groups=2, head_dim=16, state=3),
+    "state_wider_than_head": dict(heads=2, groups=2, head_dim=2, state=16),
+}
 
-        xs = tuple(jnp.moveaxis(t, 1, 0) for t in (delta, u, b, c))
-        h0 = jnp.zeros((u.shape[0], H, u.shape[3], b.shape[3]))
-        return jnp.moveaxis(jax.lax.scan(step, h0, xs)[1], 0, 1)
 
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+def test_the_chunked_scan_is_the_recurrence_at_other_head_geometries(geometry):
+    args = _ssm_inputs(24, seed=20, **_GEOMETRIES[geometry])
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda *t: jnp.sum(mamba2_chunked(*t, chunk=chunk) * probe), argnums=range(5)))(*args)
-        want = jax.jit(jax.grad(lambda *t: jnp.sum(stepwise(*t) * probe), argnums=range(5)))(*args)
-    for name, g, r in zip("u delta a b c".split(), got, want):
-        assert np.isfinite(np.asarray(g)).all() and _rel(g, r) <= 5e-5, (name, _rel(g, r))
+        got = jax.jit(lambda *t: mamba2_chunked(*t, chunk=8))(*args)
+    want = _recurrence(*args)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)) <= 1e-5
+
+
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+def test_the_chunked_scans_backward_pass_at_other_head_geometries(geometry):
+    args = _ssm_inputs(24, seed=30, **_GEOMETRIES[geometry])
+    _assert_the_gradients_are_the_recurrences(args, 8, jax.random.normal(jax.random.PRNGKey(6), args[0].shape))
+
+
+# A chunk of 128 steps at both ends of what delta * a takes (time_step_floor
+# x A = 1 up to a step of 0.3 x A = 16). A float32 sum in any order is within
+# 128 roundings of the float64 one (6e-7 of the last sum read); one pass over
+# the decays in bfloat16 reads 2e-3.
+@pytest.mark.parametrize("size", [1e-4, 5.0])
+def test_the_within_chunk_sum_is_a_float32_sum(size):
+    log_decay = -size * jax.random.uniform(jax.random.PRNGKey(1), (2, 3, 8, 128), jnp.float32, 0.5, 1.5)
+    got = jax.jit(_within_chunk_sum)(log_decay)
+    want = np.cumsum(np.asarray(log_decay, np.float64), axis=-1)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    assert np.max(np.abs(np.asarray(got, np.float64) - want)) <= 4e-6 * np.max(np.abs(want))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def _is_float32_product(eqn):
+    """A product of two float32 arrays: in the mixer, a sum that stands in
+    for a reduction (the matrices' products have bfloat16 operands). It has
+    to be at full precision whatever the ambient one."""
+    if eqn.primitive.name != "dot_general" or any(v.aval.dtype != jnp.float32 for v in eqn.invars):
+        return False
+    assert eqn.params["precision"] is not None and set(eqn.params["precision"]) == {jax.lax.Precision.HIGHEST}
+    return True
+
+
+def _last_axis(shape):
+    """The axis the lanes hold: the last one, trailing axes of 1 (a
+    broadcast about to happen, never an array of its own) set aside."""
+    shape = list(shape)
+    while len(shape) > 1 and shape[-1] == 1:
+        shape.pop()
+    return shape[-1] if shape else 1
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_the_chunked_scan_holds_its_tensors_in_the_chips_tiles(backward):
+    """At the cell's head geometry (two chunks): the chip holds an array's
+    last two axes in (8, 128) tiles, so an array of ``L x H`` elements or
+    more whose last axis is short wastes most of every tile (``(..., 8, 8)``
+    fills 64 of 1024 places), and a running sum along any other axis than
+    the last walks across tiles. ``P`` = 64 comes in and goes out on the last
+    axis (``u`` and ``y``); nothing shorter may. The sums over the decays are
+    float32 at full precision, whatever the ambient matmul precision."""
+    H, Pd, G, N, L = 64, 64, 8, 128, 128
+    S = 2 * L
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((1, S, H, Pd), jnp.bfloat16), ((1, S, H), jnp.float32), ((H,), jnp.float32),
+        ((1, S, G, N), jnp.bfloat16), ((1, S, G, N), jnp.bfloat16))]
+    fn = lambda *t: mamba2_chunked(*t, chunk=L)  # noqa: E731
+    if backward:
+        fn = jax.grad(lambda *t: jnp.sum(mamba2_chunked(*t, chunk=L)), argnums=(0, 1, 2, 3, 4))
+    running = {"cumsum", "cumprod", "cummax", "cummin", "cumlogsumexp"}
+    seen = {"arrays": 0, "sums": 0}
+    for eqn in _equations(jax.make_jaxpr(fn)(*shapes).jaxpr):
+        name = eqn.primitive.name
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            if int(np.prod(shape)) >= L * H:
+                seen["arrays"] += 1
+                assert _last_axis(shape) >= 64, (name, shape)
+        if name in running:
+            assert eqn.params["axis"] == len(eqn.invars[0].aval.shape) - 1, (name, eqn.params)
+        if name.startswith("reduce_window"):
+            assert all(w == 1 for w in eqn.params["window_dimensions"][:-1]), (name, eqn.params)
+        seen["sums"] += _is_float32_product(eqn)
+    assert seen["arrays"] > 20 and seen["sums"] >= 1
 
 
 def test_the_chunked_scan_refuses_a_ragged_sequence():
@@ -401,6 +510,63 @@ def test_the_published_sizes_count_to_the_cells_state():
     assert cfg.matmul_params_per_token == 318_431_232
     whole = M.HybridLMConfig()
     assert whole.kinds.count("M") == whole.kinds.count("E") == 23 and whole.kinds.count("*") == 6
+    # What is saved: each parameter leaf, adamw's two moments of it, its count
+    # and the step; the Mamba-2 input projection one leaf of the published
+    # width, however the mixer cuts its product.
+    state = jax.eval_shape(lambda k: M.init_state(k, cfg, M.make_optimizer()), jax.random.PRNGKey(0))
+    saved = {jax.tree_util.keystr(p): x for p, x in jax.tree_util.tree_flatten_with_path(state)[0]}
+    assert len(saved) == 218 == 3 * 72 + 2
+    in_proj = [x for name, x in saved.items() if name.endswith("['layer00_mamba']['in_proj']")]
+    assert [(x.shape, x.dtype) for x in in_proj] == [((2688, 10304), jnp.float32)] * 3
+
+
+def test_the_mixer_cuts_the_weight_and_not_the_projection():
+    """``z``, ``x``, ``B C`` and ``dt`` are four products against column
+    slices of the one ``in_proj`` leaf: no array of the projection's whole
+    width, nor of the convolution's, exists for a slice to copy from, forward
+    or backward, but the leaf and its one gradient."""
+    w, a = _layer("M"), _stream()
+    width = w["in_proj"].shape[1]
+    assert width == 2 * CFG.mamba_inner + 2 * CFG.ssm_groups * CFG.ssm_state + CFG.mamba_heads
+    grad = jax.grad(lambda w, a: jnp.sum(M.mamba_mixer(w, a, CFG)), argnums=(0, 1))
+    assert jax.eval_shape(grad, w, a)[0]["in_proj"].shape == w["in_proj"].shape
+    made = [(eqn.primitive.name, var.aval.shape) for eqn in _equations(jax.make_jaxpr(grad)(w, a).jaxpr)
+            for var in eqn.outvars if hasattr(var.aval, "shape")]
+    wide = [(name, shape) for name, shape in made if shape[-1:] == (width,)]
+    assert wide == [("concatenate", w["in_proj"].shape)]  # the four slices' gradients, put together once
+    assert not [(name, shape) for name, shape in made if shape[1:] == (S, CFG.conv_width)]
+
+
+# The gated output's norm takes its statistics over a group's 512 columns of
+# 4096. Against a float64 mean square, at entries from 1e-3 to 1e3 in one
+# group: a float32 sum in any order is within 512 roundings (3e-5, 2e-7
+# read); a bfloat16 pass over the squares reads 2e-3.
+def test_the_group_norm_is_a_float32_mean_square():
+    y = jax.random.normal(jax.random.PRNGKey(2), (2, 16, 4096), jnp.float32)
+    y = y * 10.0 ** jax.random.uniform(jax.random.PRNGKey(3), y.shape, jnp.float32, -3.0, 3.0)
+    got = jax.jit(lambda t: M._group_rms(t, 8, 1e-5))(y)
+    grouped = np.asarray(y, np.float64).reshape(2, 16, 8, 512)
+    want = (grouped / np.sqrt(np.mean(grouped ** 2, axis=-1, keepdims=True) + 1e-5)).reshape(y.shape)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    assert np.max(np.abs(np.asarray(got, np.float64) - want) / np.abs(want)) <= 2e-6
+
+
+def test_the_mixer_keeps_its_width_and_its_float32_sums_whole():
+    """The gate and the norm hold ``(B, S, inner)`` as the matmuls on both
+    sides do: nothing is reshaped to ``(..., groups, inner / groups)``, a copy
+    into other tiles on the chip, forward or backward; and every product of
+    two float32 arrays (the sums that stand in for reductions) is at full
+    precision whatever the ambient one."""
+    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)  # as the cell runs it: the matrices' products are not float32 ones
+    w = {k: v.astype(cfg.dtype) if k in M._MATRICES else v for k, v in _layer("M").items()}
+    a = _stream()
+    grad = jax.grad(lambda w, a: jnp.sum(M.mamba_mixer(w, a, cfg)), argnums=(0, 1))
+    grouped = (CFG.ssm_groups, CFG.mamba_inner // CFG.ssm_groups)
+    sums = 0
+    for eqn in _equations(jax.make_jaxpr(grad)(w, a).jaxpr):
+        assert all(getattr(var.aval, "shape", ())[-2:] != grouped for var in eqn.outvars), (eqn.primitive.name, grouped)
+        sums += _is_float32_product(eqn)
+    assert sums >= 4  # the group sums and their hand-back, forward and backward, and the decays' sums
 
 
 def test_the_init_keeps_random_routers_near_even_loads():

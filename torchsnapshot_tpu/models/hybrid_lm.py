@@ -41,7 +41,19 @@ layers (a layer's held experts are one ``(n, D, F)`` leaf, a layer). The
 matrices are cast to the compute dtype once a step (``compute_params``) and
 the train step differentiates that tree, as ``looped_lm.py`` does; float32
 stay the residual stream, the norms, the convolution, the softplus, the
-decays and their sums, the router, and every matmul result.
+decays and their sums, the router, and every matmul result. The Mamba-2
+input projection is **one leaf and four products**: ``mamba_mixer`` cuts the
+cast ``in_proj`` by columns where it is used and multiplies the stream by
+each part, so ``z``, ``x``, ``B C`` and ``dt`` are matmul results of their
+own (the convolution is depthwise, so it runs on ``x`` and on ``B C`` apart)
+and no slice of a ``(B, S, 10304)`` or ``(B, S, 6144)`` result is copied
+out, nor its gradient pieced together; the cut lies inside the
+differentiated function, so its transpose puts the four gradients back
+into the one ``(D, 10304)`` gradient the optimizer's tree has. The chunked
+recurrence holds its tensors with the chunk position on the last axis
+(``ops/ssm.py`` ``mamba2_chunked`` says why), and the gated output keeps its
+``(B, S, inner)`` through the group norm (``_group_rms``: the groups' sums
+are products with their indicator matrix, not a reshape into other tiles).
 
 Sharding: the batch over 'data', ``embed`` and ``head`` over the vocabulary
 on 'model'; the layers' leaves are replicated (no tensor-parallel layout of
@@ -258,16 +270,31 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return sum(padded[:, j:j + S] * w[j].astype(jnp.float32) for j in range(K)) + b.astype(jnp.float32)
 
 
+def _group_rms(y: jax.Array, groups: int, eps: float) -> jax.Array:
+    """``y / rms`` with the mean square taken over each of ``groups`` equal
+    runs of the last axis, float32. The runs are summed, and the result
+    handed back to their columns, by products with the groups' indicator
+    matrix at ``HIGHEST`` (ones are exact in any operand format, the
+    accumulation is float32): ``y`` stays ``(..., width)`` as the matmuls on
+    both sides hold it, where a reshape to ``(..., groups, width / groups)``
+    is a copy into other tiles and another back."""
+    width = y.shape[-1] // groups
+    member = jnp.repeat(jnp.eye(groups, dtype=jnp.float32), width, axis=0)  # (groups x width, groups)
+    mean = jnp.matmul(jnp.square(y), member, precision=jax.lax.Precision.HIGHEST) / width
+    return y * jnp.matmul(jax.lax.rsqrt(mean + eps), member.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def mamba_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig) -> jax.Array:
     """The ``M`` mixer on the normed stream ``a: (B, S, D)`` -> float32."""
     c, f32 = cfg, jnp.float32
     B, S, _ = a.shape
     H, Pd, G, N = c.mamba_heads, c.mamba_head_dim, c.ssm_groups, c.ssm_state
     with jax.named_scope("mamba2"):
-        z, xbc, dt = jnp.split(_mm(a, w["in_proj"]), [c.mamba_inner, c.mamba_inner + c.conv_width], axis=-1)
-        xbc = jax.nn.silu(_causal_conv(xbc, w["conv_w"], w["conv_b"]))
-        u, b, cmat = jnp.split(xbc, [c.mamba_inner, c.mamba_inner + G * N], axis=-1)
-        u = u.reshape(B, S, H, Pd)
+        # The leaf cut by columns, not the product (module docstring): z | x | B C | dt.
+        inner = c.mamba_inner
+        z, x, bc, dt = (_mm(a, cols) for cols in jnp.split(w["in_proj"], [inner, 2 * inner, inner + c.conv_width], axis=1))
+        u = jax.nn.silu(_causal_conv(x, w["conv_w"][:, :inner], w["conv_b"][:inner])).reshape(B, S, H, Pd)
+        b, cmat = jnp.split(jax.nn.silu(_causal_conv(bc, w["conv_w"][:, inner:], w["conv_b"][inner:])), 2, axis=-1)
         delta = jax.nn.softplus(dt + w["dt_bias"].astype(f32))  # time_step_limit (0, inf) clamps nothing
         decay = -jnp.exp(w["A_log"].astype(f32))
         y = mamba2_chunked(
@@ -275,10 +302,9 @@ def mamba_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig) -> jax.Array:
             b.reshape(B, S, G, N).astype(c.dtype), cmat.reshape(B, S, G, N).astype(c.dtype),
             chunk=c.chunk,
         )
-        y = (y + w["D"].astype(f32)[:, None] * u).reshape(B, S, c.mamba_inner) * jax.nn.silu(z)
-        grouped = y.reshape(B, S, G, c.mamba_inner // G)  # the norm's statistics are a group's
-        y = grouped * jax.lax.rsqrt(jnp.mean(jnp.square(grouped), axis=-1, keepdims=True) + c.norm_eps)
-        return _mm(y.reshape(B, S, c.mamba_inner) * w["gnorm_scale"].astype(f32), w["out_proj"])
+        y = (y + w["D"].astype(f32)[:, None] * u).reshape(B, S, inner) * jax.nn.silu(z)
+        y = _group_rms(y, G, c.norm_eps)  # the norm's statistics are a group's
+        return _mm(y * w["gnorm_scale"].astype(f32), w["out_proj"])
 
 
 def moe_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig) -> Tuple[jax.Array, jax.Array]:

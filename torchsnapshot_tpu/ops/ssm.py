@@ -216,6 +216,17 @@ def ssm_mix_sharded(
 # ------------------------------------------------ Mamba-2, chunked (SSD)
 
 
+def _within_chunk_sum(log_decay: jax.Array) -> jax.Array:
+    """Inclusive sum along the last axis (the chunk position, on the lanes),
+    float32. A product with a lower-triangular matrix of ones at
+    ``HIGHEST``: the ones are exact in any operand format and the
+    accumulation is float32, so this is a float32 sum in another order, and
+    it runs on the MXU where a windowed sum along the lanes does not."""
+    L = log_decay.shape[-1]
+    ones = jnp.triu(jnp.ones((L, L), jnp.float32))  # [s, t]: s <= t
+    return jnp.matmul(log_decay, ones, precision=jax.lax.Precision.HIGHEST)
+
+
 def mamba2_chunked(
     u: jax.Array,
     delta: jax.Array,
@@ -244,6 +255,28 @@ def mamba2_chunked(
     autodiff's of this form: chunked as well. Matmul operands keep the dtype
     ``u``, ``b`` and ``c`` come in; decays, their cumulative sums and every
     accumulation are float32. ``S`` must be a multiple of ``chunk``.
+
+    **The layout**, with ``n`` chunks of ``L`` positions and ``H = G x Hg``:
+    every intermediate has the batch axes of the products, ``(B, n, G, Hg)``,
+    first and 128 or more elements on its last axis, because the chip holds
+    the last two axes of an array in tiles of (8, 128) and a short last axis
+    leaves the rest of each tile empty (``(..., 8, 8)`` fills 64 of 1024
+    places; ``P`` = 64 half the lanes).
+
+    - the decay side, ``(B, n, G, Hg, L)``: ``delta``, ``delta a``, its
+      within-chunk sum ``cs`` (along the last axis), ``to_end``, ``exp(cs)``;
+      the ``[t, s]`` segment differences are ``cs[..., :, None] -
+      cs[..., None, :]`` of that;
+    - the ``u`` side transposed, ``(B, n, G, Hg, P, L)``: ``x = delta u`` and
+      ``y``, so a decay multiplies them along the same last axis and the
+      three products read and write them as they lie (``x mix^T``,
+      ``x_to_end b``, ``h_in c^T``); ``b`` and ``c`` are ``(B, n, G, L, N)``,
+      the chunks' states and the carry ``(B, [n,] G, Hg, P, N)``.
+
+    One transpose in (``u``, ``delta``, ``b``, ``c``) and one out (``y``);
+    none around a product. (On a v5e the compiler makes the one in a copy of
+    the float32 ``u`` and the one out two copies of ``y``, 0.4-0.5 ms each at
+    the published sizes: ``PERF.md``, PR 33.)
     """
     B, S, H, P = u.shape
     G, N = b.shape[2], b.shape[3]
@@ -251,38 +284,39 @@ def mamba2_chunked(
         raise ValueError(f"mamba2_chunked needs S ({S}) % chunk ({chunk}) == 0 and H ({H}) % G ({G}) == 0")
     n, L, Hg, f32 = S // chunk, chunk, H // G, jnp.float32
 
-    log_decay = (delta.astype(f32) * a.astype(f32)).reshape(B, n, L, G, Hg)
-    cs = jnp.cumsum(log_decay, axis=2)  # inclusive, within the chunk; <= 0
-    cs_h = jnp.moveaxis(cs, 2, -1)  # (B, n, G, Hg, L)
-    x = (u.astype(f32) * delta.astype(f32)[..., None]).astype(u.dtype).reshape(B, n, L, G, Hg, P)
-    bc, cc = b.reshape(B, n, L, G, N), c.reshape(B, n, L, G, N)
+    dt = jnp.swapaxes(delta.astype(f32).reshape(B, n, L, H), 2, 3).reshape(B, n, G, Hg, L)
+    cs = _within_chunk_sum(dt * a.astype(f32).reshape(G, Hg, 1))  # inclusive, within the chunk; <= 0
+    x = jnp.transpose(u.reshape(B, n, L, G, Hg, P), (0, 1, 3, 4, 5, 2))  # (B, n, G, Hg, P, L)
+    x = (x.astype(f32) * dt[..., None, :]).astype(u.dtype)
+    bc = jnp.transpose(b.reshape(B, n, L, G, N), (0, 1, 3, 2, 4))  # (B, n, G, L, N)
+    cc = jnp.transpose(c.reshape(B, n, L, G, N), (0, 1, 3, 2, 4))
 
     # Within a chunk: y_t += sum_{s<=t} (c_t . b_s) exp(cs_t - cs_s) x_s.
     # The mask goes on before the exp: above the diagonal cs_t - cs_s > 0.
-    cb = jnp.einsum("bnlgk,bnsgk->bngls", cc, bc, preferred_element_type=f32)
-    seg = cs_h[..., :, None] - cs_h[..., None, :]  # (B, n, G, Hg, L, L): [t, s]
+    cb = jnp.einsum("bnglk,bngsk->bngls", cc, bc, preferred_element_type=f32)
+    seg = cs[..., :, None] - cs[..., None, :]  # (B, n, G, Hg, L, L): [t, s]
     causal = jnp.tril(jnp.ones((L, L), bool))
     mix = (cb[:, :, :, None] * jnp.exp(jnp.where(causal, seg, -jnp.inf))).astype(u.dtype)
-    y = jnp.einsum("bnghls,bnsghp->bnlghp", mix, x, preferred_element_type=f32)
+    y = jnp.einsum("bnghps,bnghls->bnghpl", x, mix, preferred_element_type=f32)
 
     # What each chunk adds to the state by its end, and the state each
     # chunk starts from: h_in[j+1] = exp(sum of chunk j) h_in[j] + states[j].
-    to_end = jnp.exp(cs[:, :, -1:] - cs)  # (B, n, L, G, Hg)
+    to_end = jnp.exp(cs[..., -1:] - cs)  # (B, n, G, Hg, L)
     states = jnp.einsum(
-        "bnsgk,bnsghp->bnghpk", bc, (x.astype(f32) * to_end[..., None]).astype(u.dtype),
+        "bnghps,bngsk->bnghpk", (x.astype(f32) * to_end[..., None, :]).astype(u.dtype), bc,
         preferred_element_type=f32,
     )
-    chunk_decay = jnp.exp(cs[:, :, -1])  # (B, n, G, Hg)
+    chunk_decay = jnp.exp(cs[..., -1])  # (B, n, G, Hg)
 
-    def carry(h, xs):
+    def carry(h, xs):  # float32 carried; handed on as the operand the last product reads
         decay, add = xs
-        return decay[..., None, None] * h + add, h
+        return decay[..., None, None] * h + add, h.astype(u.dtype)
 
     _, h_in = jax.lax.scan(
         carry, jnp.zeros((B, G, Hg, P, N), f32),
         (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(states, 1, 0)),
     )
-    h_in = jnp.moveaxis(h_in, 0, 1).astype(u.dtype)  # (B, n, G, Hg, P, N)
-    y_off = jnp.einsum("bnlgk,bnghpk->bnlghp", cc, h_in, preferred_element_type=f32)
-    y = y + y_off * jnp.exp(cs)[..., None]
-    return y.reshape(B, S, H, P)
+    h_in = jnp.moveaxis(h_in, 0, 1)  # (B, n, G, Hg, P, N)
+    y_off = jnp.einsum("bnghpk,bnglk->bnghpl", h_in, cc, preferred_element_type=f32)
+    y = y + y_off * jnp.exp(cs)[..., None, :]
+    return jnp.transpose(y, (0, 1, 5, 2, 3, 4)).reshape(B, S, H, P)
