@@ -189,3 +189,22 @@ def test_ulysses_flash_inner() -> None:
         q, k, v, mesh, causal=True, inner="flash", inner_block_size=16
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_the_lines_the_mosaic_modules_record_have_not_moved() -> None:
+    """The Mosaic module of each kernel holds source locations of this
+    file: the ``where`` of the causal mask (under shard_map), the three
+    ``pallas_call``s and ``flash_attention``'s call of the kernel. A line
+    more or fewer ahead of any of them changes the lowered train step, and
+    with it the compile-cache key, of every model that runs the kernels
+    (PERF.md, PRs 32 and 34). Move them knowingly: re-base every cell."""
+    import inspect
+
+    from torchsnapshot_tpu.ops import pallas_attention as pa
+
+    lines = inspect.getsource(pa).splitlines()
+    at = lambda text: [i + 1 for i, line in enumerate(lines) if text in line]  # noqa: E731
+    assert at("return jnp.where(q_pos >= k_pos, s, NEG_INF)") == [41]
+    assert at("pl.pallas_call(") == [234, 260, 275]
+    assert at("out = flash(qt, kt, vt)") == [373]
+    assert at("    return jax.shard_map(") == [421]

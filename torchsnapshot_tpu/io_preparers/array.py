@@ -694,8 +694,23 @@ class ArrayBufferStager(BufferStager):
     (and for numpy inputs) an explicit copy is made.
     """
 
-    def __init__(self, arr, entry: Optional[ArrayEntry] = None) -> None:
+    def __init__(
+        self,
+        arr,
+        entry: Optional[ArrayEntry] = None,
+        index: Optional[Tuple[slice, ...]] = None,
+    ) -> None:
+        # ``index``: this payload is ``arr[index]``, cut when it is staged
+        # (``_cut``) and not before, so a chunk of a device array holds no
+        # device copy from prepare time on: the cut is made inside the
+        # DtoH window and dropped with the staged payload, and the device
+        # memory a save adds is bounded by the window, not by the state.
         self.arr = arr
+        self.index = index
+        self.shape = (
+            tuple(arr.shape) if index is None else
+            tuple(len(range(*ix.indices(n))) for ix, n in zip(index, arr.shape))
+        )
         # When given, the entry's checksum is recorded at stage time (the
         # manifest is gathered/committed after staging completes, so the
         # mutation is visible in the persisted metadata).
@@ -709,6 +724,20 @@ class ArrayBufferStager(BufferStager):
         # Set at stage time when the payload matched the dedup base: the
         # scheduler then releases the buffer without writing it.
         self.io_skipped = False
+
+    def _nbytes(self) -> int:
+        return array_size_bytes(self.shape, dtype_to_string(self.arr.dtype))
+
+    def _cut(self):
+        """The array this stager stages: ``arr`` itself, or its chunk. The
+        ``stage_chunk_cut`` span is the dispatch of the device slice (the
+        slice itself runs ahead of the chunk's DtoH transfer, on the
+        device's own queue)."""
+        if self.index is None:
+            return self.arr
+        with telemetry.span("stage_chunk_cut", cat="stager", bytes=self._nbytes()):
+            telemetry.counter_add("chunk_payloads", 1)
+            return self.arr[self.index]
 
     def _needs_consistency_copy(self, arr) -> bool:
         """The module-level platform rule (needs_consistency_copy), gated
@@ -935,11 +964,10 @@ class ArrayBufferStager(BufferStager):
             return False
         if self.entry is not None and self.entry.byte_range is not None:
             return False
-        arr = self.arr
-        shape = getattr(arr, "shape", None)
-        if shape is None or 0 in tuple(shape):
+        arr, shape = self.arr, self.shape
+        if 0 in shape:
             return False
-        nbytes = array_nbytes(arr)
+        nbytes = self._nbytes()
         # A stream of one chunk is a buffered write with extra hops.
         if nbytes < 2 * sub_chunk_bytes:
             return False
@@ -1029,7 +1057,7 @@ class ArrayBufferStager(BufferStager):
         the chunk being written plus the chunk being staged — the
         _STREAM_DEPTH window the scheduler's budget charges. All byte
         work runs in the executor, never on the event loop."""
-        arr = self.arr
+        arr = self._cut()
         loop = asyncio.get_running_loop()
         state = self._stream_checksum_init()
         device_backed = _is_jax_array(arr) and _device_backed(arr)
@@ -1100,6 +1128,8 @@ class ArrayBufferStager(BufferStager):
         loop = asyncio.get_running_loop()
         record_fp = False
         if self._device_dedup_candidate(arr):
+            # The fingerprint is the payload's own: a chunk is cut ahead of it.
+            arr = self._cut()
             ref = self.dedup.refs.get(self.entry.location)
             if ref is not None and ref.device_digest is not None:
                 # A skip is possible: fingerprint BEFORE kicking the DtoH
@@ -1127,9 +1157,11 @@ class ArrayBufferStager(BufferStager):
         # the chip: PERF.md, PR 31). CPU arrays have no DMA and bypass it.
         window = dtoh_window.get() if is_jax and _device_backed(arr) else None
         if window is not None:
-            nbytes = array_nbytes(arr)
+            nbytes = self._nbytes()
             await window.admit(nbytes)
         try:
+            if arr is self.arr:
+                arr = self._cut()
             if is_jax:
                 # Kick off the DMA before blocking. No except: a failed
                 # kick would silently turn the overlapped DtoH into a
@@ -1151,10 +1183,17 @@ class ArrayBufferStager(BufferStager):
         finally:
             # Staged, or a stage that raised or was cancelled.
             if window is not None:
+                if arr is not self.arr:
+                    # The cut's device copy goes with its bytes in the
+                    # window, not with the last reference to what was
+                    # staged from it (the host copy is the runtime's own
+                    # memory): six 480 MiB cuts were alive at once on the
+                    # chip under a window that admits four (PERF.md, PR 34).
+                    arr.delete()
                 window.release(nbytes)
 
     def get_staging_cost_bytes(self) -> int:
-        return array_nbytes(self.arr)
+        return self._nbytes()
 
 
 @dataclass
@@ -1513,18 +1552,22 @@ class ArrayBufferConsumer(BufferConsumer):
 class ArrayIOPreparer:
     @staticmethod
     def prepare_write(
-        storage_path: str, arr, replicated: bool = False
+        storage_path: str,
+        arr,
+        replicated: bool = False,
+        index: Optional[Tuple[slice, ...]] = None,
     ) -> Tuple[ArrayEntry, List[WriteReq]]:
-        entry = ArrayEntry(
+        """One payload: ``arr``, or with ``index`` the chunk ``arr[index]``,
+        which the stager cuts when it stages it."""
+        stager = ArrayBufferStager(arr, index=index)
+        stager.entry = entry = ArrayEntry(
             location=storage_path,
             serializer=Serializer.BUFFER_PROTOCOL.value,
             dtype=dtype_to_string(arr.dtype),
-            shape=list(arr.shape),
+            shape=list(stager.shape),
             replicated=replicated,
         )
-        return entry, [
-            WriteReq(path=storage_path, buffer_stager=ArrayBufferStager(arr, entry))
-        ]
+        return entry, [WriteReq(path=storage_path, buffer_stager=stager)]
 
     @staticmethod
     def prepare_read(

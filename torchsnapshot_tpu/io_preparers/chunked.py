@@ -108,17 +108,19 @@ class ChunkedArrayIOPreparer:
         chunks: List[Shard] = []
         write_reqs: List[WriteReq] = []
         for offsets, sizes in local_chunks:
-            if offsets:
+            # A chunk is named here and cut when it is staged: a slice of a
+            # device array made now would be a device copy that lives until
+            # the save is done, one per chunk, a second state beside the
+            # first (PERF.md, PRs 22 and 34).
+            index = None
+            if list(sizes) != list(arr.shape):  # a chunk that is the whole array is not cut
                 index = tuple(slice(o, o + s) for o, s in zip(offsets, sizes))
-                sub = arr[index]
-            else:
-                sub = arr
             suffix = "_".join(str(o) for o in offsets)
             location = (
                 f"{storage_path_prefix}_{suffix}" if suffix else storage_path_prefix
             )
             chunk_entry, reqs = ArrayIOPreparer.prepare_write(
-                location, sub, replicated=replicated
+                location, arr, replicated=replicated, index=index
             )
             chunks.append(Shard(offsets=list(offsets), sizes=list(sizes), array=chunk_entry))
             write_reqs.extend(reqs)

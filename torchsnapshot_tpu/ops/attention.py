@@ -13,6 +13,7 @@ compiled program size flat as sequence length grows.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -20,6 +21,90 @@ import jax.numpy as jnp
 
 # Effectively -inf for masking without producing NaNs in exp()/max() chains.
 NEG_INF = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """The block-diffusion training mask over ``2 * half`` positions: the
+    clean copy of a sequence (positions ``0 .. half-1``) followed by its
+    noised copy, both carrying the ids ``0 .. half-1``, in blocks of
+    ``block`` ids (``blk = id // block``)::
+
+        clean query  -> clean key   iff blk(key) <= blk(query)
+        clean query  -> noised key  never
+        noised query -> clean key   iff blk(key) <  blk(query)
+        noised query -> noised key  iff blk(key) == blk(query)
+
+    ``allowed`` is the one statement of the rule: the dense and blockwise
+    routes and the three Pallas kernels all call it. The kernels work in
+    square tiles of ``tile`` positions that divide ``half``, so a tile lies
+    in one copy, and visit only tiles that hold a live score: ``k_tiles``
+    and ``q_tiles`` enumerate them, for a tile index that may be traced.
+    Every query sees its own block, so no row is empty."""
+
+    half: int
+    block: int
+
+    def __post_init__(self):
+        if self.half <= 0 or self.block <= 0 or self.half % self.block:
+            raise ValueError(f"blocks of {self.block} do not tile {self.half} positions")
+
+    def allowed(self, q_pos, k_pos):
+        """Whether the query at ``q_pos`` sees the key at ``k_pos``
+        (positions in ``0 .. 2 * half - 1``, any shapes that broadcast).
+        Integer arithmetic and two comparisons, which is what the kernels'
+        compiler takes on vectors: a noised position's block is numbered
+        ``half`` higher than its clean twin's, past every clean block, so
+        "the same block of the same copy" is one equality and "an earlier
+        clean block" one inequality."""
+        q_copy, k_copy = (jnp.where(pos >= self.half, 1, 0) for pos in (q_pos, k_pos))
+        q_blk = jax.lax.div(q_pos - q_copy * self.half, self.block)
+        k_blk = jax.lax.div(k_pos - k_copy * self.half, self.block) + k_copy * self.half
+        return (k_blk == q_blk + q_copy * self.half) | (k_blk < q_blk)
+
+    def tile(self, configured: int) -> Optional[int]:
+        """The kernels' tile for a target of ``configured`` positions: a
+        divisor of ``half`` that whole blocks fill, or None."""
+        bs = pick_block_size(self.half, configured)
+        return bs if bs is not None and bs % self.block == 0 else None
+
+    def k_tiles(self, qi, tile: int):
+        """The key tiles that hold a live score for query tile ``qi``:
+        ``(count, j -> tile index)``. A clean query tile sees the clean
+        tiles up to its own; a noised one its own noised tile (first: every
+        row has a live key there, so the online softmax starts from a real
+        maximum) and then the clean tiles up to its clean twin, the twin
+        itself unless the tile is a single block."""
+        n, twin = self.half // tile, int(tile > self.block)
+        noised = qi >= n
+        return (
+            jnp.where(noised, qi - n + 1 + twin, qi + 1),
+            lambda j: jnp.where(noised, jnp.where(j == 0, qi, j - 1), j),
+        )
+
+    def q_tiles(self, ki, tile: int):
+        """The query tiles that hold a live score for key tile ``ki``: a
+        clean key tile is seen from its own clean tile on and from its
+        noised twin on (from the one after, where the tile is a single
+        block), a noised one by itself alone."""
+        n, past = self.half // tile, int(tile == self.block)
+        clean = ki < n
+        return (
+            jnp.where(clean, 2 * (n - ki) - past, 1),
+            lambda j: jnp.where(clean, jnp.where(j < n - ki, ki + j, 2 * ki + j + past), ki),
+        )
+
+    def live_tiles(self, tile: int) -> int:
+        """Tiles of ``tile x tile`` scores that the kernels compute, of the
+        ``(2 * half / tile)^2`` a masked-dense pass would."""
+        return sum(int(self.k_tiles(qi, tile)[0]) for qi in range(2 * self.half // tile))
+
+
+def _allowed(causal: bool, mask: Optional[BlockDiffusionMask], q_pos, k_pos):
+    """The (Sq, Sk) boolean of scores that count, or None for all of them."""
+    if mask is not None:
+        return mask.allowed(q_pos[:, None], k_pos[None, :])
+    return q_pos[:, None] >= k_pos[None, :] if causal else None
 
 
 def pick_block_size(seq_len: int, configured: int) -> Optional[int]:
@@ -45,26 +130,26 @@ def dense_attention(
     scale: Optional[float] = None,
     q_offset: int = 0,
     k_offset: int = 0,
+    mask: Optional[BlockDiffusionMask] = None,
 ) -> jax.Array:
     """Reference O(S^2)-memory attention. ``q, k, v: (B, S, H, D)``.
 
     ``q_offset``/``k_offset`` are the global positions of the first query /
-    key — used when q and k are shards of a longer sequence.
+    key — used when q and k are shards of a longer sequence. A ``mask``
+    takes the causal rule's place.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
-        q_pos = q_offset + jnp.arange(q.shape[1])
-        k_pos = k_offset + jnp.arange(k.shape[1])
-        mask = q_pos[:, None] >= k_pos[None, :]
-        s = jnp.where(mask[None, None], s, NEG_INF)
+    allowed = _allowed(causal, mask, q_offset + jnp.arange(q.shape[1]), k_offset + jnp.arange(k.shape[1]))
+    if allowed is not None:
+        s = jnp.where(allowed[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    if causal:
+    if allowed is not None:
         # A query row with no valid key (reachable via k_offset > q_offset
         # on sharded calls) must attend to nothing, not uniformly to
         # everything — softmax of an all-NEG_INF row is uniform.
-        row_valid = mask.any(axis=-1)  # (Sq, Sk) -> (Sq,)
+        row_valid = allowed.any(axis=-1)  # (Sq, Sk) -> (Sq,)
         p = jnp.where(row_valid[None, None, :, None], p, 0.0)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
@@ -79,6 +164,7 @@ def attention_block_update(
     scale: float,
     causal: bool,
     acc: Tuple[jax.Array, jax.Array, jax.Array],
+    mask: Optional[BlockDiffusionMask] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One online-softmax update of accumulator ``acc = (o, m, l)`` with a
     (q-block, kv-block) pair.
@@ -91,12 +177,15 @@ def attention_block_update(
     as long as the first block processed for every query row contains at
     least one valid key (true for causal self-attention, where the diagonal
     block is always processed first), ``exp(score - m)`` underflows to 0.
+    A row whose first blocks are wholly masked (a ``mask``'s noised
+    queries) collects weight-1 rows there and drops them, ``alpha = 0``,
+    at its first live score; NEG_INF is finite, so nothing overflows.
     """
     o, m, l = acc
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
-        mask = q_pos[:, None] >= k_pos[None, :]
-        s = jnp.where(mask[None, None], s, NEG_INF)
+    allowed = _allowed(causal, mask, q_pos, k_pos)
+    if allowed is not None:
+        s = jnp.where(allowed[None, None], s, NEG_INF)
     m_new = jnp.maximum(m, s.max(axis=-1))
     p = jnp.exp(s - m_new[..., None])
     alpha = jnp.exp(m - m_new)  # (B, H, Sq)
@@ -119,11 +208,13 @@ def blockwise_attention(
     block_size: int = 512,
     causal: bool = True,
     scale: Optional[float] = None,
+    mask: Optional[BlockDiffusionMask] = None,
 ) -> jax.Array:
     """Flash-style attention: scan over K/V blocks with an online softmax.
 
     ``q, k, v: (B, S, H, D)`` with S divisible by ``block_size`` (callers pad;
-    a static check enforces it so XLA never sees dynamic shapes).
+    a static check enforces it so XLA never sees dynamic shapes). Every
+    block is computed and masked, under the causal rule or a ``mask``.
     """
     B, S, H, D = q.shape
     if scale is None:
@@ -141,7 +232,7 @@ def blockwise_attention(
         k_blk, v_blk, j = blk
         k_pos = j * block_size + jnp.arange(block_size)
         acc = attention_block_update(
-            q, k_blk, v_blk, q_pos, k_pos, scale, causal, acc
+            q, k_blk, v_blk, q_pos, k_pos, scale, causal, acc, mask
         )
         return acc, None
 
@@ -180,10 +271,20 @@ def flash_mesh_ok(n_heads: int, mesh, B: int, S: int) -> bool:
     return S > 0 and pick_block_size(S, 512) is not None
 
 
-def _select_route(attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S: int) -> str:
+def _tile(S: int, block_size: int, mask: Optional[BlockDiffusionMask]) -> Optional[int]:
+    """The tile the blockwise and flash routes cut S into, or None."""
+    return pick_block_size(S, block_size) if mask is None else mask.tile(block_size)
+
+
+def _select_route(
+    attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S: int, mask: Optional[BlockDiffusionMask] = None
+) -> str:
     if attn_impl not in ATTN_IMPLS:
         raise ValueError(f"unknown attn_impl {attn_impl!r}")
+    if mask is not None and (S != 2 * mask.half or attn_impl in ("ring", "zigzag", "ulysses")):
+        raise ValueError(f"a block-diffusion mask over {2 * mask.half} positions: S is {S}, attn_impl {attn_impl!r}")
     on_tpu = jax.default_backend() == "tpu"
+    tiled = S if mask is None else mask.half  # what the kernels' tiles divide
     impl = attn_impl
     if impl == "auto":
         # Backend-aware kernel choice: the Pallas flash kernel on TPU —
@@ -194,7 +295,7 @@ def _select_route(attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S
         # (O(S*block) memory); dense for short sequences. Never selects a
         # cp impl — ring/zigzag/ulysses are mesh topology decisions for
         # the caller.
-        if on_tpu and (mesh is None or flash_mesh_ok(n_heads, mesh, B, S)):
+        if on_tpu and (mesh is None or flash_mesh_ok(n_heads, mesh, B, tiled)):
             impl = "flash"
         elif S > block_size:
             impl = "blockwise"
@@ -221,23 +322,29 @@ def _select_route(attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S
             return impl + "_flash"
         return impl
     if impl in ("blockwise", "flash"):
-        if pick_block_size(S, block_size) is None:
+        if _tile(S, block_size, mask) is None:
             return "dense"
         if impl == "flash" and mesh is not None:
             # Under a mesh the bare pallas_call would make GSPMD gather
             # the sharded operands; shard_map the kernel instead, or give
             # way to blockwise when the preconditions don't hold.
-            return "flash_sharded" if flash_mesh_ok(n_heads, mesh, B, S) else "blockwise"
+            ok = flash_mesh_ok(n_heads, mesh, B, tiled) and _tile(S, 512, mask)
+            return "flash_sharded" if ok else "blockwise"
     return impl
 
 
 def causal_attention_route(
-    attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S: int
+    attn_impl: str, block_size: int, n_heads: int, mesh, B: int, S: int,
+    mask: Optional[BlockDiffusionMask] = None,
 ) -> Tuple[str, Callable[..., jax.Array]]:
     """The causal attention a model runs for this request, mesh and shape:
     the route's name and ``attend(q, k, v, in_layout=False)`` on logical
     ``(B, S, H, hd)`` operands (sharding via the caller's constraints);
     ``k`` and ``v`` may come with fewer heads, one a group of query heads.
+    With a ``mask`` (``BlockDiffusionMask``; S is then the clean and the
+    noised copy together) its rule takes the causal one's place on the
+    dense, blockwise and flash routes, whose tiles then divide one copy;
+    the context-parallel routes do not take one.
 
     ``attn_impl`` is a request; what runs also depends on things the code
     observes (backend, mesh axes, whether S tiles), and a request that
@@ -251,7 +358,7 @@ def causal_attention_route(
     ``in_layout`` tells the zigzag routes that the caller already applied
     the folded layout.
     """
-    route = _select_route(attn_impl, block_size, n_heads, mesh, B, S)
+    route = _select_route(attn_impl, block_size, n_heads, mesh, B, S, mask)
 
     def attend(q, k, v, in_layout: bool = False):
         if k.shape[2] != q.shape[2]:
@@ -287,15 +394,15 @@ def causal_attention_route(
         if route == "flash_sharded":
             from .pallas_attention import flash_attention_sharded
 
-            return flash_attention_sharded(q, k, v, mesh, causal=True)
+            return flash_attention_sharded(q, k, v, mesh, causal=True, mask=mask)
         if route == "flash":
             from .pallas_attention import flash_attention
 
-            bs = pick_block_size(S, block_size)
-            return flash_attention(q, k, v, causal=True, block_q=bs, block_k=bs)
+            bs = _tile(S, block_size, mask)
+            return flash_attention(q, k, v, causal=True, block_q=bs, block_k=bs, mask=mask)
         if route == "blockwise":
-            bs = pick_block_size(S, block_size)
-            return blockwise_attention(q, k, v, block_size=bs, causal=True)
-        return dense_attention(q, k, v, causal=True)
+            bs = _tile(S, block_size, mask)
+            return blockwise_attention(q, k, v, block_size=bs, causal=True, mask=mask)
+        return dense_attention(q, k, v, causal=True, mask=mask)
 
     return route, attend

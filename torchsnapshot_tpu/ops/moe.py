@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -311,7 +312,7 @@ def moe_ffn_sharded(
     return y, aux
 
 
-# ------------------------------- sigmoid top-k, dropless, a share of the experts
+# ------------------------------- top-k of many, dropless, a share of the experts
 
 
 def relu2_ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
@@ -320,6 +321,16 @@ def relu2_ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
     h = jnp.matmul(x.astype(w_up.dtype), w_up, preferred_element_type=jnp.float32)
     a = jnp.square(jax.nn.relu(h)).astype(w_down.dtype)
     return jnp.matmul(a, w_down, preferred_element_type=jnp.float32)
+
+
+def gated_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """``(silu(x w_gate) * (x w_up)) w_down``: the gated three-matrix expert.
+    Operands in the weights' dtype, the three products accumulated in
+    float32, the gate and the gated product in float32."""
+    x = x.astype(w_gate.dtype)
+    g = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.matmul((jax.nn.silu(g) * u).astype(w_down.dtype), w_down, preferred_element_type=jnp.float32)
 
 
 def sigmoid_topk_route(
@@ -339,10 +350,46 @@ def sigmoid_topk_route(
     return ids, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _held_experts(x2, w_held, order, counts, w_up, w_down, tile):
-    """``sum_e w_held[e, t] relu2_ffn(x2[t]; w_up[e], w_down[e])`` over the
-    tokens that chose expert e: ``(T, D) -> (T, D)`` float32.
+def softmax_topk_route(x2: jax.Array, router: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """``(T, D)`` tokens -> the ids ``(T, k)`` of the ``top_k`` experts with
+    the largest ``softmax(x router)`` over **all** experts, and their
+    weights ``(T, k)``: the chosen probabilities over their own sum
+    (``norm_topk_prob``). All of it in float32, the product at full
+    precision, as the sigmoid sibling says."""
+    f32 = jnp.float32
+    logits = jnp.matmul(x2.astype(f32), router.astype(f32), precision=jax.lax.Precision.HIGHEST)
+    chosen, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return ids, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+
+
+# An expert is its matrices, the last one down: two of them are
+# ``relu2_ffn``, three are ``gated_ffn``. What differs between the two in the
+# backward pass is the hidden activation from the products ``x w`` of the
+# matrices ahead of the last, and its derivative: per kind, ``hs -> (a, da ->
+# the cotangent of each h)``, all float32.
+
+
+def _relu2_hidden(hs):
+    (r,) = (jax.nn.relu(h) for h in hs)
+    return jnp.square(r), lambda da: (da * 2.0 * r,)
+
+
+def _gated_hidden(hs):
+    g, u = hs
+    sig = jax.nn.sigmoid(g)
+    act = g * sig  # silu(g)
+    return act * u, lambda da: (da * u * (sig + act * (1.0 - sig)), da * act)
+
+
+_EXPERTS = {2: (relu2_ffn, _relu2_hidden), 3: (gated_ffn, _gated_hidden)}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_experts(x2, w_held, order, counts, ws, tile):
+    """``sum_e w_held[e, t] f(x2[t]; ws[..][e])`` over the tokens that chose
+    expert e: ``(T, D) -> (T, D)`` float32. ``ws`` are the experts' stacked
+    matrices, ``(up, down)`` for ``relu2_ffn`` or ``(gate, up, down)`` for
+    ``gated_ffn``: what is given chooses the expert.
 
     ``order[e]`` lists the tokens, those of expert e first (``counts[e]`` of
     them), and ``w_held[e]`` is 0 on everyone else. Each expert walks its
@@ -353,60 +400,95 @@ def _held_experts(x2, w_held, order, counts, w_up, w_down, tile):
     held. The trip counts are data, which
     reverse-mode autodiff cannot transpose, so the backward pass is written
     out below with the same loops."""
-    return _held_experts_fwd(x2, w_held, order, counts, w_up, w_down, tile)[0]
+    return _held_experts_fwd(x2, w_held, order, counts, ws, tile)[0]
 
 
-def _held_experts_fwd(x2, w_held, order, counts, w_up, w_down, tile):
+def _held_experts_fwd(x2, w_held, order, counts, ws, tile):
     f32 = jnp.float32
+    ffn = _EXPERTS[len(ws)][0]
 
     def one(y, args):
-        w_e, order_e, n, up, down = args
+        w_e, order_e, n, w = args
 
         def body(j, y):
             idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
-            o = relu2_ffn(x2[idx], up, down)
+            o = ffn(x2[idx], *w)
             return y.at[idx].add(w_e[idx][:, None] * o, unique_indices=True)
 
         return jax.lax.fori_loop(0, (n + tile - 1) // tile, body, y), None
 
-    y, _ = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, w_up, w_down))
-    return y, (x2, w_held, order, counts, w_up, w_down)
+    y, _ = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, ws))
+    return y, (x2, w_held, order, counts, ws)
 
 
 def _held_experts_bwd(tile, res, g):
-    x2, w_held, order, counts, w_up, w_down = res
+    x2, w_held, order, counts, ws = res
     f32, T = jnp.float32, x2.shape[0]
+    hidden = _EXPERTS[len(ws)][1]
     contract0 = (((0,), (0,)), ((), ()))  # a^T b
     contract1 = (((1,), (1,)), ((), ()))  # a b^T
 
     def one(dx, args):
-        w_e, order_e, n, up, down = args
+        w_e, order_e, n, w = args
+        ins, down = w[:-1], w[-1]
 
         def body(j, carry):
-            dx, dw, dup, ddown = carry
+            dx, dw, dins, ddown = carry
             idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
             rows, go = x2[idx], g[idx]
-            r = jax.nn.relu(jnp.matmul(rows, up, preferred_element_type=f32))
-            a = jnp.square(r).astype(down.dtype)
+            act, d_hidden = hidden([jnp.matmul(rows, m, preferred_element_type=f32) for m in ins])
+            a = act.astype(down.dtype)
             o = jnp.matmul(a, down, preferred_element_type=f32)
             dw = dw.at[idx].set(jnp.sum(o * go, axis=-1), unique_indices=True)
             do = (w_e[idx][:, None] * go).astype(down.dtype)
             ddown = ddown + jax.lax.dot_general(a, do, contract0, preferred_element_type=f32)
-            dh = (jax.lax.dot_general(do, down, contract1, preferred_element_type=f32) * 2.0 * r).astype(up.dtype)
-            dup = dup + jax.lax.dot_general(rows, dh, contract0, preferred_element_type=f32)
-            drows = jax.lax.dot_general(dh, up, contract1, preferred_element_type=f32)
-            return dx.at[idx].add(drows, unique_indices=True), dw, dup, ddown
+            dhs = d_hidden(jax.lax.dot_general(do, down, contract1, preferred_element_type=f32))
+            dhs = [dh.astype(m.dtype) for dh, m in zip(dhs, ins)]
+            dins = tuple(
+                acc + jax.lax.dot_general(rows, dh, contract0, preferred_element_type=f32)
+                for acc, dh in zip(dins, dhs)
+            )
+            drows = functools.reduce(
+                operator.add,
+                [jax.lax.dot_general(dh, m, contract1, preferred_element_type=f32) for dh, m in zip(dhs, ins)],
+            )
+            return dx.at[idx].add(drows, unique_indices=True), dw, dins, ddown
 
-        init = (dx, jnp.zeros((T,), f32), jnp.zeros(up.shape, f32), jnp.zeros(down.shape, f32))
-        dx, dw, dup, ddown = jax.lax.fori_loop(0, (n + tile - 1) // tile, body, init)
-        return dx, (dw, dup.astype(up.dtype), ddown.astype(down.dtype))
+        init = (dx, jnp.zeros((T,), f32), tuple(jnp.zeros(m.shape, f32) for m in ins), jnp.zeros(down.shape, f32))
+        dx, dw, dins, ddown = jax.lax.fori_loop(0, (n + tile - 1) // tile, body, init)
+        return dx, (dw, tuple(d.astype(m.dtype) for d, m in zip(dins, ins)) + (ddown.astype(down.dtype),))
 
-    dx, (dw, dup, ddown) = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, w_up, w_down))
+    dx, (dw, dws) = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, ws))
     none = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
-    return dx.astype(x2.dtype), dw, none(order), none(counts), dup, ddown
+    return dx.astype(x2.dtype), dw, none(order), none(counts), dws
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Array, ...], tile: int):
+    """What the experts held here add for ``x: (..., D)``: (float32 of the
+    same shape, the chosen ids ``(T, k)``). ``route(x2) -> (ids, weights)``
+    scores and chooses over all experts; ``ws`` are the held experts'
+    stacked matrices (``_held_experts``)."""
+    orig_shape = x.shape
+    x2 = x.reshape(-1, orig_shape[-1])
+    T, n = x2.shape[0], len(held)
+    if ws[0].shape[0] != n:
+        raise ValueError(f"{n} expert ids held, {ws[0].shape[0]} experts' weights given")
+    with jax.named_scope("moe_route"):
+        ids, weights = route(x2)
+        hit = ids[None] == jnp.asarray(held, ids.dtype)[:, None, None]  # (n, T, k)
+        member = jnp.any(hit, axis=-1)  # (n, T)
+        w_held = jnp.sum(jnp.where(hit, weights[None], 0.0), axis=-1)  # (n, T) float32, 0 off the expert
+        counts = jnp.sum(member, axis=-1, dtype=jnp.int32)
+        # Each expert's list of all T tokens, its own first, in token order.
+        order = jnp.argsort(~member, axis=-1, stable=True).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        y = _held_experts(
+            x2.astype(ws[0].dtype), w_held, order, counts, ws, math.gcd(T, tile),  # tiles cover the list exactly
+        )
+    return y.reshape(orig_shape), ids
 
 
 def sigmoid_topk_routed(
@@ -442,23 +524,24 @@ def sigmoid_topk_routed(
     step then costs the same for any load up to 512 tokens an expert
     (256 and 1024 were measured on the chip: PERF.md, PR 32).
     """
-    orig_shape = x.shape
-    D = orig_shape[-1]
-    x2 = x.reshape(-1, D)
-    T, n = x2.shape[0], len(held)
-    if params["expert_up"].shape[0] != n:
-        raise ValueError(f"{n} expert ids held, {params['expert_up'].shape[0]} experts' weights given")
-    with jax.named_scope("moe_route"):
-        ids, weights = sigmoid_topk_route(x2, params["router"], params["router_bias"], top_k, routed_scale)
-        hit = ids[None] == jnp.asarray(held, ids.dtype)[:, None, None]  # (n, T, k)
-        member = jnp.any(hit, axis=-1)  # (n, T)
-        w_held = jnp.sum(jnp.where(hit, weights[None], 0.0), axis=-1)  # (n, T) float32, 0 off the expert
-        counts = jnp.sum(member, axis=-1, dtype=jnp.int32)
-        # Each expert's list of all T tokens, its own first, in token order.
-        order = jnp.argsort(~member, axis=-1, stable=True).astype(jnp.int32)
-    with jax.named_scope("moe_experts"):
-        y = _held_experts(
-            x2.astype(params["expert_up"].dtype), w_held, order, counts,
-            params["expert_up"], params["expert_down"], math.gcd(T, tile),  # tiles cover the list exactly
-        )
-    return y.reshape(orig_shape), ids
+    return _routed_to_held(
+        x,
+        lambda x2: sigmoid_topk_route(x2, params["router"], params["router_bias"], top_k, routed_scale),
+        held, (params["expert_up"], params["expert_down"]), tile,
+    )
+
+
+def softmax_topk_routed(
+    params: Dict[str, Any], x: jax.Array, *, top_k: int, held: Tuple[int, ...], tile: int = 512
+) -> Tuple[jax.Array, jax.Array]:
+    """The softmax sibling, with gated experts: ``router (D, E)`` over all
+    E, ``expert_gate`` and ``expert_up (n, D, F)``, ``expert_down (n, F,
+    D)`` of the held ones. Scores are ``softmax`` over all E, the weights
+    the ``top_k`` chosen probabilities over their sum, the result ``sum of
+    w_i (silu(x G_i) * (x U_i)) D_i`` over the chosen experts held here;
+    dropless and tiled as ``sigmoid_topk_routed`` says."""
+    return _routed_to_held(
+        x,
+        lambda x2: softmax_topk_route(x2, params["router"], top_k),
+        held, (params["expert_gate"], params["expert_up"], params["expert_down"]), tile,
+    )

@@ -14,11 +14,11 @@ emits the per-row log-sum-exp, and the backward recomputes each softmax
 block from (q, k, lse) next to the MXU — dq in a kernel gridded over
 q-blocks streaming K/V, dk/dv in a kernel gridded over k-blocks streaming
 Q/dO. Like the forward, the causal variants skip fully-masked blocks
-rather than masking them. In training, backward is ~2/3 of attention
-FLOPs, so keeping it on the kernel path matters as much as the forward.
-
-On non-TPU backends the kernels run in Pallas interpret mode (tests), or
-callers can just use blockwise_attention.
+rather than masking them, and so do all three under a block-diffusion
+mask (ops/attention.py BlockDiffusionMask), whose live tiles they walk.
+In training, backward is ~2/3 of attention FLOPs. On non-TPU backends
+the kernels run in Pallas interpret mode (tests), or callers can just
+use blockwise_attention.
 """
 
 from __future__ import annotations
@@ -42,23 +42,22 @@ def _apply_causal_mask(s, q_off, k_off, block_q, block_k):
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, causal, scale, seq_len):
+    """o and lse for one (batch*head, q-block) tile, streaming K/V blocks.
+    ``causal``, here and in the backward kernels, is the mask: True for the
+    causal rule, False for none, or a ``BlockDiffusionMask``."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)  # q-block index within the sequence
     q = q_ref[0].astype(jnp.float32) * scale  # (block_q, D)
 
-    n_k_blocks = seq_len // block_k
-    if causal:
-        # K blocks strictly above the diagonal contribute nothing — skip
-        # them (fori_loop upper bound), don't just mask them.
-        q_end = (qi + 1) * block_q
-        n_k = jax.lax.div(q_end + block_k - 1, block_k)
-        n_k = jnp.minimum(n_k, n_k_blocks)
-    else:
-        n_k = n_k_blocks
+    # K blocks that hold no live score (strictly above the causal diagonal,
+    # or dead under the mask) contribute nothing — skip them (the loop's
+    # trip count), don't just mask them.
+    n_k, k_tile = _k_tiles(causal, qi, block_q, block_k, seq_len)
 
-    def body(kb, carry):
+    def body(j, carry):
         acc, m, l = carry
+        kb = k_tile(j)
         k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -66,8 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, causal, sc
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
-        if causal:
-            s = _apply_causal_mask(s, qi * block_q, kb * block_k, block_q, block_k)
+        s = _apply_mask(causal, s, qi * block_q, kb * block_k, block_q, block_k)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -108,14 +106,10 @@ def _bwd_dq_kernel(
     lse = lse_ref[0]  # (block_q, 1)
     delta = delta_ref[0]  # (block_q, 1)
 
-    n_k_blocks = seq_len // block_k
-    if causal:
-        q_end = (qi + 1) * block_q
-        n_k = jnp.minimum(jax.lax.div(q_end + block_k - 1, block_k), n_k_blocks)
-    else:
-        n_k = n_k_blocks
+    n_k, k_tile = _k_tiles(causal, qi, block_q, block_k, seq_len)
 
-    def body(kb, dq):
+    def body(j, dq):
+        kb = k_tile(j)
         k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         s = scale * jax.lax.dot_general(
@@ -123,8 +117,7 @@ def _bwd_dq_kernel(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
-        if causal:
-            s = _apply_causal_mask(s, qi * block_q, kb * block_k, block_q, block_k)
+        s = _apply_mask(causal, s, qi * block_q, kb * block_k, block_q, block_k)
         p = jnp.exp(s - lse)  # masked entries underflow to 0
         dp = jax.lax.dot_general(
             g, v_blk,
@@ -150,7 +143,8 @@ def _bwd_dkv_kernel(
     """dk, dv for one (batch*head, k-block) tile, streaming Q/dO blocks.
 
     dv = sum_blocks p^T @ dO; dk = scale * sum_blocks ds^T @ Q. Causal
-    programs start at the first q-block that can see this k-block.
+    programs start at the first q-block that can see this k-block; under a
+    mask the loop walks the q-blocks that can.
     """
     from jax.experimental import pallas as pl
 
@@ -158,11 +152,11 @@ def _bwd_dkv_kernel(
     k = k_ref[0].astype(jnp.float32)  # (block_k, D)
     v = v_ref[0].astype(jnp.float32)  # (block_k, D)
 
-    n_q_blocks = seq_len // block_q
-    qb_start = jax.lax.div(ki * block_k, block_q) if causal else 0
+    first, last, q_tile = _q_tiles(causal, ki, block_q, block_k, seq_len)
 
-    def body(qb, carry):
+    def body(j, carry):
         dk, dv = carry
+        qb = q_tile(j)
         q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
         g_blk = g_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
         lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]  # (block_q, 1)
@@ -172,8 +166,7 @@ def _bwd_dkv_kernel(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
-        if causal:
-            s = _apply_causal_mask(s, qb * block_q, ki * block_k, block_q, block_k)
+        s = _apply_mask(causal, s, qb * block_q, ki * block_k, block_q, block_k)
         p = jnp.exp(s - lse)
         dv_new = dv + jax.lax.dot_general(
             p, g_blk,
@@ -195,9 +188,16 @@ def _bwd_dkv_kernel(
 
     dk = jnp.zeros((block_k, k.shape[1]), jnp.float32)
     dv = jnp.zeros((block_k, k.shape[1]), jnp.float32)
-    dk, dv = jax.lax.fori_loop(qb_start, n_q_blocks, body, (dk, dv))
+    dk, dv = jax.lax.fori_loop(first, last, body, (dk, dv))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+# Everything above and down to ``flash_attention`` keeps its lines: the Mosaic
+# modules' location tables hold the line of ``_apply_causal_mask``'s ``where``
+# (under shard_map), of the three ``pallas_call``s and of ``flash_attention``'s
+# call of the kernel, so a line more or fewer ahead of any of them changes the
+# lowered train step, and with it the compile-cache key, of every model. What
+# the block-diffusion mask adds (``_apply_mask``, the tile walks) is at the end.
 
 
 def _shape(shape, dtype, like):
@@ -250,7 +250,7 @@ def _make_flash_parts(causal, scale, block_q, block_k, interpret):
                 pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             ),
-            interpret=interpret, **_vmem_room(S, D, q.dtype),
+            interpret=interpret, **_call_opts(causal, "fwd", S, D, q.dtype),
         )(q, k, v)
 
     def bwd_impl(q, k, v, g, lse, delta):
@@ -270,7 +270,7 @@ def _make_flash_parts(causal, scale, block_q, block_k, interpret):
                 pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # delta
             ],
             out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            interpret=interpret, **_vmem_room(S, D, q.dtype),
+            interpret=interpret, **_call_opts(causal, "dq", S, D, q.dtype),
         )(q, k, v, g, lse, delta)
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, **kern_opts(D, S)),
@@ -291,7 +291,7 @@ def _make_flash_parts(causal, scale, block_q, block_k, interpret):
                 pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
             ),
-            interpret=interpret, **_vmem_room(S, D, q.dtype),
+            interpret=interpret, **_call_opts(causal, "dkv", S, D, q.dtype),
         )(q, k, v, g, lse, delta)
         return dq, dk, dv
 
@@ -335,13 +335,16 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    mask=None,
 ) -> jax.Array:
     """Flash attention on ``(B, S, H, D)`` via a Pallas TPU kernel.
 
     ``block_q``/``block_k`` default to the largest divisor of S up to 512;
     explicitly passed blocks must divide S (callers pad or pick divisors;
     static shapes keep the kernel MXU-tiled). ``interpret=None``
-    auto-enables interpret mode off-TPU so tests run on CPU.
+    auto-enables interpret mode off-TPU so tests run on CPU. A ``mask``
+    (``attention.BlockDiffusionMask``) takes the causal rule's place: one
+    tile for both blocks, and only tiles with a live score are visited.
 
     Bigger tiles amortize the grid and keep the MXU fed; at D=128 a
     512-block program uses well under VMEM (q/acc tiles 256 KB, score
@@ -351,25 +354,22 @@ def flash_attention(
     from .attention import pick_block_size
 
     B, S, H, D = q.shape
-    if block_q is None:
-        block_q = pick_block_size(S, 512) or min(512, S)
-    if block_k is None:
-        block_k = pick_block_size(S, 512) or min(512, S)
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
+    if mask is not None:
+        block_q = block_k = _mask_tile(mask, S, block_q, block_k)
+    default = pick_block_size(S, 512) or min(512, S)
+    block_q, block_k = min(block_q or default, S), min(block_k or default, S)
     if S % block_q or S % block_k:
-        raise ValueError(
-            f"seq len {S} must be divisible by block_q={block_q} and "
-            f"block_k={block_k}"
-        )
+        raise ValueError(f"seq len {S} must be divisible by block_q={block_q} and block_k={block_k}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    flash = _make_flash(causal, scale, block_q, block_k, interpret)
+    flash = _make_flash(causal if mask is None else mask, scale, block_q, block_k, interpret)
     # (B, S, H, D) -> (B*H, S, D)
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, S, D)
     vt = v.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+    # This call's line is in the Mosaic modules' location tables (the note
+    # ahead of ``_shape``).
     out = flash(qt, kt, vt)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
@@ -383,7 +383,7 @@ def flash_attention_sharded(
     causal: bool = True,
     scale: Optional[float] = None,
     batch_axis: Optional[str] = "data",
-    head_axis: Optional[str] = "model",
+    head_axis: Optional[str] = "model", mask=None,
 ) -> jax.Array:
     """Flash attention under a ('data','model') mesh via ``shard_map``.
 
@@ -413,7 +413,7 @@ def flash_attention_sharded(
     spec = P(b, None, h, None)
 
     def fn(q, k, v):
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale, mask=mask)
 
     # Interpret mode (off-TPU testing) trips shard_map's varying-axes
     # checker with a jax-internal false positive (see ulysses.py); the
@@ -443,3 +443,58 @@ def _vmem_room(S: int, D: int, dtype) -> dict:
     from jax.experimental.pallas import tpu as pltpu
 
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=min(2 * need, _VMEM_MOST))}
+
+
+def _call_opts(mask, kernel: str, S: int, D: int, dtype) -> dict:
+    """What a ``pallas_call`` of these kernels takes beside its specs: the
+    VMEM room, and under a block-diffusion mask a name (``attn_bd_fwd``,
+    ``attn_bd_dq``, ``attn_bd_dkv``) by which a trace can find the kernel.
+    The causal and unmasked calls keep the name they always had."""
+    named = {} if isinstance(mask, bool) else {"name": f"attn_bd_{kernel}"}
+    return {**named, **_vmem_room(S, D, dtype)}
+
+
+def _apply_mask(mask, s, q_off, k_off, block_q, block_k):
+    """Scores the mask rules out set to NEG_INF: the causal rule, none, or a
+    ``BlockDiffusionMask``'s, for the forward and both backward kernels."""
+    if isinstance(mask, bool):
+        return _apply_causal_mask(s, q_off, k_off, block_q, block_k) if mask else s
+    # One column of query positions against one row of key positions.
+    q_pos = q_off + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    k_pos = k_off + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return jnp.where(mask.allowed(q_pos, k_pos), s, NEG_INF)
+
+
+def _mask_tile(mask, S: int, block_q: Optional[int], block_k: Optional[int]) -> int:
+    """The one tile the kernels use under ``mask``: what the caller named
+    for both blocks, else the mask's own 512-target pick."""
+    tile = block_q or block_k or mask.tile(512)
+    if S != 2 * mask.half or not tile or (block_k or tile) != tile or mask.half % tile or tile % mask.block:
+        raise ValueError(
+            f"{mask} over seq len {S}: needs S = 2 * half and one tile of "
+            f"whole blocks that divides half, got {block_q} x {block_k}"
+        )
+    return tile
+
+
+def _k_tiles(mask, qi, block_q: int, block_k: int, seq_len: int):
+    """The K tiles the forward and dq kernels visit for q tile ``qi``:
+    ``(count, j -> tile index)``."""
+    n_k_blocks = seq_len // block_k
+    if mask is False:
+        return n_k_blocks, lambda j: j
+    if mask is True:
+        q_end = (qi + 1) * block_q
+        n_k = jax.lax.div(q_end + block_k - 1, block_k)
+        return jnp.minimum(n_k, n_k_blocks), lambda j: j
+    return mask.k_tiles(qi, block_q)
+
+
+def _q_tiles(mask, ki, block_q: int, block_k: int, seq_len: int):
+    """The Q tiles the dk/dv kernel visits for k tile ``ki``: the loop's
+    ``(first, last, j -> tile index)``."""
+    n_q_blocks = seq_len // block_q
+    if isinstance(mask, bool):
+        return (jax.lax.div(ki * block_k, block_q) if mask else 0), n_q_blocks, lambda j: j
+    count, tile = mask.q_tiles(ki, block_k)
+    return 0, count, tile

@@ -33,6 +33,7 @@ _SUMMED_COUNTERS = (
     "retry_backoff_s",
     "budget_defers",
     "dtoh_window_waits",
+    "chunk_payloads",
     # Degradation counters (PR 4/6 machinery): a fleet that failed over
     # mid-take must SAY so in the persisted summary — these existed on
     # the bus but vanished post-hoc until the observability PR.
