@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib.util
+import math
 import os
 
 import jax
@@ -262,6 +263,11 @@ def test_routing_stats_count_what_the_routers_chose():
     np.testing.assert_array_equal(stats["held_counts"], counts)
     np.testing.assert_allclose(stats["held_share"], counts.sum(1) / chosen[0].size, rtol=1e-6)
     np.testing.assert_allclose(stats["max_over_mean"], counts.max(1) / counts.mean(1), rtol=1e-6)
+    tile = math.gcd(B * 2 * S, M.expert_tile(CFG, B * 2 * S))
+    trips = np.sum(-(-counts // tile), axis=1)
+    np.testing.assert_array_equal(stats["trips"], trips)
+    np.testing.assert_allclose(stats["tile_fill"], counts.sum(1) / (trips * tile), rtol=1e-6)
+    assert (trips > 0).all()
     want = R.chosen_experts(params, batch["tokens"], masked=masked, **_ref_args(CFG))
     for got_layer, want_layer in zip(chosen, want):
         np.testing.assert_array_equal(np.sort(got_layer, -1), np.sort(np.asarray(want_layer).reshape(got_layer.shape), -1))

@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib.util
+import math
 import os
 
 import jax
@@ -409,6 +410,10 @@ def test_routing_stats_count_what_the_routers_chose():
         np.testing.assert_allclose(float(stats[name]["held_share"]), counts.sum() / ids.size, rtol=1e-6)
         np.testing.assert_allclose(float(stats[name]["max_over_mean"]), counts.max() / counts.mean(), rtol=1e-5)
         assert 0 < float(stats[name]["held_share"]) < 1
+        tile = math.gcd(B * S, 512)  # sigmoid_topk_routed's, as the layer runs it
+        trips = int(np.sum(-(-counts // tile)))
+        assert int(stats[name]["trips"]) == trips > 0
+        np.testing.assert_allclose(float(stats[name]["tile_fill"]), counts.sum() / (trips * tile), rtol=1e-6)
 
 
 # -------------------------------------------------------------- attention

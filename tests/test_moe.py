@@ -13,7 +13,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from torchsnapshot_tpu.ops import moe
 from torchsnapshot_tpu.ops.moe import init_moe_params, moe_ffn
+from torchsnapshot_tpu.ops.pallas_add_rows import add_rows
 
 
 def reference_moe_no_drops(params, x):
@@ -214,3 +216,72 @@ def test_dense_transformer_unchanged() -> None:
     assert "ff_in" in params["layers"] and "moe_router" not in params["layers"]
     logits = T.forward(params, jnp.zeros((2, 16), jnp.int32), cfg)
     assert logits.shape == (2, 16, 64)
+
+
+# ----------------------------------------- the held experts' row-accumulate kernel
+
+
+# A tile of 128 rows is two groups of the kernel's 64, one of 16 is a group
+# of 16: no row, one, part of a group, a group and part of the next, all.
+@pytest.mark.parametrize(
+    "D,tile,n_own",
+    [(128, 128, n) for n in (0, 1, 13, 64, 77, 128)] + [(256, 16, n) for n in (0, 1, 5, 16)] + [(96, 24, 7)],
+)
+def test_add_rows_is_xlas_scatter_add_of_the_own_rows(D, tile, n_own):
+    """The kernel in interpret mode, the code the chip compiles: exactly
+    ``acc.at[idx[:n_own], 0].add(rows[:n_own])``, the rows past ``n_own``
+    untouched, and again on its own result inside a ``fori_loop`` with the
+    tile's rows shifted by one, so that the second call adds to rows the
+    first has written (the loops' use of it)."""
+    T = 4 * tile
+    k_acc, k_idx, k_rows = jax.random.split(jax.random.PRNGKey(0), 3)
+    acc = jax.random.normal(k_acc, (T, 1, D), jnp.float32)
+    idx = jax.random.permutation(k_idx, T)[:tile].astype(jnp.int32)
+    idxs, rows = jnp.stack([idx, jnp.roll(idx, 1)]), jax.random.normal(k_rows, (2, tile, D), jnp.float32)
+    n = jnp.int32(n_own)
+    want = acc.at[idxs[0, :n_own], 0].add(rows[0, :n_own])
+    np.testing.assert_array_equal(jax.jit(add_rows)(acc, idxs[0], rows[0], n), want)
+    twice = jax.jit(lambda a: jax.lax.fori_loop(0, 2, lambda i, a: add_rows(a, idxs[i], rows[i], n), a))(acc)
+    np.testing.assert_array_equal(twice, want.at[idxs[1, :n_own], 0].add(rows[1, :n_own]))
+
+
+def _xla_scatter(acc, idx, rows, n_own):
+    """How the parent added a tile: XLA's scatter-add of every row of it,
+    those past ``n_own`` at weight 0."""
+    del n_own
+    return acc.at[idx, 0].add(rows, unique_indices=True)
+
+
+@pytest.mark.parametrize("n_matrices", [2, 3])
+def test_held_experts_with_the_kernel_equal_the_parents_scatter(monkeypatch, n_matrices):
+    """``_held_experts``, value and every gradient, against the same loops
+    with XLA's scatter in the kernel's place: equal, not close (the same
+    float32 additions in the same order of trips). An expert of no rows,
+    one of exactly a tile, one of a tile and a row, one of part of a tile.
+    The routing weights are powers of two, so a row's product with its
+    weight is exact: the CPU's compiler contracts that product and the
+    interpreted kernel's addition into one fused multiply-add, which rounds
+    once where XLA's scatter, and the chip either way, round twice."""
+    T, D, F, tile = 64, 32, 24, 16
+    counts = jnp.asarray([0, tile, tile + 1, 5], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    x = jax.random.normal(keys[0], (T, D))
+    ws = tuple(jax.random.normal(k, (4,) + s) * s[0] ** -0.5 for k, s in zip(keys[1:], [(D, F)] * (n_matrices - 1) + [(F, D)]))
+    order = jnp.stack([jax.random.permutation(k, T) for k in jax.random.split(keys[4], 4)]).astype(jnp.int32)
+    member = jnp.zeros((4, T), bool).at[jnp.arange(4)[:, None], order].set(jnp.arange(T)[None] < counts[:, None])
+    w_held = jnp.where(member, 2.0 ** jax.random.randint(keys[5], (4, T), -2, 2), 0.0)
+    g = jax.random.normal(jax.random.PRNGKey(4), (T, D))
+
+    def value_and_grads():
+        # a fresh trace each time: add_rows is looked up when the loops are traced
+        f = lambda x, w, ws: moe._held_experts(x, w, order, counts, ws, tile)  # noqa: E731
+        return jax.jit(lambda x, w, ws: (f(x, w, ws), jax.grad(lambda *a: jnp.sum(f(*a) * g), (0, 1, 2))(x, w, ws)))(x, w_held, ws)
+
+    got = value_and_grads()
+    monkeypatch.setattr(moe, "add_rows", _xla_scatter)
+    want = value_and_grads()
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    value, (dx, dw, dws) = got
+    assert float(jnp.abs(value).max()) > 0 and float(jnp.abs(dx).max()) > 0
+    assert all(float(jnp.abs(d[0]).max()) == 0.0 and float(jnp.abs(d[2]).max()) > 0 for d in dws)  # nobody chose expert 0

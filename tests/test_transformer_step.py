@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import re
 import shutil
 
 import jax
@@ -23,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchsnapshot_tpu import Snapshot, StateDict
 from torchsnapshot_tpu.models import ssm_lm, transformer as T
+from torchsnapshot_tpu.ops.moe import _held_experts
 from torchsnapshot_tpu.parallel import make_mesh
 from torchsnapshot_tpu.parallel.mesh import collective_bytes, collectives, spanned_axes, with_replica_dim
 
@@ -465,6 +467,58 @@ def test_the_resume_layout_compiles_to_what_it_did_on_the_tpu_partitioner(v5e_2x
     text = _compiled_step_text(T, FULL, mesh, batch=(4, 2048))
     assert round(sum(collective_bytes(text).values()) / MB, 1) == 4161.0
     assert _data_reductions(text, mesh) == []
+
+
+def _in_loop_bodies(text):
+    """The instruction lines of every computation a ``while`` body reaches."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and not line.startswith("}"):
+            cur.append(line)
+    callees = lambda lines, roles: {c for line in lines for c in re.findall(rf"\b(?:{roles})=%([^\s,)}}]+)", line)}  # noqa: E731
+    todo, seen = callees([l for ls in comps.values() for l in ls], "body"), set()
+    while todo:
+        comp = todo.pop()
+        seen.add(comp)
+        todo |= callees(comps[comp], "body|calls|to_apply|true_computation|false_computation") - seen
+    return [line for comp in sorted(seen) for line in comps[comp]]
+
+
+def test_no_loop_of_the_held_experts_moves_the_whole_accumulator_on_the_tpu_compiler(v5e_2x2, monkeypatch):
+    """``_held_experts`` forward and backward at ``sdar30b.save``'s sizes
+    (8192 positions of width 2048, 16 experts of width 768, tiles of 1024
+    rows), compiled for the chip: a tile is added to the float32
+    accumulator by the row kernel, once forward and once backward, and no
+    loop body copies, slices or scatters into the accumulator itself. With
+    XLA's scatter-add in the kernel's place (the parent's ``ops/moe.py``)
+    the loop bodies hold ``copy-start`` x 3 and ``slice-start`` x 4 of it
+    around two scatter fusions: the whole 64 MB through VMEM every trip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
+    T_, D_, F_, E_, tile = 8192, 2048, 768, 16, 1024
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
+    ws = (arg((E_, D_, F_), jnp.bfloat16),) * 2 + (arg((E_, F_, D_), jnp.bfloat16),)
+
+    def both(x2, w_held, order, counts, ws, g):
+        y, vjp = jax.vjp(lambda x, w, ws: _held_experts(x, w, order, counts, ws, tile), x2, w_held, ws)
+        return y, vjp(g)
+
+    text = jax.jit(both).lower(
+        arg((T_, D_), jnp.bfloat16), arg((E_, T_), jnp.float32), arg((E_, T_), jnp.int32), arg((E_,), jnp.int32), ws,
+        arg((T_, D_), jnp.float32),
+    ).compile().as_text()
+    accumulator, in_loops = re.compile(rf"f32\[{T_},(?:1,)?{D_}\]"), _in_loop_bodies(text)
+    moved = [
+        line.strip()[:160] for line in in_loops
+        if accumulator.search(line) and re.search(r" (copy-start|slice-start|scatter)\(", line)
+    ]
+    assert moved == []
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # the loops were read: they carry the accumulator, row by row
+    assert any(re.search(rf"f32\[{T_},1,{D_}\]\S* custom-call\(", line) for line in in_loops)
 
 
 CP = {"data": 2, "seq": 2, "model": 2}

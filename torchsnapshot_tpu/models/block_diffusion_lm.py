@@ -69,7 +69,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..ops.attention import BlockDiffusionMask, causal_attention_route
-from ..ops.moe import softmax_topk_routed
+from ..ops.moe import held_tile_stats, softmax_topk_routed
 from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
 
 Params = Dict[str, Any]
@@ -353,7 +353,9 @@ def routing_stats(params: Params, tokens: jax.Array, masked: jax.Array, cfg: Blo
     fall on experts held here (``len(held) / n_experts`` under even
     routing), ``max_over_mean``, the most positions a held expert gets
     over their mean (the expert loops' longest trip over the average one),
-    and ``held_counts`` ``(L, n)``, the positions each held expert gets."""
+    ``held_counts`` ``(L, n)``, the positions each held expert gets, and
+    the loops' ``trips`` and ``tile_fill`` (``ops/moe.py``
+    ``held_tile_stats``)."""
     chosen = chosen_experts(params, tokens, masked, cfg)
     held = jnp.asarray(cfg.held, jnp.int32)
     counts = jnp.sum(chosen[:, None] == held[None, :, None, None], axis=(2, 3))  # (L, n)
@@ -361,6 +363,7 @@ def routing_stats(params: Params, tokens: jax.Array, masked: jax.Array, cfg: Blo
         "held_counts": counts,
         "held_share": jnp.sum(counts, axis=1) / (chosen.shape[1] * chosen.shape[2]),
         "max_over_mean": jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts.astype(jnp.float32), axis=1), 1e-9),
+        **held_tile_stats(counts, chosen.shape[1], expert_tile(cfg, chosen.shape[1])),
     }
 
 

@@ -73,7 +73,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..ops.attention import causal_attention_route
-from ..ops.moe import relu2_ffn, sigmoid_topk_routed
+from ..ops.moe import held_tile_stats, relu2_ffn, sigmoid_topk_routed
 from ..ops.ssm import mamba2_chunked
 from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
 
@@ -396,10 +396,11 @@ def chosen_experts(params: Params, tokens: jax.Array, cfg: HybridLMConfig) -> Di
 def routing_stats(params: Params, tokens: jax.Array, cfg: HybridLMConfig) -> Dict[str, Dict[str, jax.Array]]:
     """What the routers did with this batch, per ``E`` layer: ``held_share``,
     the share of the ``top_k * T`` assignments that fall on experts held
-    here (``len(held) / n_experts`` under even routing), and
+    here (``len(held) / n_experts`` under even routing),
     ``max_over_mean``, the most tokens a held expert gets over their mean
     (1 when they are level; the expert loops' longest trip over the
-    average one)."""
+    average one), and the loops' ``trips`` and ``tile_fill``
+    (``ops/moe.py`` ``held_tile_stats``)."""
     held = jnp.asarray(cfg.held, jnp.int32)
     out = {}
     for name, ids in chosen_experts(params, tokens, cfg).items():
@@ -407,6 +408,7 @@ def routing_stats(params: Params, tokens: jax.Array, cfg: HybridLMConfig) -> Dic
         out[name] = {
             "held_share": jnp.sum(counts) / ids.size,
             "max_over_mean": jnp.max(counts) / jnp.maximum(jnp.mean(counts.astype(jnp.float32)), 1e-9),
+            **held_tile_stats(counts, ids.shape[0]),
         }
     return out
 

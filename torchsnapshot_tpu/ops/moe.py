@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .pallas_add_rows import add_rows
+
 # Above this many elements in the (T, E, cap) dispatch tensor, "auto"
 # switches to the sort-based dispatch (2**22 f32 elements = 16 MB).
 _EINSUM_DISPATCH_MAX_ELEMENTS = 1 << 22
@@ -395,16 +397,27 @@ def _held_experts(x2, w_held, order, counts, ws, tile):
     them), and ``w_held[e]`` is 0 on everyone else. Each expert walks its
     list in tiles of ``tile`` tokens and stops after the last tile that
     holds one of its own (that tile's tail is computed at weight 0): rows
-    are gathered, multiplied and scatter-added a tile at a time, so a step
-    costs what its routing sends here and nothing of size ``E x T`` is ever
-    held. The trip counts are data, which
-    reverse-mode autodiff cannot transpose, so the backward pass is written
-    out below with the same loops."""
+    are gathered and multiplied a tile at a time, so a step costs what its
+    routing sends here and nothing of size ``E x T`` is ever held. The trip
+    counts are data, which reverse-mode autodiff cannot transpose, so the
+    backward pass is written out below with the same loops.
+
+    A tile's own rows are added into the float32 accumulator (``y`` here,
+    ``dx`` backward) where it lies, by ``add_rows``
+    (``pallas_add_rows.py``): the loops carry it as ``(T, 1, D)``, the rows
+    past the expert's last are not touched, and one relayout to ``(T, D)``
+    follows the scan. XLA's scatter-add in that place copied the whole
+    accumulator into VMEM and back every trip (PERF.md, PR 35).
+    ``held_tile_stats`` counts the trips and how full their tiles are."""
     return _held_experts_fwd(x2, w_held, order, counts, ws, tile)[0]
 
 
+def _row_accumulator(x2):
+    """Zeros for ``add_rows`` to add into, row by row: ``(T, 1, D)`` float32."""
+    return jnp.zeros((x2.shape[0], 1, x2.shape[1]), jnp.float32)
+
+
 def _held_experts_fwd(x2, w_held, order, counts, ws, tile):
-    f32 = jnp.float32
     ffn = _EXPERTS[len(ws)][0]
 
     def one(y, args):
@@ -413,12 +426,12 @@ def _held_experts_fwd(x2, w_held, order, counts, ws, tile):
         def body(j, y):
             idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
             o = ffn(x2[idx], *w)
-            return y.at[idx].add(w_e[idx][:, None] * o, unique_indices=True)
+            return add_rows(y, idx, w_e[idx][:, None] * o, jnp.minimum(n - j * tile, tile))
 
         return jax.lax.fori_loop(0, (n + tile - 1) // tile, body, y), None
 
-    y, _ = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, ws))
-    return y, (x2, w_held, order, counts, ws)
+    y, _ = jax.lax.scan(one, _row_accumulator(x2), (w_held, order, counts, ws))
+    return y.reshape(x2.shape), (x2, w_held, order, counts, ws)
 
 
 def _held_experts_bwd(tile, res, g):
@@ -452,15 +465,15 @@ def _held_experts_bwd(tile, res, g):
                 operator.add,
                 [jax.lax.dot_general(dh, m, contract1, preferred_element_type=f32) for dh, m in zip(dhs, ins)],
             )
-            return dx.at[idx].add(drows, unique_indices=True), dw, dins, ddown
+            return add_rows(dx, idx, drows, jnp.minimum(n - j * tile, tile)), dw, dins, ddown
 
         init = (dx, jnp.zeros((T,), f32), tuple(jnp.zeros(m.shape, f32) for m in ins), jnp.zeros(down.shape, f32))
         dx, dw, dins, ddown = jax.lax.fori_loop(0, (n + tile - 1) // tile, body, init)
         return dx, (dw, tuple(d.astype(m.dtype) for d, m in zip(dins, ins)) + (ddown.astype(down.dtype),))
 
-    dx, (dw, dws) = jax.lax.scan(one, jnp.zeros(x2.shape, f32), (w_held, order, counts, ws))
+    dx, (dw, dws) = jax.lax.scan(one, _row_accumulator(x2), (w_held, order, counts, ws))
     none = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
-    return dx.astype(x2.dtype), dw, none(order), none(counts), dws
+    return dx.reshape(x2.shape).astype(x2.dtype), dw, none(order), none(counts), dws
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -491,6 +504,20 @@ def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Ar
     return y.reshape(orig_shape), ids
 
 
+_TILE = 512  # rows a held expert multiplies a trip: why, under sigmoid_topk_routed
+
+
+def held_tile_stats(held_counts: jax.Array, T: int, tile: int = _TILE) -> Dict[str, jax.Array]:
+    """What ``_held_experts``' loops make of ``held_counts`` ``(..., n)``,
+    each held expert's own rows among ``T``, at the ``tile`` its layer was
+    given: ``trips``, the tiles walked in a pass over the layer, and
+    ``tile_fill``, own rows over the rows of those tiles: the share of a
+    tile's rows that ``add_rows`` adds and whose products are not padding."""
+    tile = math.gcd(T, tile)
+    trips = jnp.sum((held_counts + tile - 1) // tile, axis=-1)
+    return {"trips": trips, "tile_fill": jnp.sum(held_counts, axis=-1) / jnp.maximum(trips * tile, 1)}
+
+
 def sigmoid_topk_routed(
     params: Dict[str, Any],
     x: jax.Array,
@@ -498,7 +525,7 @@ def sigmoid_topk_routed(
     top_k: int,
     held: Tuple[int, ...],
     routed_scale: float,
-    tile: int = 512,
+    tile: int = _TILE,
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed part of a sigmoid top-k expert layer, for the experts held
     here: ``x: (..., D)`` -> (float32 of the same shape, the chosen ids
@@ -519,10 +546,16 @@ def sigmoid_topk_routed(
     one that everybody chooses costs ``min(top_k, n) * T`` rows. ``tile``
     rows are multiplied at a time, the last tile of an expert part-filled:
     512 because the MXU's time for the padding is cheap beside what every
-    tile pays whatever its size (two float32 weight-gradient accumulators
-    read and written, the gathers' and scatters' set-up), and because a
-    step then costs the same for any load up to 512 tokens an expert
-    (256 and 1024 were measured on the chip: PERF.md, PR 32).
+    tile pays whatever its size, and because a step then costs the same
+    for any load up to 512 tokens an expert (256 and 1024 were measured on
+    the chip: PERF.md, PR 32). What a tile pays whatever its size, since
+    PR 35: the gathers of its rows, the slices of the expert's matrices,
+    two or three float32 weight-gradient accumulators zero-filled, read
+    and written, and ``add_rows``' pass over the tile's products (11 us
+    for 8 MB); what it pays by its own rows: two 8 KB DMAs each, 30 ns a
+    row. The ``(T, D)`` accumulator itself is no longer moved: XLA's
+    scatter-add copied all of it into VMEM and out again every trip, or
+    ran in HBM at 310 ns a row (PERF.md, PR 35).
     """
     return _routed_to_held(
         x,
@@ -532,14 +565,15 @@ def sigmoid_topk_routed(
 
 
 def softmax_topk_routed(
-    params: Dict[str, Any], x: jax.Array, *, top_k: int, held: Tuple[int, ...], tile: int = 512
+    params: Dict[str, Any], x: jax.Array, *, top_k: int, held: Tuple[int, ...], tile: int = _TILE
 ) -> Tuple[jax.Array, jax.Array]:
     """The softmax sibling, with gated experts: ``router (D, E)`` over all
     E, ``expert_gate`` and ``expert_up (n, D, F)``, ``expert_down (n, F,
     D)`` of the held ones. Scores are ``softmax`` over all E, the weights
     the ``top_k`` chosen probabilities over their sum, the result ``sum of
     w_i (silu(x G_i) * (x U_i)) D_i`` over the chosen experts held here;
-    dropless and tiled as ``sigmoid_topk_routed`` says."""
+    dropless and tiled as ``sigmoid_topk_routed`` says, and a tile pays
+    what it says there, with three weight-gradient accumulators for two."""
     return _routed_to_held(
         x,
         lambda x2: softmax_topk_route(x2, params["router"], top_k),
