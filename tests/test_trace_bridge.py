@@ -6,29 +6,44 @@ with ``jax.profiler`` shows the save and restore pipeline beside the
 device on the profiler's own clock. With telemetry off nothing is written
 and nothing is allocated. The staging lump is split where the work
 happens: ``stage_dtoh`` and ``stage_crc`` inside ``stage_hash``, and the
-device-side assembly of a streamed restore is ``consume_assemble``.
+device-side assembly of a streamed restore is ``consume_assemble``. A
+restore's host seconds have names of their own: ``consume_verify``,
+``consume_hostcopy``, ``consume_place``, ``consume_queue`` and
+``stream_read_wait``, siblings inside ``stream_read`` (a streamed entry) or
+``consume`` (a buffered one).
 
 One small take + restore of jax arrays runs under an open profiler trace
 once per mode (module fixtures); the cases read what it left behind.
 """
 
 import asyncio
+import functools
 import glob
 import os
 import subprocess
 import sys
+import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
 from torchsnapshot_tpu import Snapshot, StateDict, telemetry
+from torchsnapshot_tpu.io_preparers.array import _executor_submit
 from torchsnapshot_tpu.telemetry import core
 
 PREFIX = "tsnap:"
 SUB_CHUNK = 128 << 10
+# What this PR names of a restore, and the two older spans that stay their
+# siblings: each lies inside one OUTER span, none inside another.
+RESTORE_SPANS = ["consume_verify", "consume_hostcopy", "consume_place", "consume_queue", "stream_read_wait"]
+INNER = set(RESTORE_SPANS) | {"sub_chunk_htod", "consume_assemble"}
+OUTER = ("stream_read", "consume")
 
 
 def _trace(tmp, body):
@@ -126,7 +141,7 @@ def _traced_names(run):
 @pytest.mark.parametrize(
     "name",
     ["stage", "stage_hash", "stage_dtoh", "stage_crc", "storage_write", "stream_read", "consume_chunk",
-     "sub_chunk_htod", "consume_assemble"],
+     "sub_chunk_htod", "consume_assemble", *RESTORE_SPANS],
 )
 def test_span_lands_in_the_profilers_trace(run_on, name):
     assert PREFIX + name in _traced_names(run_on)
@@ -153,6 +168,28 @@ def test_telemetry_off_writes_nothing(run_off):
     assert run_off["lines"] == {}
     assert run_off["bus"] == {"take": [], "restore": []}
     assert telemetry.span("stage_dtoh", cat="stager", bytes=1) is core._NULL_SPAN
+
+
+@pytest.mark.parametrize("name", RESTORE_SPANS)
+def test_telemetry_off_allocates_nothing_for_a_restore_span(name):
+    assert telemetry.span(name, cat="consumer", path="0/app/w", bytes=1) is core._NULL_SPAN
+    assert telemetry.handoff_span(name, cat="consumer", path="0/app/w") is core._NULL_SPAN
+
+
+def test_telemetry_off_submits_to_the_executor_with_no_wrapper():
+    """Off, a consumer's ``submit`` is ``run_in_executor`` itself, bound
+    once a stream: nothing is allocated a chunk."""
+
+    async def body():
+        loop = asyncio.get_running_loop()
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            submit = _executor_submit(pool, "0/app/w")
+            assert isinstance(submit, functools.partial)
+            assert submit.func == loop.run_in_executor and submit.args == (pool,) and not submit.keywords
+            assert await submit(threading.get_ident) != threading.get_ident()
+
+    asyncio.run(body())
+    assert telemetry.events() == []
 
 
 # ------------------------------------------------------------------- the bus
@@ -183,6 +220,200 @@ def test_consume_assemble_counts_the_streamed_leafs_blocks(run_on):
     htod = [e for e in restore if e["name"] == "sub_chunk_htod"]
     assert assembled[0]["args"]["blocks"] == len(htod) > 1
     assert all(e["ts"] + e["dur"] <= assembled[0]["ts"] + 1e-9 for e in htod)
+
+
+# ------------------------------------------- a restore's seconds, by name
+
+
+@pytest.mark.parametrize("name", RESTORE_SPANS)
+def test_restore_span_is_on_the_bus_with_the_path_of_its_read(run_on, name):
+    restore = run_on["bus"]["restore"]
+    reads = {e["args"]["path"] for e in restore if e["name"] in OUTER}
+    found = [e for e in restore if e["name"] == name]
+    assert found and all(e["args"]["path"] in reads for e in found)
+    moved = [e for e in found if "bytes" in e["args"]]
+    if name in ("consume_hostcopy", "consume_place"):
+        assert moved == found
+    assert all(e["args"]["bytes"] > 0 for e in moved)
+
+
+def _by_path(spans, names):
+    out = {}
+    for e in spans:
+        if e["name"] in names:
+            out.setdefault(e["args"]["path"], []).append(e)
+    return out
+
+
+def _assert_siblings_inside(outer, inner):
+    """Every inner span inside the one outer span, no two overlapping:
+    their durations then sum to no more than the outer's."""
+    lo, hi = outer["ts"], outer["ts"] + outer["dur"]
+    inner = sorted(inner, key=lambda e: e["ts"])
+    for e in inner:
+        assert lo - 1e-9 <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-9, (e["name"], outer["name"])
+    for a, b in zip(inner, inner[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-9, (a["name"], b["name"])
+    assert sum(e["dur"] for e in inner) <= outer["dur"] + 1e-9
+
+
+@pytest.mark.parametrize("outer, leaf, want", [
+    # the large leaf streams through the device row sink, the small one is read whole
+    ("stream_read", "w", {"stream_read_wait", "consume_queue", "consume_verify", "consume_hostcopy",
+                          "sub_chunk_htod", "consume_assemble"}),
+    ("consume", "b", {"consume_queue", "consume_verify", "consume_place"}),
+])
+def test_inner_spans_are_siblings_inside_the_outer_span_of_their_path(run_on, outer, leaf, want):
+    restore = run_on["bus"]["restore"]
+    (outer_span,) = [e for e in restore if e["name"] == outer and ("/" + leaf + "_") in e["args"]["path"]]
+    assert not [e for e in restore if e["name"] in OUTER and e is not outer_span
+                and e["args"]["path"] == outer_span["args"]["path"]]
+    inner = _by_path(restore, INNER)[outer_span["args"]["path"]]
+    assert {e["name"] for e in inner} == want
+    _assert_siblings_inside(outer_span, inner)
+
+
+def test_hostcopy_and_htod_are_siblings_under_consume_chunk(run_on):
+    restore = run_on["bus"]["restore"]
+    by_id = {e["id"]: e for e in restore}
+    htod = [e for e in restore if e["name"] == "sub_chunk_htod"]
+    assert len(htod) > 1
+    for e in htod:
+        chunk = by_id[e["parent"]]
+        assert chunk["name"] == "consume_chunk" and chunk["tid"] == e["tid"]
+        children = sorted((c for c in restore if c["parent"] == chunk["id"]), key=lambda c: c["ts"])
+        assert [c["name"] for c in children] == ["consume_verify", "consume_hostcopy", "sub_chunk_htod"]
+        for a, b in zip(children, children[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-9
+    # every byte of the leaf went through the carry, and the same bytes to the device
+    copied = [e for e in restore if e["name"] == "consume_hostcopy"]
+    assert sum(e["args"]["bytes"] for e in copied) == sum(e["args"]["bytes"] for e in htod) == 400 * 1000 * 4
+
+
+def test_consume_place_says_which_thread_placed_the_leaf(run_on):
+    (placed,) = [e for e in run_on["bus"]["restore"] if e["name"] == "consume_place"]
+    assert placed["args"]["thread"] == "worker" and placed["args"]["bytes"] == 64 * 64 * 4
+    assert placed["tid"] != threading.get_ident()
+
+
+def test_a_queue_span_handed_to_a_worker_leaves_every_parent_as_it_was():
+    """``consume_queue`` opens on the submitting thread and closes on the
+    worker: it joins neither thread's nesting stack."""
+    telemetry.set_enabled(True)
+    seen = {}
+
+    def work(waited):
+        with telemetry.span("worker_before"):
+            pass
+        waited.__exit__(None, None, None)
+        seen["closed_on"] = threading.get_ident()
+        with telemetry.span("worker_after"):
+            pass
+
+    with ThreadPoolExecutor(max_workers=1) as pool, telemetry.span("outer"):
+        waited = telemetry.handoff_span("consume_queue", cat="consumer", path="0/app/w")
+        assert type(waited) is telemetry.Span
+        with telemetry.span("submitter_while_open"):
+            pass
+        time.sleep(0.02)
+        pool.submit(work, waited).result(timeout=30)
+        with telemetry.span("submitter_after"):
+            pass
+    ev = {e["name"]: e for e in _spans(telemetry.events())}
+    outer = ev["outer"]["id"]
+    assert ev["submitter_while_open"]["parent"] == outer and ev["submitter_after"]["parent"] == outer
+    assert ev["worker_before"]["parent"] is None and ev["worker_after"]["parent"] is None
+    queue = ev["consume_queue"]
+    assert queue["parent"] == outer and queue["args"] == {"path": "0/app/w"} and queue["cat"] == "consumer"
+    assert queue["tid"] == threading.get_ident() != seen["closed_on"]
+    assert queue["dur"] >= 0.02 and queue["ts"] + queue["dur"] <= ev["worker_after"]["ts"]
+
+
+def test_a_queue_span_ends_where_the_work_starts_and_another_brings_the_result_back():
+    """Through the consumers' ``submit``: one worker, two calls at once, so
+    the second waits for the first; its span covers that wait and no more.
+    The way back to the loop thread is a span of its own."""
+    telemetry.set_enabled(True)
+
+    def work(name):
+        with telemetry.span(name):
+            time.sleep(0.05)
+        return name
+
+    async def body():
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            submit = _executor_submit(pool, "0/app/w")
+            assert await asyncio.gather(submit(work, "first"), submit(work, "second")) == ["first", "second"]
+            with telemetry.span("loop_after"):
+                pass
+
+    asyncio.run(body())
+    ev = _spans(telemetry.events())
+    first, second, after = (next(e for e in ev if e["name"] == n) for n in ("first", "second", "loop_after"))
+    queued = sorted((e for e in ev if e["name"] == "consume_queue"), key=lambda e: e["ts"])
+    assert all(e["args"]["path"] == "0/app/w" for e in queued)
+    (q1, q2), back = [e for e in queued if e["args"]["thread"] == "worker"], [e for e in queued if e["args"]["thread"] == "loop"]
+    assert q1["ts"] + q1["dur"] <= first["ts"] and q2["ts"] + q2["dur"] <= second["ts"]
+    assert q2["dur"] >= 0.04 and q2["ts"] < first["ts"] + first["dur"] <= q2["ts"] + q2["dur"]
+    assert q1["tid"] == q2["tid"] == after["tid"] != first["tid"]
+    # opened on the worker when the work ends, closed on the loop thread before the caller goes on
+    for work_span, b in zip((first, second), back):
+        assert b["tid"] == work_span["tid"] and work_span["ts"] + work_span["dur"] <= b["ts"]
+        assert b["ts"] + b["dur"] <= after["ts"]
+    assert len(back) == 2 and first["parent"] is None and second["parent"] is None and after["parent"] is None
+
+
+def _sharded_restore(tmp, streamed):
+    """Two leaves sharded 4 x 2 over the CPU mesh, restored under 2 x 4:
+    every saved shard scatters into destination boxes. Returns the
+    restore's spans and the saved shards' sizes by location."""
+    devs = np.array(jax.devices()[:8])
+    save = NamedSharding(Mesh(devs.reshape(4, 2), ("x", "y")), P("x", "y"))
+    load = NamedSharding(Mesh(devs.reshape(2, 4), ("x", "y")), P("x", "y"))
+    data = {k: np.random.default_rng(i).standard_normal((512, 1024)).astype(np.float32) for i, k in enumerate("uv")}
+    state = {"app": StateDict(**{k: jax.device_put(v, save) for k, v in data.items()})}
+    dst = {"app": StateDict(**{k: jax.device_put(np.zeros_like(v), load) for k, v in data.items()})}
+    env = {"TORCHSNAPSHOT_TPU_SUB_CHUNK_BYTES": str(64 << 10), "TORCHSNAPSHOT_TPU_ENABLE_BATCHING": "0",
+           "TORCHSNAPSHOT_TPU_STREAM_READS": "always" if streamed else "never"}
+    with pytest.MonkeyPatch.context() as mp:
+        for k, v in env.items():
+            mp.setenv(k, v)
+        snap = Snapshot.take(str(tmp / "snap"), state)
+        telemetry.set_enabled(True)
+        snap.restore(dst)
+        spans = _spans(telemetry.events())
+    for k, v in data.items():
+        assert np.array_equal(np.asarray(dst["app"][k]), v)
+    return spans
+
+
+@pytest.mark.parametrize("streamed", [False, True], ids=["buffered", "streamed"])
+def test_a_sharded_restore_places_once_a_leaf_and_scatters_once_a_shard(tmp_path, streamed):
+    spans = _sharded_restore(tmp_path, streamed)
+    outer = {e["args"]["path"]: e for e in spans if e["name"] == ("stream_read" if streamed else "consume")}
+    assert len(outer) == 16 and not [e for e in spans if e["name"] == ("consume" if streamed else "stream_read")]
+    shard_bytes = 512 * 1024 * 4 // 8
+    inner = _by_path(spans, INNER)
+    assert set(inner) == set(outer)
+    placed = [e for e in spans if e["name"] == "consume_place"]
+    assert sorted(e["args"]["path"].rsplit("/", 1)[-1][0] for e in placed) == ["u", "v"]
+    assert all(e["args"]["thread"] == ("loop" if streamed else "worker") for e in placed)
+    assert all(e["args"]["bytes"] == 512 * 1024 * 4 for e in placed)  # the eight boxes of a leaf
+    for path, found in inner.items():
+        _assert_siblings_inside(outer[path], found)
+        scatters = [e for e in found if e["name"] == "consume_hostcopy" and e["args"]["bytes"] == shard_bytes]
+        chunks = [e for e in found if e["name"] == "consume_hostcopy" and e["args"]["bytes"] < shard_bytes]
+        assert len(scatters) == 1  # the copy of the verified shard into its boxes
+        # streamed, each sub-chunk is first copied into the shard's scratch; buffered, nothing else moves
+        assert sum(e["args"]["bytes"] for e in chunks) == (shard_bytes if streamed else 0)
+        verified = [e for e in found if e["name"] == "consume_verify"]
+        assert len(verified) == (len(chunks) + 1 if streamed else 1)
+        queued = [e["args"]["thread"] for e in found if e["name"] == "consume_queue"]
+        assert queued.count("worker") == queued.count("loop") == len(verified)  # a wait each way, a call
+        assert bool([e for e in found if e["name"] == "stream_read_wait"]) == streamed
+        # the leaf is placed inside the read of its last shard, and after that shard's scatter
+        for e in (e for e in found if e["name"] == "consume_place"):
+            assert scatters[0]["ts"] + scatters[0]["dur"] <= e["ts"] + 1e-9
 
 
 def test_the_bus_keeps_its_clock():

@@ -18,6 +18,7 @@ import asyncio
 import contextlib
 import contextvars
 import ctypes
+import functools
 import logging
 import os
 import threading
@@ -1196,6 +1197,71 @@ class ArrayBufferStager(BufferStager):
         return self._nbytes()
 
 
+def _executor_submit(executor, path: str) -> Callable:
+    """``await submit(fn, *args)``: ``fn(*args)`` on one of ``executor``'s
+    threads (here and now where there is no executor). With telemetry on,
+    what the call waits for a thread is a ``consume_queue`` span each
+    way: ``thread="worker"`` from here to the start of ``fn`` on the
+    executor's thread, ``thread="loop"`` from the end of ``fn`` there
+    until the event-loop thread takes the result up (it needs the GIL
+    that the other workers' copies hold). Off, this is ``run_in_executor``
+    itself. The branch is taken here, once a stream or buffer, not once
+    a chunk."""
+    if executor is None:
+
+        async def inline(fn, *args):
+            return fn(*args)
+
+        return inline
+    loop = asyncio.get_running_loop()
+    if not telemetry.enabled():
+        return functools.partial(loop.run_in_executor, executor)
+
+    def queued(thread: str):
+        return telemetry.handoff_span(
+            "consume_queue", cat="consumer", path=path, thread=thread
+        )
+
+    async def submit(fn, *args):
+        out = queued("worker")
+        back = None
+
+        def run():
+            nonlocal back
+            out.__exit__(None, None, None)
+            try:
+                return fn(*args)
+            finally:
+                back = queued("loop")
+
+        try:
+            return await loop.run_in_executor(executor, run)
+        finally:
+            if back is not None:
+                back.__exit__(None, None, None)
+
+    return submit
+
+
+def _hostcopy_span(path: Optional[str], nbytes: int):
+    """Around a host-to-host move of ``nbytes`` restored bytes of the
+    read ``path``."""
+    return telemetry.span(
+        "consume_hostcopy", cat="consumer", path=path, bytes=nbytes
+    )
+
+
+def _placing_thread() -> str:
+    """What a ``consume_place`` span says of its thread: ``"loop"`` where
+    an event loop runs (every other read of the restore stalls behind the
+    placement), else ``"worker"``."""
+    try:
+        asyncio.get_running_loop()
+    except RuntimeError:
+        return "worker"
+    return "loop"
+
+
 @dataclass
 class DeviceMaterializer:
     """How a restored array lands on device, captured at prepare time
@@ -1221,7 +1287,8 @@ class _ScratchSink:
     the payload (which is why consumers using this sink declare the FULL
     consuming cost to the budget, not the window)."""
 
-    def __init__(self, nbytes: int) -> None:
+    def __init__(self, nbytes: int, path: Optional[str] = None) -> None:
+        self.path = path
         # Pooled slab, not a fresh allocation: on lazily-backed VMs the
         # first touch of never-used memory costs several x a normal
         # fault, and a training loop restores repeatedly — the pool's
@@ -1239,7 +1306,8 @@ class _ScratchSink:
                 f"read stream produced more than the expected "
                 f"{self.buf.nbytes} bytes"
             )
-        self.buf[self.pos : self.pos + mv.nbytes] = np.frombuffer(mv, np.uint8)
+        with _hostcopy_span(self.path, mv.nbytes):
+            self.buf[self.pos : self.pos + mv.nbytes] = np.frombuffer(mv, np.uint8)
         self.pos += mv.nbytes
 
     def finish(self) -> memoryview:
@@ -1261,6 +1329,7 @@ class _DeviceRowSink:
     the callback fires."""
 
     def __init__(self, entry: "ArrayEntry", dest: DeviceMaterializer) -> None:
+        self.path = entry.location
         self.shape = tuple(entry.shape)
         self.np_dtype = string_to_dtype(entry.dtype)
         raw = array_size_bytes(self.shape, entry.dtype)
@@ -1276,23 +1345,28 @@ class _DeviceRowSink:
         import jax
 
         mv = data if isinstance(data, memoryview) else memoryview(data)
-        self.carry += mv.cast("B")
-        whole = (len(self.carry) // self.row_bytes) * self.row_bytes
-        if not whole:
-            return
-        src = self.carry
-        self.carry = bytearray(memoryview(src)[whole:])
-        rows = whole // self.row_bytes
-        block = np.frombuffer(
-            src, dtype=self.np_dtype, count=rows * self.row_elems
-        ).reshape((rows,) + self.shape[1:])
+        # Every byte is copied into the carry, and what follows the last
+        # whole row into a new one: a sibling of the HtoD dispatch below.
+        with _hostcopy_span(self.path, mv.nbytes):
+            self.carry += mv.cast("B")
+            whole = (len(self.carry) // self.row_bytes) * self.row_bytes
+            if not whole:
+                return
+            src = self.carry
+            self.carry = bytearray(memoryview(src)[whole:])
+            rows = whole // self.row_bytes
+            block = np.frombuffer(
+                src, dtype=self.np_dtype, count=rows * self.row_elems
+            ).reshape((rows,) + self.shape[1:])
         if self._device is None and self.dest.committed:
             self._device = next(iter(self.dest.sharding.device_set))
         # device_put returns immediately (transfer proceeds in the
         # background) and `src` stays alive through the block's buffer
         # reference — and is never mutated again, so a zero-copy CPU
         # device_put is safe too.
-        with telemetry.span("sub_chunk_htod", cat="consumer", bytes=whole):
+        with telemetry.span(
+            "sub_chunk_htod", cat="consumer", path=self.path, bytes=whole
+        ):
             self.blocks.append(jax.device_put(block, self._device))
         self.rows += rows
 
@@ -1312,7 +1386,8 @@ class _DeviceRowSink:
             )
         # Device-side assembly: concatenate, placement, cast, hand-over.
         with telemetry.span(
-            "consume_assemble", cat="consumer", blocks=len(self.blocks)
+            "consume_assemble", cat="consumer", path=self.path,
+            blocks=len(self.blocks),
         ):
             full = self.blocks[0] if len(self.blocks) == 1 else jnp.concatenate(
                 self.blocks, axis=0
@@ -1342,6 +1417,7 @@ class _IncrementalEntryDecoder:
         from ..compression import StreamingDecompressor
         from ..integrity import IncrementalVerifier
 
+        self.path = entry.location
         self.verifier = IncrementalVerifier(entry.checksum, entry.location)
         self.decomp = (
             StreamingDecompressor(
@@ -1354,10 +1430,12 @@ class _IncrementalEntryDecoder:
         self.sink_add = sink_add
 
     def add(self, chunk) -> None:
-        with telemetry.span(
-            "consume_chunk", cat="consumer", bytes=memoryview(chunk).nbytes
-        ):
-            self.verifier.update(chunk)
+        nbytes = memoryview(chunk).nbytes
+        with telemetry.span("consume_chunk", cat="consumer", bytes=nbytes):
+            with telemetry.span(
+                "consume_verify", cat="consumer", path=self.path, bytes=nbytes
+            ):
+                self.verifier.update(chunk)
             data = self.decomp.feed(chunk) if self.decomp is not None else chunk
             if memoryview(data).nbytes:
                 self.sink_add(data)
@@ -1367,7 +1445,8 @@ class _IncrementalEntryDecoder:
             tail = self.decomp.finish()
             if tail:
                 self.sink_add(tail)
-        self.verifier.finish()
+        with telemetry.span("consume_verify", cat="consumer", path=self.path):
+            self.verifier.finish()
 
 
 def _entry_stored_size(entry: "ArrayEntry") -> int:
@@ -1417,9 +1496,11 @@ class ArrayBufferConsumer(BufferConsumer):
             and self.ensure_writable
             and not arr.flags["WRITEABLE"]
         ):
-            arr = np.array(arr)
+            with _hostcopy_span(self.entry.location, arr.nbytes):
+                arr = np.array(arr)
         if self.dst_view is not None:
-            fast_copyto(self.dst_view, arr)
+            with _hostcopy_span(self.entry.location, arr.nbytes):
+                fast_copyto(self.dst_view, arr)
             if self.callback is not None:
                 self.callback(self.dst_view)
         elif self.callback is not None:
@@ -1433,7 +1514,11 @@ class ArrayBufferConsumer(BufferConsumer):
             # (whole file, or the entry's byte_range within a batched slab),
             # so the recorded checksum applies directly.
             if verification_enabled():
-                verify_checksum(buf, self.entry.checksum, self.entry.location)
+                with telemetry.span(
+                    "consume_verify", cat="consumer", path=self.entry.location,
+                    bytes=memoryview(buf).nbytes,
+                ):
+                    verify_checksum(buf, self.entry.checksum, self.entry.location)
         if self.entry.codec is not None:
             from ..compression import decompress
 
@@ -1447,11 +1532,8 @@ class ArrayBufferConsumer(BufferConsumer):
         self._deliver(buf)
 
     async def consume_buffer(self, buf: BufferType, executor=None) -> None:
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, self._consume_sync, buf)
-        else:
-            self._consume_sync(buf)
+        submit = _executor_submit(executor, self.entry.location)
+        await submit(self._consume_sync, buf)
 
     def get_consuming_cost_bytes(self) -> int:
         return array_size_bytes(self.entry.shape, self.entry.dtype)
@@ -1525,11 +1607,12 @@ class ArrayBufferConsumer(BufferConsumer):
             scratch = None
         else:
             scratch = _ScratchSink(
-                array_size_bytes(self.entry.shape, self.entry.dtype)
+                array_size_bytes(self.entry.shape, self.entry.dtype),
+                self.entry.location,
             )
             sink = scratch
         decoder = _IncrementalEntryDecoder(self.entry, sink.add)
-        loop = asyncio.get_running_loop() if executor is not None else None
+        submit = _executor_submit(executor, self.entry.location)
 
         def finish() -> None:
             decoder.finish()  # checksum mismatch raises BEFORE any commit
@@ -1539,14 +1622,8 @@ class ArrayBufferConsumer(BufferConsumer):
                 sink.finish()
 
         async for chunk in stream.chunks:
-            if loop is not None:
-                await loop.run_in_executor(executor, decoder.add, chunk)
-            else:
-                decoder.add(chunk)
-        if loop is not None:
-            await loop.run_in_executor(executor, finish)
-        else:
-            finish()
+            await submit(decoder.add, chunk)
+        await submit(finish)
 
 
 class ArrayIOPreparer:
@@ -1625,14 +1702,13 @@ class _SlicedArrayConsumer(BufferConsumer):
         from ..serialization import string_to_dtype
 
         flat = np.frombuffer(buf, dtype=np.uint8).view(string_to_dtype(self.entry.dtype))
-        self.assembler.fill_flat(self.elem_lo, self.elem_hi, flat)
+        self.assembler.fill_flat(
+            self.elem_lo, self.elem_hi, flat, self.entry.location
+        )
 
     async def consume_buffer(self, buf: BufferType, executor=None) -> None:
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, self._consume_sync, buf)
-        else:
-            self._consume_sync(buf)
+        submit = _executor_submit(executor, self.entry.location)
+        await submit(self._consume_sync, buf)
 
     def get_consuming_cost_bytes(self) -> int:
         itemsize = array_size_bytes((1,), self.entry.dtype)
@@ -1685,23 +1761,21 @@ class _SlicedArrayConsumer(BufferConsumer):
                         f"read stream produced more than the expected "
                         f"{total} bytes for {self.entry.location}"
                     )
-                dst[base + pos : base + pos + mv.nbytes] = np.frombuffer(
-                    mv, np.uint8
-                )
+                with _hostcopy_span(self.entry.location, mv.nbytes):
+                    dst[base + pos : base + pos + mv.nbytes] = np.frombuffer(
+                        mv, np.uint8
+                    )
             return mv.nbytes
 
-        loop = asyncio.get_running_loop() if executor is not None else None
+        submit = _executor_submit(executor, self.entry.location)
         async for chunk in stream.chunks:
-            if loop is not None:
-                pos += await loop.run_in_executor(executor, fill, chunk)
-            else:
-                pos += fill(chunk)
+            pos += await submit(fill, chunk)
         if pos != total:
             raise IOError(
                 f"short read stream for {self.entry.location}: produced "
                 f"{pos} of {total} bytes"
             )
-        self.assembler.part_done()
+        self.assembler.part_done(self.entry.location)
 
 
 class ArrayAssembler:
@@ -1738,22 +1812,34 @@ class ArrayAssembler:
         ``dst`` on completion, which would clobber direct writes."""
         return self._scratch[index] if index else self._scratch
 
-    def fill_flat(self, elem_lo: int, elem_hi: int, values: np.ndarray) -> None:
-        fast_copyto(self._flat[elem_lo:elem_hi], values)
-        self.part_done()
+    # ``path`` is the read a part arrived in: what its copy's span carries.
 
-    def fill_region(self, index: Tuple[slice, ...], values: np.ndarray) -> None:
-        fast_copyto(self.region_view(index), values)
-        self.part_done()
+    def fill_flat(
+        self, elem_lo: int, elem_hi: int, values: np.ndarray, path: Optional[str] = None
+    ) -> None:
+        with _hostcopy_span(path, values.nbytes):
+            fast_copyto(self._flat[elem_lo:elem_hi], values)
+        self.part_done(path)
 
-    def part_done(self) -> None:
+    def fill_region(
+        self,
+        index: Tuple[slice, ...],
+        values: np.ndarray,
+        path: Optional[str] = None,
+    ) -> None:
+        with _hostcopy_span(path, values.nbytes):
+            fast_copyto(self.region_view(index), values)
+        self.part_done(path)
+
+    def part_done(self, path: Optional[str] = None) -> None:
         # Parts are consumed concurrently from executor threads.
         with self._lock:
             self._remaining -= 1
             remaining = self._remaining
         if remaining == 0:
             if self._scratch is not self.dst:
-                fast_copyto(self.dst, self._scratch)
+                with _hostcopy_span(path, self._scratch.nbytes):
+                    fast_copyto(self.dst, self._scratch)
             if self.callback is not None:
                 self.callback(self.dst)
 

@@ -44,7 +44,7 @@ class _RegionConsumer:
         )
 
         def cb(arr: np.ndarray) -> None:
-            self.assembler.fill_region(index, arr)
+            self.assembler.fill_region(index, arr, self.chunk.array.location)
 
         return cb
 
@@ -185,7 +185,9 @@ class ChunkedArrayIOPreparer:
                     ArrayIOPreparer.prepare_read(
                         chunk.array,
                         dst_view=sub_dst,
-                        callback=lambda _, a=assembler: a.part_done(),
+                        callback=lambda _, a=assembler, p=chunk.array.location: (
+                            a.part_done(p)
+                        ),
                         buffer_size_limit_bytes=buffer_size_limit_bytes,
                     )
                 )

@@ -12,6 +12,7 @@ from typing import Any, Callable, List, Optional
 
 import numpy as np
 
+from .. import telemetry
 from ..io_types import ReadReq
 from ..manifest import (
     ArrayEntry,
@@ -21,7 +22,7 @@ from ..manifest import (
     PrimitiveEntry,
     ShardedArrayEntry,
 )
-from .array import ArrayIOPreparer
+from .array import ArrayIOPreparer, _placing_thread
 from .chunked import ChunkedArrayIOPreparer
 from .object import ObjectIOPreparer
 
@@ -277,16 +278,29 @@ def prepare_read(
         # DEVICE after the transfer: the wire moves the snapshot's (often
         # narrower) bytes and the VPU does the widening, not the host.
 
+        # The read a leaf is placed in: its own, or its one chunk's. Which
+        # of several chunks lands last is not known here, and the span
+        # then names none.
+        place_path = getattr(entry, "location", None)
+        if isinstance(entry, ChunkedArrayEntry) and len(entry.chunks) == 1:
+            place_path = entry.chunks[0].array.location
+
         def _materialize(
             host: np.ndarray,
             _cb=callback,
             _placement=sharding if committed else None,
         ) -> None:
-            restored = jax.device_put(host, _placement)
-            if needs_cast:
-                restored = restored.astype(dst_dtype)
-            if _cb is not None:
-                _cb(restored)
+            # One HtoD dispatch of the whole leaf, inside the read that
+            # completed it.
+            with telemetry.span(
+                "consume_place", cat="consumer", path=place_path,
+                bytes=host.nbytes, thread=_placing_thread(),
+            ):
+                restored = jax.device_put(host, _placement)
+                if needs_cast:
+                    restored = restored.astype(dst_dtype)
+                if _cb is not None:
+                    _cb(restored)
 
         final_callback = _materialize
         ensure_writable = False

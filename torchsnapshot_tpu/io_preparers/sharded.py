@@ -34,7 +34,6 @@ Restore (reference: io_preparer.py:199-246,315-389):
 
 from __future__ import annotations
 
-import asyncio
 import hashlib
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -49,7 +48,14 @@ from ..serialization import (
     dtype_to_string,
     string_to_dtype,
 )
-from .array import ArrayBufferStager, fast_copyto
+from .. import telemetry
+from .array import (
+    ArrayBufferStager,
+    _executor_submit,
+    _hostcopy_span,
+    _placing_thread,
+    fast_copyto,
+)
 
 DEFAULT_MAX_SHARD_SIZE_BYTES = 512 * 1024 * 1024
 
@@ -174,9 +180,14 @@ class _ShardScatterConsumer(BufferConsumer):
 
             # Each saved shard is read exactly once, in full.
             if verification_enabled():
-                verify_checksum(
-                    buf, self.shard.array.checksum, self.shard.array.location
-                )
+                with telemetry.span(
+                    "consume_verify", cat="consumer",
+                    path=self.shard.array.location,
+                    bytes=memoryview(buf).nbytes,
+                ):
+                    verify_checksum(
+                        buf, self.shard.array.checksum, self.shard.array.location
+                    )
         if self.shard.array.codec is not None:
             from ..compression import decompress
             from ..serialization import array_size_bytes
@@ -192,21 +203,22 @@ class _ShardScatterConsumer(BufferConsumer):
             buf, self.shard.array.dtype, self.shard.array.shape
         )
 
+    def _copy_to_boxes(self, arr: np.ndarray) -> None:
+        with _hostcopy_span(self.shard.array.location, arr.nbytes):
+            for dst_buf, src_slices, dst_slices in self.targets:
+                target = dst_buf[dst_slices] if dst_slices else dst_buf
+                fast_copyto(target, arr[src_slices] if src_slices else arr)
+
     def _scatter(self, arr: np.ndarray) -> None:
-        for dst_buf, src_slices, dst_slices in self.targets:
-            target = dst_buf[dst_slices] if dst_slices else dst_buf
-            fast_copyto(target, arr[src_slices] if src_slices else arr)
-        self.completion.part_done()
+        self._copy_to_boxes(arr)
+        self.completion.part_done(self.shard.array.location)
 
     def _consume_sync(self, buf: BufferType) -> None:
         self._scatter(self._decode(buf))
 
     async def consume_buffer(self, buf: BufferType, executor=None) -> None:
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, self._consume_sync, buf)
-        else:
-            self._consume_sync(buf)
+        submit = _executor_submit(executor, self.shard.array.location)
+        await submit(self._consume_sync, buf)
 
     def get_consuming_cost_bytes(self) -> int:
         return array_size_bytes(self.shard.array.shape, self.shard.array.dtype)
@@ -231,42 +243,43 @@ class _ShardScatterConsumer(BufferConsumer):
         from .array import _IncrementalEntryDecoder, _ScratchSink
 
         entry = self.shard.array
-        scratch = _ScratchSink(array_size_bytes(entry.shape, entry.dtype))
+        scratch = _ScratchSink(
+            array_size_bytes(entry.shape, entry.dtype), entry.location
+        )
         decoder = _IncrementalEntryDecoder(entry, scratch.add)
 
         def finish() -> None:
             decoder.finish()  # checksum mismatch raises BEFORE the scatter
-            arr = array_from_buffer(scratch.finish(), entry.dtype, entry.shape)
-            for dst_buf, src_slices, dst_slices in self.targets:
-                target = dst_buf[dst_slices] if dst_slices else dst_buf
-                fast_copyto(target, arr[src_slices] if src_slices else arr)
+            self._copy_to_boxes(
+                array_from_buffer(scratch.finish(), entry.dtype, entry.shape)
+            )
 
-        loop = asyncio.get_running_loop() if executor is not None else None
+        submit = _executor_submit(executor, entry.location)
         async for chunk in stream.chunks:
-            if loop is not None:
-                await loop.run_in_executor(executor, decoder.add, chunk)
-            else:
-                decoder.add(chunk)
-        if loop is not None:
-            await loop.run_in_executor(executor, finish)
-        else:
-            finish()
-        self.completion.part_done()
+            await submit(decoder.add, chunk)
+        await submit(finish)
+        self.completion.part_done(entry.location)
 
 
 class _Completion:
-    def __init__(self, num_parts: int, finalize: Callable[[], None]) -> None:
+    """Counts a leaf's shards in; the last one runs ``finalize(path)``,
+    ``path`` being that shard's location: the read under whose span the
+    leaf is placed."""
+
+    def __init__(
+        self, num_parts: int, finalize: Callable[[Optional[str]], None]
+    ) -> None:
         self._remaining = num_parts
         self._finalize = finalize
         self._lock = threading.Lock()
 
-    def part_done(self) -> None:
+    def part_done(self, path: Optional[str] = None) -> None:
         # Parts are consumed concurrently from executor threads.
         with self._lock:
             self._remaining -= 1
             remaining = self._remaining
         if remaining == 0:
-            self._finalize()
+            self._finalize(path)
 
 
 class ShardedArrayIOPreparer:
@@ -641,17 +654,26 @@ class ShardedArrayIOPreparer:
                         tuple(hi - lo for lo, hi in box), dtype=np_dtype
                     )
 
-            def finalize() -> None:
+            def finalize(path: Optional[str] = None) -> None:
                 def cb(index: Tuple[slice, ...]) -> np.ndarray:
                     return boxes[_normalize_index(index, shape)]
 
-                restored = jax.make_array_from_callback(shape, sharding, cb)
-                if needs_cast:
-                    # Cast on device after the (narrower-dtype) transfer;
-                    # astype preserves the destination sharding.
-                    restored = restored.astype(dst_dtype)
-                if callback is not None:
-                    callback(restored)
+                # Runs where the leaf's last shard lands: on an executor
+                # thread after a buffered read, on the event-loop thread
+                # after a streamed one.
+                with telemetry.span(
+                    "consume_place", cat="consumer",
+                    path=path,
+                    bytes=sum(b.nbytes for b in boxes.values()),
+                    thread=_placing_thread(),
+                ):
+                    restored = jax.make_array_from_callback(shape, sharding, cb)
+                    if needs_cast:
+                        # Cast on device after the (narrower-dtype) transfer;
+                        # astype preserves the destination sharding.
+                        restored = restored.astype(dst_dtype)
+                    if callback is not None:
+                        callback(restored)
 
             # Planned-peer source tier: with an active reshard context,
             # project EVERY rank's destination boxes out of the global
@@ -696,7 +718,7 @@ class ShardedArrayIOPreparer:
         whole: Box = tuple((0, dim) for dim in shape)
         boxes = {whole: dst}
 
-        def finalize_np() -> None:
+        def finalize_np(path: Optional[str] = None) -> None:
             if callback is not None:
                 callback(dst)
 
@@ -707,7 +729,7 @@ class ShardedArrayIOPreparer:
         cls,
         entry: ShardedArrayEntry,
         boxes: Dict[Box, np.ndarray],
-        finalize: Callable[[], None],
+        finalize: Callable[[Optional[str]], None],
         reshard_roles: Optional[Dict[int, Any]] = None,
     ) -> List[ReadReq]:
         """One ReadReq per saved shard overlapping a destination box.

@@ -52,7 +52,6 @@ box maps the caller supplies; device work stays in io_preparers above.
 
 from __future__ import annotations
 
-import asyncio
 import json
 import os
 from dataclasses import dataclass, field
@@ -462,11 +461,10 @@ class PlannedOwnerConsumer(BufferConsumer):
         self.direct._scatter(arr)
 
     async def consume_buffer(self, buf: BufferType, executor=None) -> None:
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, self._consume_sync, buf)
-        else:
-            self._consume_sync(buf)
+        from .io_preparers.array import _executor_submit
+
+        submit = _executor_submit(executor, self.direct.shard.array.location)
+        await submit(self._consume_sync, buf)
 
     def get_consuming_cost_bytes(self) -> int:
         return self.direct.get_consuming_cost_bytes()
@@ -601,14 +599,13 @@ class PlannedRecvConsumer(BufferConsumer):
                 f"trailing byte(s) after {len(self._regions)} region(s)"
             )
         telemetry.counter_add("bytes_resharded_from_peers", pos)
-        self.direct.completion.part_done()
+        self.direct.completion.part_done(self.direct.shard.array.location)
 
     async def consume_buffer(self, buf: BufferType, executor=None) -> None:
-        if executor is not None:
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(executor, self._consume_sync, buf)
-        else:
-            self._consume_sync(buf)
+        from .io_preparers.array import _executor_submit
+
+        submit = _executor_submit(executor, self.direct.shard.array.location)
+        await submit(self._consume_sync, buf)
 
     def get_consuming_cost_bytes(self) -> int:
         # The fallback path decodes the full stored shard; budget for it.

@@ -988,8 +988,6 @@ class PendingIOWork:
         reporter = self._reporter
         if reporter is not None:
             reporter.start()
-        drain_span = telemetry.span("io_drain")
-        drain_span.__enter__()
         try:
             while self._io_tasks or self._ready_for_io:
                 self._dispatch_io()
@@ -1022,7 +1020,6 @@ class PendingIOWork:
             self._executor.shutdown(wait=True)
             raise
         finally:
-            drain_span.__exit__(None, None, None)
             if reporter is not None:
                 reporter.stop()
         self._executor.shutdown(wait=True)
@@ -1601,7 +1598,12 @@ class _ReadPipeline:
                 while True:
                     t0 = telemetry.monotonic() if observe else None
                     try:
-                        chunk = await chunks.__anext__()
+                        # The stream's wait for storage as the consumer
+                        # sees it: the stretch the histogram below times.
+                        with telemetry.span(
+                            "stream_read_wait", path=self.read_req.path
+                        ):
+                            chunk = await chunks.__anext__()
                     except StopAsyncIteration:
                         break
                     if t0 is not None:

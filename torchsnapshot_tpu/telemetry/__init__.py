@@ -29,6 +29,7 @@ from .core import (  # noqa: F401
     events,
     gauge_set,
     gauges,
+    handoff_span,
     histogram_observe,
     histogram_quantile,
     histograms,

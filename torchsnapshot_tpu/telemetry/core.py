@@ -194,10 +194,20 @@ class Span:
         "name", "cat", "args", "_ts", "_parent", "_tid", "_id", "_tok", "_note",
     )
 
-    def __init__(self, name: str, cat: str, args: Optional[Dict[str, Any]]):
+    def __init__(
+        self,
+        name: str,
+        cat: str,
+        args: Optional[Dict[str, Any]],
+        handed_over: bool = False,
+    ):
         self.name = name
         self.cat = cat
         self.args = args
+        # A span that another thread will exit (:func:`handoff_span`) stays
+        # off the nesting stack: _tok is False, where an open nested span
+        # holds its reset token.
+        self._tok = False if handed_over else None
 
     def set(self, **args: Any) -> None:
         """Attach/overwrite args after entry (e.g. bytes known at exit)."""
@@ -217,7 +227,8 @@ class Span:
         with _lock:
             _next_id += 1
             self._id = _next_id
-        self._tok = _span_stack.set(stack + (self._id,))
+        if self._tok is not False:
+            self._tok = _span_stack.set(stack + (self._id,))
         self._note = _profiler_note(self.name)
         self._ts = monotonic()
         return self
@@ -229,10 +240,11 @@ class Span:
                 self._note.__exit__(None, None, None)
             except Exception:  # pragma: no cover - the bus still records
                 pass
-        try:
-            _span_stack.reset(self._tok)
-        except ValueError:  # pragma: no cover - exit in a foreign context
-            pass
+        if self._tok is not False:
+            try:
+                _span_stack.reset(self._tok)
+            except ValueError:  # pragma: no cover - exit in a foreign context
+                pass
         ev = {
             "ph": "span",
             "id": self._id,
@@ -258,6 +270,21 @@ def span(name: str, cat: str = "pipeline", **args: Any):
     if not _enabled:
         return _NULL_SPAN
     return Span(name, cat, args or None)
+
+
+def handoff_span(name: str, cat: str = "pipeline", **args: Any):
+    """A span opened HERE, now, that another thread closes with
+    ``__exit__(None, None, None)``: the wait of a unit of work for a
+    thread of an executor, from its submission to the start of the work.
+    It records the span open on the submitting thread as its ``parent``
+    but never joins a nesting stack, so no other span on either thread
+    becomes its child or loses its own parent; the bus event carries the
+    submitting thread's ``tid``, the profiler's event lands on the thread
+    that closes it (:func:`_profiler_note`). The shared no-op when
+    disabled."""
+    if not _enabled:
+        return _NULL_SPAN
+    return Span(name, cat, args or None, handed_over=True).__enter__()
 
 
 def event(name: str, cat: str = "event", **args: Any) -> None:

@@ -63,9 +63,10 @@ CATEGORIES: Tuple[str, ...] = (
 )
 
 #: Span name -> category, for spans whose WHOLE duration is one
-#: resource. Spans not listed here or in :data:`FUSED_SPANS` (io_drain
-#: and other containers) attribute through their children, never
-#: themselves.
+#: resource. Spans not listed here or in :data:`FUSED_SPANS` (containers
+#: such as ``stage_crc`` inside ``stage_hash``, and spans that split a
+#: listed one, such as ``consume_verify`` inside ``consume_chunk``)
+#: attribute through the listed spans around them, never themselves.
 SPAN_CATEGORIES: Dict[str, str] = {
     "stage_hash": "hash",
     "sub_chunk_stage": "stage_copy",
