@@ -683,6 +683,219 @@ def test_mirror_zero_produced_failover_is_transparent(loop, tmp_path) -> None:
     assert b"".join(parts) == payload
 
 
+# ------------------------------------------------------ the device row sink
+
+
+@pytest.fixture
+def bus():
+    from torchsnapshot_tpu import telemetry
+
+    telemetry.reset()
+    telemetry.set_enabled(True)
+    yield telemetry
+    telemetry.set_enabled(False)
+    telemetry.reset()
+
+
+def _slab(piece):
+    """A chunk as the fs plugin yields one: a view of a pooled slab."""
+    from torchsnapshot_tpu.io_preparers.array import pooled_buffer
+
+    buf = pooled_buffer(len(piece))
+    buf[:] = np.frombuffer(piece, np.uint8)
+    return memoryview(buf)
+
+
+def _through_the_sink(loop, monkeypatch, arr, sub, *, chunk_of=_slab, stored=None, **entry_kw):
+    """Stream ``arr``'s stored bytes in chunks of ``sub`` through the
+    consumer of a single-device jax destination; returns what reached
+    the callback and, for each ``add``, the carry it left and the row."""
+    from torchsnapshot_tpu.io_preparers.array import _DeviceRowSink
+
+    entry, whole = _entry_for(arr, **entry_kw)
+    stored = whole if stored is None else stored(whole)
+    consumer, restored = _device_consumer(arr, entry)
+    left = []
+    add = _DeviceRowSink.add
+
+    def spied(self, data):
+        add(self, data)
+        left.append((len(self.carry), self.row_bytes))
+
+    monkeypatch.setattr(_DeviceRowSink, "add", spied)
+
+    async def chunks():
+        for lo in range(0, len(stored), sub):
+            yield chunk_of(stored[lo : lo + sub])
+
+    run = consumer.consume_stream(ReadStream(path="x", nbytes=len(stored), chunks=chunks()))
+    return restored, left, lambda: loop.run_until_complete(run)
+
+
+def _bf16(shape):
+    import ml_dtypes
+
+    n = int(np.prod(shape))
+    return (np.arange(n, dtype=np.float32) % 509).astype(ml_dtypes.bfloat16).reshape(shape)
+
+
+def _f32(shape):
+    return np.arange(int(np.prod(shape)), dtype=np.float32).reshape(shape)
+
+
+# name: leaf, sub-chunk, the sink's row in bytes, bytes that may be copied
+# (a row a sub-chunk edge that splits one; None: see the case), chunk type
+_SINK_CASES = {
+    # shape[0]'s row is 512 KiB, over the sub-chunk and over the cap: the
+    # row is the last dimension, 2 KiB, and no edge splits one
+    "stacked-leaf-with-rows-wider-than-the-sub-chunk": (lambda: _f32((3, 256, 512)), 64 << 10, 2048, 0, _slab),
+    "one-dimensional-leaf": (lambda: np.arange(300_000, dtype=np.int32), 100_000, 4, 0, _slab),
+    "bfloat16": (lambda: _bf16((600, 1000)), 64 << 10, 2000, 18 * 2000, _slab),
+    # two trailing dimensions fit the cap: a row of 100 KiB, every byte through the carry
+    "sub-chunk-smaller-than-a-row": (lambda: _f32((4, 100, 256)), 40_000, 102_400, 409_600, _slab),
+    "sub-chunk-ends-on-a-row-boundary": (lambda: _f32((512, 1024)), 64 << 10, 4096, 0, _slab),
+    "sub-chunk-ends-inside-a-row": (lambda: _f32((500, 1000)), 64 << 10, 4000, 30 * 4000, _slab),
+    # 10 001 bytes a chunk: every second chunk's rows start off a float's alignment
+    "odd-lengths-off-the-dtypes-alignment": (lambda: _f32((500, 1000)), 10_001, 4000, None, _slab),
+    "a-bytearray-the-sink-may-not-keep": (lambda: _f32((500, 1000)), 64 << 10, 4000, 2_000_000,
+                                          lambda piece: memoryview(bytearray(piece))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SINK_CASES))
+def test_device_sink_restores_bit_for_bit_under_a_carry_of_less_than_a_row(case, loop, monkeypatch, bus) -> None:
+    make, sub, row, may_copy, chunk_of = _SINK_CASES[case]
+    arr = make()
+    restored, left, run = _through_the_sink(loop, monkeypatch, arr, sub, chunk_of=chunk_of)
+    run()
+    (out,) = restored
+    assert out.shape == arr.shape and out.dtype == arr.dtype
+    assert np.asarray(out).tobytes() == arr.tobytes()
+    assert left and all(r == row and carry < r for carry, r in left)
+    counters = bus.counters()
+    views, copied = counters.get("bytes_htod_views", 0), counters.get("bytes_htod_copied", 0)
+    assert views + copied == arr.nbytes
+    htod = [e for e in bus.events() if e["ph"] == "span" and e["name"] == "sub_chunk_htod"]
+    host = [e for e in bus.events() if e["ph"] == "span" and e["name"] == "consume_hostcopy"]
+    assert sum(e["args"]["bytes"] for e in htod) == arr.nbytes
+    if may_copy is None:
+        # chunks 1, 3, 5, ... start 1, 3, 5, ... bytes into a float
+        assert 0 < views < arr.nbytes and copied > arr.nbytes // 3
+    else:
+        assert copied == may_copy
+        # what the carry took in, half a row at a time: never a chunk
+        assert sum(e["args"]["bytes"] for e in host) == copied
+    assert bool(host) == bool(copied) and all(e["args"]["bytes"] > 0 for e in host)
+
+
+def test_device_sink_copies_what_a_decompressor_feeds_off_alignment(loop, monkeypatch, bus) -> None:
+    """A compressed entry: the decompressor hands over ``bytes`` of any
+    length, whole rows rarely start on the dtype's alignment, and the
+    sink then copies as it always did."""
+    arr = np.random.default_rng(0).integers(0, 16, 500_000).astype(np.float32).reshape(500, 1000)
+    restored, left, run = _through_the_sink(loop, monkeypatch, arr, 7777, codec="zlib:6")
+    run()
+    assert np.asarray(restored[0]).tobytes() == arr.tobytes()
+    assert len(left) > 4 and all(carry < row == 4000 for carry, row in left)
+    counters = bus.counters()
+    assert counters.get("bytes_htod_views", 0) + counters["bytes_htod_copied"] == arr.nbytes
+    # more than the two halves of a row a feed: whole rows were copied
+    assert counters["bytes_htod_copied"] > 2 * 4000 * len(left)
+
+
+@pytest.mark.parametrize("cut, says", [(1_999_000, "mid-row"), (1_996_000, "short read stream")])
+def test_device_sink_refuses_a_stream_that_ends_early(cut, says, loop, monkeypatch) -> None:
+    arr = _f32((500, 1000))
+    restored, left, run = _through_the_sink(
+        loop, monkeypatch, arr, 64 << 10, stored=lambda whole: whole[:cut], checksum=False
+    )
+    with pytest.raises(IOError, match=says):
+        run()
+    assert restored == [] and all(carry < row for carry, row in left)
+
+
+def test_device_sink_raises_on_a_corrupted_byte_before_any_callback(loop, monkeypatch) -> None:
+    from torchsnapshot_tpu.integrity import IntegrityError
+
+    arr = _f32((500, 1000))
+
+    def flip(whole):
+        bad = bytearray(whole)
+        bad[1_234_567] ^= 0xFF
+        return bytes(bad)
+
+    restored, _, run = _through_the_sink(loop, monkeypatch, arr, 64 << 10, stored=flip)
+    with pytest.raises(IntegrityError):
+        run()
+    assert restored == []
+
+
+@pytest.mark.parametrize("shape, itemsize, row", [
+    # olmo1b's stacked leaves: a layer's slice is 16-64 MiB, the row is the last dimension
+    ((7, 2048, 8192), 4, (8192,)), ((7, 8192, 2048), 4, (2048,)), ((7, 2048, 6144), 4, (6144,)),
+    ((50304, 2048), 4, (2048,)),
+    # trailing dimensions are taken while they fit 256 KiB, never the whole shape
+    ((6, 16, 2048, 768), 2, (768,)), ((48, 8, 64, 128), 4, (8, 64, 128)), ((3, 64, 128), 4, (64, 128)),
+    ((4, 3_000_000), 4, (3_000_000,)), ((1000,), 4, ()),
+])
+def test_device_sink_row_is_a_short_run_of_trailing_dimensions(shape, itemsize, row) -> None:
+    from torchsnapshot_tpu.io_preparers.array import _sink_row_shape
+
+    assert _sink_row_shape(shape, itemsize) == row
+
+
+def test_device_sink_declares_the_window_unless_the_last_dimension_outgrows_it() -> None:
+    """A stacked leaf's admission cost is the window, whatever its
+    ``shape[0]`` row; only a last dimension wider than the sub-chunk
+    still grows the carry past it."""
+    arr = _f32((3, 256, 512))  # shape[0]'s row is 512 KiB, eight sub-chunks
+    consumer, _ = _device_consumer(arr, _entry_for(arr)[0])
+    assert consumer.stream_admission_cost(SUB) == (STREAM_DEPTH + 1) * SUB
+    wide = _f32((4, 100_000))
+    consumer, _ = _device_consumer(wide, _entry_for(wide)[0])
+    assert consumer.stream_admission_cost(SUB) == wide.nbytes
+
+
+def test_view_and_copy_counters_reach_stats_and_the_history_record(tmp_path, monkeypatch, bus, capsys) -> None:
+    """A restore persists no document of its own; what ``stats -v``
+    prints of one and what a history record keeps come from the same
+    summary and fleet view, and both hold the two counters."""
+    import json
+
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import Snapshot, StateDict
+    from torchsnapshot_tpu.cli import main
+    from torchsnapshot_tpu.telemetry import TELEMETRY_SUMMARY_FNAME, build_summary_document, history
+
+    monkeypatch.setenv("TORCHSNAPSHOT_TPU_SUB_CHUNK_BYTES", str(128 << 10))
+    monkeypatch.setenv("TORCHSNAPSHOT_TPU_ENABLE_BATCHING", "0")
+    w, v = _f32((400, 1000)), _f32((3, 256, 512))
+    Snapshot.take(str(tmp_path / "s"), {"app": StateDict(w=jnp.asarray(w), v=jnp.asarray(v), b=jnp.ones((8, 8)))})
+    dst = {"app": StateDict(w=jnp.zeros_like(w), v=jnp.zeros_like(v), b=jnp.zeros((8, 8)))}
+    Snapshot(str(tmp_path / "s")).restore(dst)
+    assert np.array_equal(np.asarray(dst["app"]["w"]), w) and np.array_equal(np.asarray(dst["app"]["v"]), v)
+
+    summary, fleet = bus.last_summary(), bus.last_fleet()
+    assert summary["op"] == "restore"
+    streamed = w.nbytes + v.nbytes  # the small leaf is read whole
+    views, copied = summary["counters"]["bytes_htod_views"], summary["counters"]["bytes_htod_copied"]
+    assert views + copied == streamed
+    # w's rows of 4000 bytes are split at 12 sub-chunk edges, v's of 2 KiB at none
+    assert copied == 12 * 4000
+    assert fleet["aggregate"]["bytes_htod_views"] == views and fleet["aggregate"]["bytes_htod_copied"] == copied
+
+    (tmp_path / "doc").mkdir()
+    doc = build_summary_document("restore", 1, [summary], fleet)
+    (tmp_path / "doc" / TELEMETRY_SUMMARY_FNAME).write_text(json.dumps(doc, default=repr))
+    assert main(["stats", "-v", str(tmp_path / "doc")]) == 0
+    out = capsys.readouterr().out
+    assert "bytes_htod_views" in out and "bytes_htod_copied" in out
+
+    record = history.build_record(op="restore", path=str(tmp_path / "s"), wall_s=1.0, world_size=1, fleet=fleet)
+    assert record["bytes_htod_views"] == views and record["bytes_htod_copied"] == copied
+
+
 # ------------------------------------------------------------- end to end
 
 

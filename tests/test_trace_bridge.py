@@ -39,6 +39,14 @@ from torchsnapshot_tpu.telemetry import core
 
 PREFIX = "tsnap:"
 SUB_CHUNK = 128 << 10
+# The streamed leaf: 400 rows of 4000 bytes. A row does not divide the
+# sub-chunk, so every sub-chunk edge splits one and the device row sink's
+# carry (hence ``consume_hostcopy``) has work; a leaf whose rows divide it
+# goes to the device with no host copy and no such span.
+LEAF_SHAPE = (400, 1000)
+ROW_BYTES = 1000 * 4
+LEAF_BYTES = 400 * ROW_BYTES
+assert SUB_CHUNK % ROW_BYTES and all((k * SUB_CHUNK) % ROW_BYTES for k in range(1, LEAF_BYTES // SUB_CHUNK + 1))
 # What this PR names of a restore, and the two older spans that stay their
 # siblings: each lies inside one OUTER span, none inside another.
 RESTORE_SPANS = ["consume_verify", "consume_hostcopy", "consume_place", "consume_queue", "stream_read_wait"]
@@ -80,9 +88,9 @@ def _spans(events):
 def _take_and_restore(tmp, enabled):
     """What one traced take + streamed restore left in the profiler's
     trace and, per operation, on the bus."""
-    arr = np.arange(400_000, dtype=np.float32).reshape(400, 1000)
+    arr = np.arange(LEAF_BYTES // 4, dtype=np.float32).reshape(LEAF_SHAPE)
     state = {"app": StateDict(w=jnp.asarray(arr), b=jnp.ones((64, 64), jnp.float32))}
-    dst = {"app": StateDict(w=jnp.zeros((400, 1000), jnp.float32), b=jnp.zeros((64, 64), jnp.float32))}
+    dst = {"app": StateDict(w=jnp.zeros(LEAF_SHAPE, jnp.float32), b=jnp.zeros((64, 64), jnp.float32))}
     bus = {}
     # Buffered writes (every leaf goes through stage_hash, as on the chip),
     # streamed reads (the large leaf goes through the device row sink).
@@ -235,6 +243,13 @@ def test_restore_span_is_on_the_bus_with_the_path_of_its_read(run_on, name):
     if name in ("consume_hostcopy", "consume_place"):
         assert moved == found
     assert all(e["args"]["bytes"] > 0 for e in moved)
+    if name == "consume_hostcopy":
+        # only the streamed leaf copies, and only around its carry: the
+        # buffered one is placed from the buffer it was read into
+        (streamed,) = {e["args"]["path"] for e in restore if e["name"] == "stream_read"}
+        assert {e["args"]["path"] for e in found} == {streamed}
+        # one span a chunk: the first opens a row, the last closes one, the others do both
+        assert len(found) == -(-LEAF_BYTES // SUB_CHUNK) and all(e["args"]["bytes"] < 2 * ROW_BYTES for e in found)
 
 
 def _by_path(spans, names):
@@ -258,7 +273,8 @@ def _assert_siblings_inside(outer, inner):
 
 
 @pytest.mark.parametrize("outer, leaf, want", [
-    # the large leaf streams through the device row sink, the small one is read whole
+    # the large leaf streams through the device row sink (its rows do not divide the
+    # sub-chunk, so the carry copies: LEAF_SHAPE), the small one is read whole
     ("stream_read", "w", {"stream_read_wait", "consume_queue", "consume_verify", "consume_hostcopy",
                           "sub_chunk_htod", "consume_assemble"}),
     ("consume", "b", {"consume_queue", "consume_verify", "consume_place"}),
@@ -277,17 +293,27 @@ def test_hostcopy_and_htod_are_siblings_under_consume_chunk(run_on):
     restore = run_on["bus"]["restore"]
     by_id = {e["id"]: e for e in restore}
     htod = [e for e in restore if e["name"] == "sub_chunk_htod"]
-    assert len(htod) > 1
-    for e in htod:
-        chunk = by_id[e["parent"]]
-        assert chunk["name"] == "consume_chunk" and chunk["tid"] == e["tid"]
+    chunks = {e["parent"] for e in htod}
+    assert len(chunks) == -(-LEAF_BYTES // SUB_CHUNK)
+    for chunk in map(by_id.get, chunks):
+        assert chunk["name"] == "consume_chunk"
         children = sorted((c for c in restore if c["parent"] == chunk["id"]), key=lambda c: c["ts"])
-        assert [c["name"] for c in children] == ["consume_verify", "consume_hostcopy", "sub_chunk_htod"]
+        assert all(c["tid"] == chunk["tid"] for c in children)
+        # the copies into and out of the carry come first, in one span; then
+        # the row they completed goes to the device, then the chunk's own rows
+        names = [c["name"] for c in children]
+        assert names[:2] == ["consume_verify", "consume_hostcopy"] and 1 <= len(names[2:]) <= 2
+        assert set(names[2:]) == {"sub_chunk_htod"}
         for a, b in zip(children, children[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-9
-    # every byte of the leaf went through the carry, and the same bytes to the device
+    # every byte of the leaf went to the device; the carry took the two parts
+    # of the one row a sub-chunk edge splits, never a chunk
     copied = [e for e in restore if e["name"] == "consume_hostcopy"]
-    assert sum(e["args"]["bytes"] for e in copied) == sum(e["args"]["bytes"] for e in htod) == 400 * 1000 * 4
+    assert sum(e["args"]["bytes"] for e in htod) == LEAF_BYTES
+    assert all(0 < e["args"]["bytes"] < 2 * ROW_BYTES for e in copied)
+    assert sum(e["args"]["bytes"] for e in copied) == ROW_BYTES * (len(chunks) - 1) < ROW_BYTES * len(chunks)
+    # a completed row goes alone, so the blocks are the chunks and those rows
+    assert len(htod) == 2 * len(chunks) - 1
 
 
 def test_consume_place_says_which_thread_placed_the_leaf(run_on):

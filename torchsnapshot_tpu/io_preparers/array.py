@@ -1319,80 +1319,173 @@ class _ScratchSink:
         return memoryview(self.buf)
 
 
+# The sink's row is the longest run of trailing dimensions within this
+# many bytes: a thirty-second of the smallest sub-chunk the governor
+# elects (8 MiB), so whatever the shape a sub-chunk holds rows by the
+# dozen and what the carry copies at its edges stays under 1/32 of it.
+_SINK_ROW_CAP_BYTES = 256 << 10
+
+
+def _sink_row_shape(shape: Tuple[int, ...], itemsize: int) -> Tuple[int, ...]:
+    """The trailing dimensions that make one row of ``_DeviceRowSink``:
+    the longest suffix of ``shape`` short of the whole within
+    ``_SINK_ROW_CAP_BYTES``, the last dimension alone where two are
+    wider than that, and ``()``, one element, for a 1-D leaf."""
+    if len(shape) < 2:
+        return ()
+    keep = len(shape) - 1
+    nbytes = itemsize * shape[keep]
+    while keep > 1 and nbytes * shape[keep - 1] <= _SINK_ROW_CAP_BYTES:
+        keep -= 1
+        nbytes *= shape[keep]
+    return shape[keep:]
+
+
+def _sink_row_bytes(entry: "ArrayEntry") -> int:
+    row = _sink_row_shape(tuple(entry.shape), string_to_dtype(entry.dtype).itemsize)
+    return max(1, array_size_bytes(row, entry.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _device_assembler() -> Callable:
+    """``assemble(shape, *blocks)``: the blocks joined along axis 0 and
+    given the leaf's shape, as one program a set of block shapes, so the
+    joined rows are written once and the reshape (leading dimensions
+    folded back out of axis 0) is no pass of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def assemble(shape, *blocks):
+        return jnp.concatenate(blocks, axis=0).reshape(shape)
+
+    return assemble
+
+
 class _DeviceRowSink:
     """Per-sub-chunk HtoD sink: whole-row blocks of the decoded payload
     are ``device_put`` as they land, assembled on device at the end
-    (concatenate along dim 0, then placed under the destination
-    sharding). The host holds only the carry of a partial row plus the
-    chunk in flight — the window the scheduler's budget charges — and
-    the destination array is untouched until the checksum validated and
-    the callback fires."""
+    (joined along axis 0, reshaped to the leaf, then placed under the
+    destination sharding). A row is a run of trailing dimensions short
+    enough that every sub-chunk holds many (``_sink_row_shape``), and a
+    sub-chunk's whole rows go to the device as a view of the buffer they
+    were read into: nothing is copied but what completes a row that the
+    previous sub-chunk left open, and the tail that opens the next. The
+    view keeps the buffer (a pooled slab: ``pooled_buffer``) alive until
+    the runtime has taken the bytes, and nobody writes it again
+    (``ReadStream``). On the CPU backend ``device_put`` may alias the
+    host buffer for good: a leaf restored from a single block then lives
+    in a pool slab until it dies, which the pool's contract allows. A
+    chunk the sink cannot keep a view of (``_view``) is copied whole, as
+    every chunk once was. The host holds the carry, under one row, plus
+    the chunks in flight — the window the scheduler's budget charges —
+    and the destination array is untouched until the checksum validated
+    and the callback fires."""
 
     def __init__(self, entry: "ArrayEntry", dest: DeviceMaterializer) -> None:
         self.path = entry.location
         self.shape = tuple(entry.shape)
         self.np_dtype = string_to_dtype(entry.dtype)
-        raw = array_size_bytes(self.shape, entry.dtype)
-        self.row_bytes = max(1, raw // self.shape[0])
-        self.row_elems = self.row_bytes // self.np_dtype.itemsize
+        self.row_shape = _sink_row_shape(self.shape, self.np_dtype.itemsize)
+        self.row_bytes = _sink_row_bytes(entry)
+        self.total_rows = array_size_bytes(self.shape, entry.dtype) // self.row_bytes
         self.dest = dest
         self.carry = bytearray()
         self.blocks: list = []
         self.rows = 0
         self._device = None
 
+    def _view(self, mv: memoryview, offset: int, nbytes: int):
+        """``mv[offset : offset + nbytes]`` as an array of the entry's
+        dtype over the chunk's own memory, or None where the sink has
+        to copy: the exporter is neither ``bytes`` nor an ndarray (a
+        pooled slab is one), so its owner may resize or close it under
+        a view the sink still holds (``bytearray``, ``mmap``), or the
+        rows start off the dtype's alignment (a decompressor feeds any
+        length)."""
+        if not isinstance(mv.obj, (bytes, np.ndarray)):
+            return None
+        block = np.frombuffer(
+            mv, self.np_dtype, nbytes // self.np_dtype.itemsize, offset
+        )
+        return block if block.flags.aligned else None
+
     def add(self, data) -> None:
+        mv = (data if isinstance(data, memoryview) else memoryview(data)).cast("B")
+        row = self.row_bytes
+        # What this chunk holds: the head that goes on filling the open
+        # row, whole rows, and the tail that opens the next one.
+        head = min(mv.nbytes, row - len(self.carry)) if self.carry else 0
+        whole = (mv.nbytes - head) // row * row
+        tail = mv.nbytes - head - whole
+        block = self._view(mv, head, whole) if whole else None
+        viewed = block is not None
+        copied = head + tail + (0 if viewed else whole)
+        closed = None
+        if copied:
+            # Siblings of the HtoD dispatches below, ahead of them all.
+            with _hostcopy_span(self.path, copied):
+                if head:
+                    self.carry += mv[:head]
+                    if len(self.carry) == row:
+                        closed, self.carry = self.carry, bytearray()
+                if whole and not viewed:
+                    block = np.frombuffer(
+                        bytearray(mv[head : head + whole]), self.np_dtype
+                    )
+                if tail:
+                    self.carry = bytearray(mv[head + whole :])
+        if closed is not None:
+            self._put(np.frombuffer(closed, self.np_dtype), viewed=False)
+        if whole:
+            self._put(block, viewed)
+
+    def _put(self, block: np.ndarray, viewed: bool) -> None:
         import jax
 
-        mv = data if isinstance(data, memoryview) else memoryview(data)
-        # Every byte is copied into the carry, and what follows the last
-        # whole row into a new one: a sibling of the HtoD dispatch below.
-        with _hostcopy_span(self.path, mv.nbytes):
-            self.carry += mv.cast("B")
-            whole = (len(self.carry) // self.row_bytes) * self.row_bytes
-            if not whole:
-                return
-            src = self.carry
-            self.carry = bytearray(memoryview(src)[whole:])
-            rows = whole // self.row_bytes
-            block = np.frombuffer(
-                src, dtype=self.np_dtype, count=rows * self.row_elems
-            ).reshape((rows,) + self.shape[1:])
         if self._device is None and self.dest.committed:
             self._device = next(iter(self.dest.sharding.device_set))
+        rows = block.nbytes // self.row_bytes
         # device_put returns immediately (transfer proceeds in the
-        # background) and `src` stays alive through the block's buffer
-        # reference — and is never mutated again, so a zero-copy CPU
-        # device_put is safe too.
+        # background) and what `block` views stays alive through the
+        # block's buffer reference — and is never mutated again, so a
+        # zero-copy CPU device_put is safe too.
         with telemetry.span(
-            "sub_chunk_htod", cat="consumer", path=self.path, bytes=whole
+            "sub_chunk_htod", cat="consumer", path=self.path, bytes=block.nbytes
         ):
-            self.blocks.append(jax.device_put(block, self._device))
+            self.blocks.append(
+                jax.device_put(block.reshape((rows,) + self.row_shape), self._device)
+            )
+        telemetry.counter_add(
+            "bytes_htod_views" if viewed else "bytes_htod_copied", block.nbytes
+        )
         self.rows += rows
 
     def finish(self) -> None:
         import jax
-        import jax.numpy as jnp
 
         if self.carry:
             raise IOError(
                 f"read stream ended mid-row: {len(self.carry)} trailing "
                 f"bytes do not fill a {self.row_bytes}-byte row"
             )
-        if self.rows != self.shape[0]:
+        if self.rows != self.total_rows:
             raise IOError(
                 f"short read stream: produced {self.rows} of "
-                f"{self.shape[0]} rows"
+                f"{self.total_rows} rows"
             )
         # Device-side assembly: concatenate, placement, cast, hand-over.
         with telemetry.span(
             "consume_assemble", cat="consumer", path=self.path,
             blocks=len(self.blocks),
         ):
-            full = self.blocks[0] if len(self.blocks) == 1 else jnp.concatenate(
-                self.blocks, axis=0
+            blocks, self.blocks = self.blocks, []
+            full = (
+                blocks[0]
+                if len(blocks) == 1 and blocks[0].shape == self.shape
+                else _device_assembler()(self.shape, *blocks)
             )
-            self.blocks = []
+            del blocks
             restored = (
                 jax.device_put(full, self.dest.sharding)
                 if self.dest.committed
@@ -1587,9 +1680,12 @@ class ArrayBufferConsumer(BufferConsumer):
 
     def stream_admission_cost(self, sub_chunk_bytes: int) -> int:
         cost = self.get_consuming_cost_bytes()
-        if self._device_mode_ok(sub_chunk_bytes):
+        if self._device_sink_ok() and _sink_row_bytes(self.entry) <= sub_chunk_bytes:
             # Chunk being decoded + the plugin's read-ahead + the row
-            # carry: the window the device sink actually holds.
+            # carry: the window the device sink actually holds. Its row
+            # is a short run of trailing dimensions; only a LAST
+            # dimension wider than the sub-chunk still grows the carry
+            # past the window, and declares the full cost below.
             from ..io_types import STREAM_DEPTH
 
             return min(cost, (STREAM_DEPTH + 1) * sub_chunk_bytes)

@@ -34,6 +34,12 @@ _SUMMED_COUNTERS = (
     "budget_defers",
     "dtoh_window_waits",
     "chunk_payloads",
+    # A streamed restore onto one device (_DeviceRowSink): bytes handed
+    # to device_put as views of the buffer they were read into, and
+    # bytes copied first (a row's halves at a sub-chunk's edges, or a
+    # chunk the sink could keep no view of).
+    "bytes_htod_views",
+    "bytes_htod_copied",
     # Degradation counters (PR 4/6 machinery): a fleet that failed over
     # mid-take must SAY so in the persisted summary — these existed on
     # the bus but vanished post-hoc until the observability PR.

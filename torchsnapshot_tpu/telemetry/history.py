@@ -43,6 +43,8 @@ _RECORD_COUNTERS = (
     "bytes_read",
     "bytes_to_peers",
     "bytes_deduped",
+    "bytes_htod_views",
+    "bytes_htod_copied",
     "retry_attempts",
     "store_failovers",
     "lease_renewals",
