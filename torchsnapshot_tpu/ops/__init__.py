@@ -17,6 +17,9 @@ state to snapshot:
   kernels per hop), hops merged by log-sum-exp under one custom VJP;
 - GShard-style top-2 MoE with einsum and sort-based dispatch, and an
   explicit all-to-all expert-parallel path;
+- the front end of compressed convolutional attention (``cca``): q and k
+  through two causal convolutions, a mean shared between them, an L2 norm
+  and a temperature, v shifted by a position for half its heads;
 - selective-SSM sequence mixing via associative scan, with a
   sequence-parallel cross-chunk carry.
 """

@@ -579,3 +579,64 @@ def softmax_topk_routed(
         lambda x2: softmax_topk_route(x2, params["router"], top_k),
         held, (params["expert_gate"], params["expert_up"], params["expert_down"]), tile,
     )
+
+
+# ------------------------------- top-1 of many, the route an MLP over a stream carried through the depth
+
+
+def mlp_top1_route(
+    x2: jax.Array, r_prev: jax.Array, router: Dict[str, Any], *, norm_eps: float
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A top-1 route that is not a matrix (the ZAYA1 router, Zyphra,
+    arXiv:2511.17127, written from memory of the report): ``(T, D)`` tokens
+    and the router's stream ``r_prev (T, R)`` from the layer before ->
+    the id ``(T, 1)`` of each token's expert, its weight ``(T, 1)`` and the
+    stream ``r (T, R)`` the next layer receives::
+
+        r = x W_d + c_d + gamma * r_prev                    exponential depth averaging, gamma a learned vector
+        u = rms(r; g_r)
+        l = gelu(gelu(u W_1 + c_1) W_2 + c_2) W_3           R -> R -> R -> E, the exact (erf) gelu
+        p = softmax(l);   e* = argmax(p + beta_sel);   w = p[e*]
+
+    ``router``: ``down (D, R)``, ``down_b``, ``decay``, ``norm_scale
+    (R,)``, ``w1``, ``w2 (R, R)``, ``b1``, ``b2 (R,)``, ``w3 (R, E)``,
+    ``bias (E,)``. The bias only selects and takes no
+    gradient. **The weight is the chosen probability itself, not
+    renormalised**: one chosen weight over its own sum is 1, whatever the
+    router says, and no gradient would reach it. All of it in float32, the
+    products at full precision (a bfloat16 pass moves a probability by
+    1e-2, the distance between neighbours among 16)."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    w = {k: v.astype(f32) for k, v in router.items()}
+    r = jnp.matmul(x2.astype(f32), w["down"], precision=hi) + w["down_b"] + w["decay"] * r_prev.astype(f32)
+    with jax.named_scope("router_mlp"):
+        u = r * jax.lax.rsqrt(jnp.mean(jnp.square(r), axis=-1, keepdims=True) + norm_eps) * w["norm_scale"]
+        h = jax.nn.gelu(jnp.matmul(u, w["w1"], precision=hi) + w["b1"], approximate=False)
+        h = jax.nn.gelu(jnp.matmul(h, w["w2"], precision=hi) + w["b2"], approximate=False)
+        p = jax.nn.softmax(jnp.matmul(h, w["w3"], precision=hi), axis=-1)
+    ids = jnp.argmax(p + jax.lax.stop_gradient(w["bias"]), axis=-1, keepdims=True).astype(jnp.int32)
+    return ids, jnp.take_along_axis(p, ids, axis=-1), r
+
+
+def mlp_top1_routed(
+    params: Dict[str, Any], x: jax.Array, r_prev: jax.Array, *, held: Tuple[int, ...], norm_eps: float, tile: int = _TILE
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The routed part of a top-1 layer whose router is ``mlp_top1_route``,
+    for the gated experts held here: ``x: (..., D)`` and ``r_prev: (...,
+    R)`` -> (float32 of ``x``'s shape, the chosen ids ``(T, 1)``, ``r`` of
+    ``r_prev``'s shape). ``params``: the router's leaves as ``router_<name>``
+    and ``expert_gate`` / ``expert_up (n, D, F)``, ``expert_down (n, F, D)``
+    of the held experts. The result is ``w (silu(x G_e) * (x U_e)) D_e`` for
+    a token whose expert e is held here and 0 for every other token;
+    dropless and tiled as ``sigmoid_topk_routed`` says. Every chip that
+    shares the layer computes the same route and the same ``r``."""
+    router = {k[len("router_"):]: v for k, v in params.items() if k.startswith("router_")}
+    carried = []
+
+    def route(x2):
+        ids, weights, r = mlp_top1_route(x2, r_prev.reshape(x2.shape[0], -1), router, norm_eps=norm_eps)
+        carried.append(r)
+        return ids, weights
+
+    y, ids = _routed_to_held(x, route, held, (params["expert_gate"], params["expert_up"], params["expert_down"]), tile)
+    return y, ids, carried[0].reshape(r_prev.shape)
