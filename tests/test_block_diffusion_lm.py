@@ -12,7 +12,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib.util
-import math
 import os
 
 import jax
@@ -24,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
 from torchsnapshot_tpu.models import block_diffusion_lm as M
 from torchsnapshot_tpu.ops.attention import BlockDiffusionMask, causal_attention_route
+from torchsnapshot_tpu.ops import moe
 from torchsnapshot_tpu.ops.moe import _held_experts, gated_ffn, softmax_topk_route, softmax_topk_routed
 from torchsnapshot_tpu.parallel import make_mesh
 
@@ -203,16 +203,18 @@ def test_softmax_routing_takes_the_top_k_of_all_experts_and_renormalises():
     assert np.asarray(weights).std() > 0.05  # routers at full size here: the weights are not all an eighth
 
 
-def test_the_eight_disjoint_shares_add_up_to_the_uncut_layer():
+def test_the_eight_disjoint_shares_add_up_to_the_uncut_layer(monkeypatch):
     """Eight chips hold two experts each of sixteen; what they add is the
-    layer with all sixteen held, and the reference's dense loop."""
+    layer with all sixteen held, and the reference's dense loop. Row tiles
+    of 16, so an expert has several."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 16)
     w, x = _expert_layer(CFG, held="all"), _stream()
-    whole, ids = softmax_topk_routed(w, x, top_k=CFG.top_k, held=tuple(range(16)), tile=16)
+    whole, ids = softmax_topk_routed(w, x, top_k=CFG.top_k, held=tuple(range(16)))
     parts = []
     for chip in range(8):
         held = (2 * chip, 2 * chip + 1)
         part = {**w, **{k: w[k][jnp.asarray(held)] for k in ("expert_gate", "expert_up", "expert_down")}}
-        out, ids_part = softmax_topk_routed(part, x, top_k=CFG.top_k, held=held, tile=16)
+        out, ids_part = softmax_topk_routed(part, x, top_k=CFG.top_k, held=held)
         np.testing.assert_array_equal(ids_part, ids)  # every share scores and chooses over all sixteen
         parts.append(out)
     np.testing.assert_allclose(sum(parts), whole, atol=2e-6)
@@ -229,9 +231,10 @@ def _dense_held(x, w_held, ws):
 @pytest.mark.parametrize("tile", [8, 16, 64])
 def test_the_gated_experts_backward_pass_is_autodiffs_of_the_dense_form(tile):
     """``_held_experts`` given three matrices: its hand-written backward
-    (data-dependent trip counts, part-filled last tiles, an expert nobody
-    chose) against ``jax.grad`` of a dense loop, for the rows, the routing
-    weights and the three stacks. float32: 2e-5 is the order of the sums."""
+    (data-dependent chunk and slab counts, part-filled last tiles, an expert
+    nobody chose) against ``jax.grad`` of a dense loop, for the rows, the
+    routing weights and the three stacks. float32: 2e-5 is the order of the
+    sums."""
     T, n = 64, 4
     x = _stream(rows=T)
     keys = jax.random.split(jax.random.PRNGKey(1), 5)
@@ -263,11 +266,13 @@ def test_routing_stats_count_what_the_routers_chose():
     np.testing.assert_array_equal(stats["held_counts"], counts)
     np.testing.assert_allclose(stats["held_share"], counts.sum(1) / chosen[0].size, rtol=1e-6)
     np.testing.assert_allclose(stats["max_over_mean"], counts.max(1) / counts.mean(1), rtol=1e-6)
-    tile = math.gcd(B * 2 * S, M.expert_tile(CFG, B * 2 * S))
-    trips = np.sum(-(-counts // tile), axis=1)
+    # the list's row tiles: an expert's own rows rounded up to tiles, one tile for an expert of no rows
+    tile = M.expert_tile(CFG, B * 2 * S)
+    assert tile == min(128, B * 2 * S)
+    trips = np.sum(np.maximum(-(-counts // tile), 1), axis=1)
     np.testing.assert_array_equal(stats["trips"], trips)
     np.testing.assert_allclose(stats["tile_fill"], counts.sum(1) / (trips * tile), rtol=1e-6)
-    assert (trips > 0).all()
+    assert (trips >= len(CFG.held)).all()
     want = R.chosen_experts(params, batch["tokens"], masked=masked, **_ref_args(CFG))
     for got_layer, want_layer in zip(chosen, want):
         np.testing.assert_array_equal(np.sort(got_layer, -1), np.sort(np.asarray(want_layer).reshape(got_layer.shape), -1))
@@ -402,10 +407,14 @@ def test_the_init_keeps_random_routers_near_even_loads(seed):
     assert plain_fullest[-1] > 4.0 and np.abs(plain_share - 1).max() > 0.4, (plain_share, plain_fullest)
 
 
-@pytest.mark.parametrize("positions,top_k,n_experts,tile", [(8192, 8, 128, 1024), (16384, 8, 128, 2048), (256, 4, 16, 256), (8192, 6, 128, 1024)])
-def test_the_experts_tile_holds_twice_the_even_load(positions, top_k, n_experts, tile):
+@pytest.mark.parametrize("positions,top_k,n_experts,tile", [(8192, 8, 128, 128), (16384, 8, 128, 128), (256, 4, 16, 128), (96, 6, 128, 96)])
+def test_the_experts_row_tile_follows_the_positions_alone(positions, top_k, n_experts, tile):
+    """128 rows, whatever the even load (512 positions an expert in
+    ``sdar30b.save``, 1024 at twice its batch): the list rounds an expert's
+    load up to a row tile, so a smaller tile is less padding, and 128 is the
+    MXU's own."""
     cfg = dataclasses.replace(CFG, top_k=top_k, n_experts=n_experts, held=(0,))
-    assert M.expert_tile(cfg, positions) == tile >= min(2 * positions * top_k / n_experts, tile)
+    assert M.expert_tile(cfg, positions) == tile
 
 
 def test_a_bad_share_or_head_grouping_is_refused():

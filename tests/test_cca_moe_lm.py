@@ -26,7 +26,7 @@ from torchsnapshot_tpu import CheckpointManager, Snapshot, StateDict, telemetry
 from torchsnapshot_tpu.io_preparers import chunked
 from torchsnapshot_tpu.manifest import ChunkedArrayEntry
 from torchsnapshot_tpu.models import cca_moe_lm as M
-from torchsnapshot_tpu.ops import cca
+from torchsnapshot_tpu.ops import cca, moe
 from torchsnapshot_tpu.ops.attention import causal_attention_route
 from torchsnapshot_tpu.ops.moe import mlp_top1_route, mlp_top1_routed
 from torchsnapshot_tpu.parallel import make_mesh
@@ -300,14 +300,15 @@ def test_the_route_is_the_mlp_over_the_averaged_carry_and_its_weight_is_not_reno
     np.testing.assert_allclose(weights5[:, 0], p[:, 5], rtol=1e-5)
 
 
-def test_the_weight_carries_a_gradient_to_the_router_and_the_selection_bias_takes_none():
+def test_the_weight_carries_a_gradient_to_the_router_and_the_selection_bias_takes_none(monkeypatch):
+    monkeypatch.setattr(moe, "_ROW_TILE", 16)  # several row tiles an expert
     w = _layer_params(CFG)
     b, r_prev = _normed().reshape(-1, CFG.d_model), _normed(seed=4, shape=(B * S, CFG.router_dim))
     g = jax.random.normal(jax.random.PRNGKey(2), b.shape)
     held_params = {k: v for k, v in w.items() if k.startswith(("router_", "expert_"))}
 
     def out(p, r_prev):
-        y, _, r = mlp_top1_routed(p, b, r_prev, held=CFG.held, norm_eps=CFG.norm_eps, tile=16)
+        y, _, r = mlp_top1_routed(p, b, r_prev, held=CFG.held, norm_eps=CFG.norm_eps)
         return jnp.sum(y * g) + 0.0 * jnp.sum(r)
 
     grads, d_prev = jax.grad(out, (0, 1))(held_params, r_prev)
@@ -378,20 +379,23 @@ def test_routing_stats_count_what_the_routers_chose():
     np.testing.assert_array_equal(stats["held_counts"], counts)
     np.testing.assert_allclose(stats["held_share"], counts.sum(1) / (B * S), rtol=1e-6)
     np.testing.assert_allclose(stats["max_over_mean"], counts.max(1) / counts.mean(1), rtol=1e-6)
-    tile = math.gcd(B * S, M.expert_tile(CFG, B * S))
-    trips = np.sum(-(-counts // tile), axis=1)
+    # the list's row tiles: an expert's own rows rounded up to tiles, one tile for an expert of no rows
+    tile = M.expert_tile(CFG, B * S)
+    assert tile == min(128, B * S)
+    trips = np.sum(np.maximum(-(-counts // tile), 1), axis=1)
     np.testing.assert_array_equal(stats["trips"], trips)
     np.testing.assert_allclose(stats["tile_fill"], counts.sum(1) / (trips * tile), rtol=1e-6)
-    assert (trips > 0).all() and 0 < counts.sum() < CFG.n_layers * B * S  # some positions' experts are elsewhere
+    assert (trips >= len(CFG.held)).all() and 0 < counts.sum() < CFG.n_layers * B * S  # some positions' experts are elsewhere
     want = R.chosen_experts(params, batch["tokens"], **_ref_args(CFG))
     for got_layer, want_layer in zip(chosen, want):
         np.testing.assert_array_equal(got_layer[:, 0], np.asarray(want_layer).reshape(-1))
 
 
-@pytest.mark.parametrize("positions,n_experts,tile", [(8192, 16, 1024), (16384, 16, 2048), (256, 8, 256), (8192, 64, 256)])
-def test_the_experts_tile_holds_twice_the_even_load(positions, n_experts, tile):
+@pytest.mark.parametrize("positions,n_experts,tile", [(8192, 16, 128), (16384, 16, 128), (256, 8, 128), (64, 64, 64)])
+def test_the_experts_row_tile_follows_the_positions_alone(positions, n_experts, tile):
+    """128 rows whatever the even load, by ``block_diffusion_lm``'s rule."""
     cfg = dataclasses.replace(CFG, n_experts=n_experts, held=(0,))
-    assert M.expert_tile(cfg, positions) == tile >= min(2 * positions / n_experts, tile)
+    assert M.expert_tile(cfg, positions) == tile
 
 
 # -------------------------------------------------------------------- the stack
@@ -529,7 +533,7 @@ def test_the_published_sizes_count_to_the_cells_state():
     assert layers["temp"].shape == (6, 2) and layers["o"].shape == (6, 1024, 2048)
     assert cfg.layer_matmul_params == 5_242_880 + 327_680 + 659_456 + 6_291_456
     assert cfg.matmul_params_per_token == 6 * 12_521_472 + 32784 * 2048 == 142_270_464
-    assert M.expert_tile(cfg, 8192) == 1024 and cfg.rotary_dim == 64
+    assert M.expert_tile(cfg, 8192) == 128 and cfg.rotary_dim == 64
 
 
 _LOADS_CFG = M.CCAMoELMConfig(vocab_size=4096, d_model=128, n_layers=6, n_heads=4, n_kv_heads=2, head_dim=32, n_experts=16,

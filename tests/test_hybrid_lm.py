@@ -11,7 +11,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib.util
-import math
 import os
 
 import jax
@@ -23,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
 from torchsnapshot_tpu.models import hybrid_lm as M
 from torchsnapshot_tpu.ops.attention import causal_attention_route, dense_attention
+from torchsnapshot_tpu.ops import moe
 from torchsnapshot_tpu.ops.moe import relu2_ffn, sigmoid_topk_routed
 from torchsnapshot_tpu.ops.ssm import _within_chunk_sum, mamba2_chunked
 from torchsnapshot_tpu.parallel import make_mesh
@@ -315,9 +315,9 @@ def test_the_chunked_scan_refuses_a_ragged_sequence():
 # ------------------------------------------------------------ the experts
 
 
-def _routed(w, a, held, cfg=CFG, tile=256):
+def _routed(w, a, held, cfg=CFG):
     part = {**w, "expert_up": w["expert_up"][jnp.asarray(held)], "expert_down": w["expert_down"][jnp.asarray(held)]}
-    return sigmoid_topk_routed(part, a, top_k=cfg.top_k, held=tuple(held), routed_scale=cfg.routed_scale, tile=tile)
+    return sigmoid_topk_routed(part, a, top_k=cfg.top_k, held=tuple(held), routed_scale=cfg.routed_scale)
 
 
 def _whole_layer(seed=0):
@@ -350,14 +350,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
 # dropped in the first case (each held expert then serves all B x S tokens,
 # the buffers' whole room), and the second costs nothing and adds nothing.
 @pytest.mark.parametrize("case", ["all_on_held", "none_on_held", "as_routed"])
-@pytest.mark.parametrize("tile", [8, 256])  # many tiles an expert; one ragged tile
-def test_no_token_is_dropped_whatever_the_routing(case, tile):
+@pytest.mark.parametrize("tile", [8, 256])  # many row tiles an expert, in several chunks; one ragged tile
+def test_no_token_is_dropped_whatever_the_routing(monkeypatch, case, tile):
+    monkeypatch.setattr(moe, "_ROW_TILE", tile)
+    monkeypatch.setattr(moe, "_CHUNK_TILES", 4)
     cfg, w, a = _whole_layer(seed=2)
     held = (0, 1, 2, 3, 4, 5)
     push = {"all_on_held": 10.0, "none_on_held": -10.0, "as_routed": 0.0}[case]
     w = {**w, "router_bias": w["router_bias"].at[jnp.asarray(held)].add(push)}
     with jax.default_matmul_precision("highest"):
-        got, ids = _routed(w, a, held, cfg, tile=tile)
+        got, ids = _routed(w, a, held, cfg)
         part = {**w, "expert_up": w["expert_up"][:6], "expert_down": w["expert_down"][:6]}
         want = R.moe_routed(part, a, **{**_ref_args(cfg), "held": held})
     on_held = np.isin(np.asarray(ids), held).sum(axis=-1)
@@ -367,7 +369,7 @@ def test_no_token_is_dropped_whatever_the_routing(case, tile):
         # all six weights arrive: they sum to the scaling factor for every token
         with jax.default_matmul_precision("highest"):
             unit = {**part, "expert_up": jnp.ones_like(part["expert_up"]), "expert_down": jnp.ones_like(part["expert_down"])}
-            one = _routed({**w, **unit}, jnp.abs(a), held, cfg, tile=tile)[0]
+            one = _routed({**w, **unit}, jnp.abs(a), held, cfg)[0]
         per_token = relu2_ffn(jnp.abs(a), unit["expert_up"][0], unit["expert_down"][0])
         np.testing.assert_allclose(np.asarray(one), cfg.routed_scale * np.asarray(per_token), rtol=1e-5)
     elif case == "none_on_held":
@@ -410,9 +412,10 @@ def test_routing_stats_count_what_the_routers_chose():
         np.testing.assert_allclose(float(stats[name]["held_share"]), counts.sum() / ids.size, rtol=1e-6)
         np.testing.assert_allclose(float(stats[name]["max_over_mean"]), counts.max() / counts.mean(), rtol=1e-5)
         assert 0 < float(stats[name]["held_share"]) < 1
-        tile = math.gcd(B * S, 512)  # sigmoid_topk_routed's, as the layer runs it
-        trips = int(np.sum(-(-counts // tile)))
-        assert int(stats[name]["trips"]) == trips > 0
+        # the list's row tiles: an expert's own rows rounded up to tiles, one tile for an expert of no rows
+        tile = min(128, B * S)
+        trips = int(np.sum(np.maximum(-(-counts // tile), 1)))
+        assert int(stats[name]["trips"]) == trips >= len(CFG.held)
         np.testing.assert_allclose(float(stats[name]["tile_fill"]), counts.sum() / (trips * tile), rtol=1e-6)
 
 

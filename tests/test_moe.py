@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchsnapshot_tpu.ops import moe
+from torchsnapshot_tpu.ops import moe, pallas_grouped
 from torchsnapshot_tpu.ops.moe import init_moe_params, moe_ffn
 from torchsnapshot_tpu.ops.pallas_add_rows import add_rows
 
@@ -245,43 +245,158 @@ def test_add_rows_is_xlas_scatter_add_of_the_own_rows(D, tile, n_own):
     np.testing.assert_array_equal(twice, want.at[idxs[1, :n_own], 0].add(rows[1, :n_own]))
 
 
-def _xla_scatter(acc, idx, rows, n_own):
-    """How the parent added a tile: XLA's scatter-add of every row of it,
-    those past ``n_own`` at weight 0."""
-    del n_own
-    return acc.at[idx, 0].add(rows, unique_indices=True)
+@pytest.mark.parametrize("n_own", [(5, 0, 16), (16, 16, 16), (0, 0, 0), (1, 9, 3)])
+def test_add_rows_adds_a_tile_after_the_other(n_own):
+    """Three tiles of 16 rows in one call, each with its own count of own
+    rows, and every tile holding the same tokens as the tile before it in
+    another order (a token that several experts hold): exactly what three
+    scatter-adds, one a tile, leave."""
+    T, D, tile = 64, 128, 16
+    k_acc, k_idx, k_rows = jax.random.split(jax.random.PRNGKey(1), 3)
+    acc = jax.random.normal(k_acc, (T, 1, D), jnp.float32)
+    first = jax.random.permutation(k_idx, T)[:tile].astype(jnp.int32)
+    idx = jnp.concatenate([first, first[::-1], jnp.roll(first, 3)])
+    rows = jax.random.normal(k_rows, (3 * tile, D), jnp.float32)
+    want = acc
+    for t, n in enumerate(n_own):
+        want = want.at[idx[t * tile:t * tile + n], 0].add(rows[t * tile:t * tile + n])
+    np.testing.assert_array_equal(jax.jit(add_rows)(acc, idx, rows, jnp.asarray(n_own, jnp.int32)), want)
+
+
+# ----------------------------------------- the grouped products' kernels
+
+
+def _grouped_case(groups, live, tile=16, K=32, N=256, n=3, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    lhs = jax.random.normal(keys[0], (len(groups) * tile, K))
+    return lhs, jax.random.normal(keys[1], (n, K, N)), jnp.asarray(groups, jnp.int32), jnp.int32(live), keys[2]
+
+
+# Column blocks of 128 (two of them: a run's block is copied ahead across the
+# change of column block too) and of all 256; one group, a group a tile, runs
+# of several tiles, a group that comes back, dead tiles behind the live ones.
+@pytest.mark.parametrize("block_bytes", [128 * 32 * 4, 1 << 20])
+@pytest.mark.parametrize("transpose_rhs,scaled", [(False, False), (True, False), (False, True)])
+@pytest.mark.parametrize("groups,live", [((1, 1, 1, 1), 4), ((0, 1, 2, 0), 4), ((0, 0, 2, 2, 2, 1), 6), ((2, 2, 0, 1, 1, 1), 3), ((1, 0, 0, 0), 1)])
+def test_grouped_matmul_is_each_live_tile_against_its_groups_matrix(monkeypatch, block_bytes, transpose_rhs, scaled, groups, live):
+    monkeypatch.setattr(pallas_grouped, "_BLOCK_BYTES", block_bytes)
+    jax.clear_caches()  # the kernels are jitted: a trace at the other block size would answer
+    lhs, rhs, group, n_live, key = _grouped_case(groups, live)
+    scale = jax.random.uniform(key, (lhs.shape[0],)) if scaled else None
+    got = pallas_grouped.grouped_matmul(
+        lhs, jnp.swapaxes(rhs, 1, 2) if transpose_rhs else rhs, group, n_live, transpose_rhs=transpose_rhs, row_scale=scale
+    )
+    tile = lhs.shape[0] // len(groups)
+    for t in range(live):
+        want = jnp.matmul(lhs[t * tile:(t + 1) * tile], rhs[groups[t]], precision="highest") * (1.0 if scale is None else scale[t * tile:(t + 1) * tile, None])
+        np.testing.assert_allclose(got[t * tile:(t + 1) * tile], want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("block_bytes", [128 * 32 * 4, 1 << 20])
+@pytest.mark.parametrize("groups,live", [((1, 1, 1, 1), 4), ((0, 1, 2, 2), 4), ((0, 0, 2, 2, 2, 1), 6), ((2, 2, 0, 1, 1, 1), 3)])
+def test_grouped_matmul_t_writes_a_groups_sum_once_and_leaves_the_other_groups(monkeypatch, block_bytes, groups, live):
+    monkeypatch.setattr(pallas_grouped, "_BLOCK_BYTES", block_bytes // 2)  # the transposed product takes blocks of twice it
+    jax.clear_caches()
+    lhs, _, group, n_live, key = _grouped_case(groups, live)
+    rhs = jax.random.normal(key, (lhs.shape[0], 256))
+    stack = jnp.full((3, lhs.shape[1], 256), 7.0)
+    got = pallas_grouped.grouped_matmul_t(lhs, rhs, group, n_live, stack)
+    tile = lhs.shape[0] // len(groups)
+    for g in range(3):
+        rows = np.concatenate([np.arange(t * tile, (t + 1) * tile) for t in range(live) if groups[t] == g] or [np.zeros(0, int)])
+        want = jnp.matmul(lhs[rows].T, rhs[rows], precision="highest") if len(rows) else stack[g]
+        np.testing.assert_allclose(got[g], want, rtol=1e-5, atol=1e-5)
+
+
+# ----------------------------------------- the held experts' list and its grouped products
+
+
+def _held_case(n_matrices, T, D, F, member, seed=3):
+    """Rows, weights and matrices for ``_held_experts`` with ``member (n,
+    T)``, and the cotangent."""
+    n = member.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    x = jax.random.normal(keys[0], (T, D))
+    ws = tuple(jax.random.normal(k, (n,) + s) * s[0] ** -0.5 for k, s in zip(keys[1:4], [(D, F)] * (n_matrices - 1) + [(F, D)]))
+    w_held = jnp.where(member, jax.random.uniform(keys[4], (n, T)) + 0.5, 0.0)
+    order = jnp.argsort(~member, axis=-1, stable=True).astype(jnp.int32)
+    counts = jnp.sum(member, axis=-1, dtype=jnp.int32)
+    return x, w_held, order, counts, ws, jax.random.normal(keys[5], (T, D))
+
+
+def _value_and_grads(f, x, w_held, ws, g):
+    return jax.jit(lambda x, w, ws: (f(x, w, ws), jax.grad(lambda *a: jnp.sum(f(*a) * g), (0, 1, 2))(x, w, ws)))(x, w_held, ws)
+
+
+def _members(case, n, T, key):
+    if case == "mixed":  # nobody, a tile exactly, a tile and a row, part of a tile
+        counts = [0, 16, 17, 5]
+    elif case == "everyone":  # one expert holds every token, the others some
+        counts = [T, 3, 0, 40]
+    elif case == "worst":  # every token on every held expert at once: the list at its longest, n * T rows
+        counts = [T] * n
+    else:
+        raise AssertionError(case)
+    perm = jnp.stack([jax.random.permutation(k, T) for k in jax.random.split(key, n)])
+    return jnp.zeros((n, T), bool).at[jnp.arange(n)[:, None], perm].set(jnp.arange(T)[None] < jnp.asarray(counts)[:, None])
+
+
+# The row tile is 16 and a chunk two of them, so the lists below take one
+# chunk to sixteen, and the backward pass one slab to four. T = 100 is no
+# multiple of the row tile; F = 116 beside D = 128 is a width of 1856's kind
+# beside 2688: no multiple of 128 where the other is, so the matrices into
+# the hidden width go through their transposes (``_lane_aligned``).
+@pytest.mark.parametrize("n_matrices", [2, 3])
+@pytest.mark.parametrize(
+    "case,T,D,F",
+    [("mixed", 64, 32, 24), ("everyone", 64, 32, 24), ("worst", 64, 32, 24), ("everyone", 100, 32, 24), ("mixed", 64, 128, 116)],
+)
+def test_held_experts_are_the_dense_sum_over_the_experts(monkeypatch, n_matrices, case, T, D, F):
+    """``_held_experts``, value and every gradient (the rows, the routing
+    weights, each stack of matrices), against every expert over every row
+    in float32: no row dropped at any load, none counted twice, and an
+    expert nobody chose takes a zero gradient. The routing weights'
+    gradient is held where an expert's own rows are: ``w_held`` is 0 by
+    construction elsewhere, and what flows there is dropped by the routing's
+    ``where``."""
+    monkeypatch.setattr(moe, "_CHUNK_TILES", 2)
+    n, tile = 4, 16
+    member = _members(case, n, T, jax.random.PRNGKey(5))
+    x, w_held, order, counts, ws, g = _held_case(n_matrices, T, D, F, member)
+    ffn = {2: moe.relu2_ffn, 3: moe.gated_ffn}[n_matrices]
+    dense = lambda x, w, ws: sum(w[e][:, None] * ffn(x, *(m[e] for m in ws)) for e in range(n))  # noqa: E731
+    got = _value_and_grads(lambda x, w, ws: moe._held_experts(x, w, order, counts, ws, tile), x, w_held, ws, g)
+    want_value, (want_dx, want_dw, want_dws) = _value_and_grads(dense, x, w_held, ws, g)
+    want = (want_value, (want_dx, jnp.where(member, want_dw, 0.0), want_dws))
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())))
+    value, (dx, dw, dws) = got
+    assert float(jnp.abs(value).max()) > 0 and float(jnp.abs(dx).max()) > 0 and float(jnp.abs(dw).max()) > 0
+    for e in range(n):
+        assert all((float(jnp.abs(d[e]).max()) > 0) == (int(counts[e]) > 0) for d in dws)
 
 
 @pytest.mark.parametrize("n_matrices", [2, 3])
-def test_held_experts_with_the_kernel_equal_the_parents_scatter(monkeypatch, n_matrices):
-    """``_held_experts``, value and every gradient, against the same loops
-    with XLA's scatter in the kernel's place: equal, not close (the same
-    float32 additions in the same order of trips). An expert of no rows,
-    one of exactly a tile, one of a tile and a row, one of part of a tile.
-    The routing weights are powers of two, so a row's product with its
-    weight is exact: the CPU's compiler contracts that product and the
-    interpreted kernel's addition into one fused multiply-add, which rounds
-    once where XLA's scatter, and the chip either way, round twice."""
-    T, D, F, tile = 64, 32, 24, 16
-    counts = jnp.asarray([0, tile, tile + 1, 5], jnp.int32)
-    keys = jax.random.split(jax.random.PRNGKey(3), 6)
-    x = jax.random.normal(keys[0], (T, D))
-    ws = tuple(jax.random.normal(k, (4,) + s) * s[0] ** -0.5 for k, s in zip(keys[1:], [(D, F)] * (n_matrices - 1) + [(F, D)]))
-    order = jnp.stack([jax.random.permutation(k, T) for k in jax.random.split(keys[4], 4)]).astype(jnp.int32)
-    member = jnp.zeros((4, T), bool).at[jnp.arange(4)[:, None], order].set(jnp.arange(T)[None] < counts[:, None])
-    w_held = jnp.where(member, 2.0 ** jax.random.randint(keys[5], (4, T), -2, 2), 0.0)
-    g = jax.random.normal(jax.random.PRNGKey(4), (T, D))
+def test_no_row_past_the_lists_end_reaches_the_value_or_a_gradient(monkeypatch, n_matrices):
+    """The products leave the rows of the tiles past the list's last
+    uninitialised, and the slabs' buffers and the gradient stacks start so.
+    With NaN wherever the chip would leave what it found, value and
+    gradients are finite and the same."""
+    monkeypatch.setattr(moe, "_CHUNK_TILES", 4)
+    n, T, D, F, tile = 4, 64, 32, 24, 16
+    member = _members("mixed", n, T, jax.random.PRNGKey(5))  # 5 tiles: the second chunk holds one of four
+    x, w_held, order, counts, ws, g = _held_case(n_matrices, T, D, F, member)
+    f = lambda x, w, ws: moe._held_experts(x, w, order, counts, ws, tile)  # noqa: E731
+    want = _value_and_grads(f, x, w_held, ws, g)
+    product = moe.grouped_matmul
 
-    def value_and_grads():
-        # a fresh trace each time: add_rows is looked up when the loops are traced
-        f = lambda x, w, ws: moe._held_experts(x, w, order, counts, ws, tile)  # noqa: E731
-        return jax.jit(lambda x, w, ws: (f(x, w, ws), jax.grad(lambda *a: jnp.sum(f(*a) * g), (0, 1, 2))(x, w, ws)))(x, w_held, ws)
+    def poisoned(lhs, rhs, tile_group, n_live, **kwargs):
+        dead = jnp.arange(lhs.shape[0]) // (lhs.shape[0] // tile_group.shape[0]) >= n_live
+        return jnp.where(dead[:, None], jnp.nan, product(lhs, rhs, tile_group, n_live, **kwargs))
 
-    got = value_and_grads()
-    monkeypatch.setattr(moe, "add_rows", _xla_scatter)
-    want = value_and_grads()
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    monkeypatch.setattr(moe, "uninitialised", lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    got = _value_and_grads(f, x, w_held, ws, g)
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert bool(jnp.isfinite(a).all())
         np.testing.assert_array_equal(a, b)
-    value, (dx, dw, dws) = got
-    assert float(jnp.abs(value).max()) > 0 and float(jnp.abs(dx).max()) > 0
-    assert all(float(jnp.abs(d[0]).max()) == 0.0 and float(jnp.abs(d[2]).max()) > 0 for d in dws)  # nobody chose expert 0

@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from torchsnapshot_tpu import Snapshot, StateDict
 from torchsnapshot_tpu.models import ssm_lm, transformer as T
-from torchsnapshot_tpu.ops.moe import _held_experts
+from torchsnapshot_tpu.ops.moe import _held_experts, held_row_tile
 from torchsnapshot_tpu.parallel import make_mesh
 from torchsnapshot_tpu.parallel.mesh import collective_bytes, collectives, spanned_axes, with_replica_dim
 
@@ -487,38 +487,86 @@ def _in_loop_bodies(text):
     return [line for comp in sorted(seen) for line in comps[comp]]
 
 
-def test_no_loop_of_the_held_experts_moves_the_whole_accumulator_on_the_tpu_compiler(v5e_2x2, monkeypatch):
-    """``_held_experts`` forward and backward at ``sdar30b.save``'s sizes
-    (8192 positions of width 2048, 16 experts of width 768, tiles of 1024
-    rows), compiled for the chip: a tile is added to the float32
-    accumulator by the row kernel, once forward and once backward, and no
-    loop body copies, slices or scatters into the accumulator itself. With
-    XLA's scatter-add in the kernel's place (the parent's ``ops/moe.py``)
-    the loop bodies hold ``copy-start`` x 3 and ``slice-start`` x 4 of it
-    around two scatter fusions: the whole 64 MB through VMEM every trip."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
-    T_, D_, F_, E_, tile = 8192, 2048, 768, 16, 1024
+# (T, D, F, held experts, matrices, the most experts a token can be on): the held experts of sdar30b.save and zaya1_8b.save
+HELD_SIZES = {"sdar30b.save": (8192, 2048, 768, 16, 3, 8), "zaya1_8b.save": (8192, 2048, 2048, 8, 3, 1)}
+
+
+@pytest.mark.parametrize("cell", sorted(HELD_SIZES))
+def test_no_loop_of_the_held_experts_moves_the_whole_accumulator_on_the_tpu_compiler(v5e_2x2, monkeypatch, cell):
+    """``_held_experts`` forward and backward at a routed cell's sizes,
+    compiled for the chip. The grouped form: no array of ``T x top_k`` rows
+    (the list is walked a chunk of 1024 rows at a time, the backward pass'
+    buffers are a slab of ``T`` and a tile an expert), every weight gradient
+    is a transposed grouped product's result and nothing in a loop zero-fills
+    a stack of the experts' shape, slices an expert out of one or updates
+    one in place (the parent's loops did each an expert), and, as since PR
+    35, no loop body copies, slices or scatters into the float32
+    accumulator: its rows are added where it lies."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
+    T_, D_, F_, E_, n_matrices, most = HELD_SIZES[cell]
+    tile = held_row_tile(T_)
     one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
     arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
-    ws = (arg((E_, D_, F_), jnp.bfloat16),) * 2 + (arg((E_, F_, D_), jnp.bfloat16),)
+    ws = (arg((E_, D_, F_), jnp.bfloat16),) * (n_matrices - 1) + (arg((E_, F_, D_), jnp.bfloat16),)
 
     def both(x2, w_held, order, counts, ws, g):
         y, vjp = jax.vjp(lambda x, w, ws: _held_experts(x, w, order, counts, ws, tile), x2, w_held, ws)
         return y, vjp(g)
 
-    text = jax.jit(both).lower(
+    compiled = jax.jit(both).lower(
         arg((T_, D_), jnp.bfloat16), arg((E_, T_), jnp.float32), arg((E_, T_), jnp.int32), arg((E_,), jnp.int32), ws,
         arg((T_, D_), jnp.float32),
-    ).compile().as_text()
-    accumulator, in_loops = re.compile(rf"f32\[{T_},(?:1,)?{D_}\]"), _in_loop_bodies(text)
+    ).compile()
+    text = compiled.as_text()
+    in_loops = _in_loop_bodies(text)
+    accumulator = re.compile(rf"f32\[{T_},(?:1,)?{D_}\]")
     moved = [
         line.strip()[:160] for line in in_loops
         if accumulator.search(line) and re.search(r" (copy-start|slice-start|scatter)\(", line)
     ]
     assert moved == []
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    # the loops were read: they carry the accumulator, row by row
+    # the loops were read: they carry the accumulator, row by row, and the stacks of gradients
     assert any(re.search(rf"f32\[{T_},1,{D_}\]\S* custom-call\(", line) for line in in_loops)
+    stack = rf"(?:bf16|f32)\[{E_},(?:{D_},{F_}|{F_},{D_})\]"
+    made = [line for line in in_loops if re.match(rf"\s*(?:ROOT )?%\S+ = {stack}\S* (?!get-tuple-element|parameter|bitcast)", line)]
+    assert made and all(" custom-call(" in line and "grouped_matmul_t" in line for line in made), [m.strip()[:120] for m in made]
+    assert not [line for line in in_loops if re.search(rf"f32\[(?:{E_},)?(?:{D_},{F_}|{F_},{D_})\]", line)]  # no float32 copy of one either
+    # nothing as long as the worst case: T x (the most experts a token is on) rows, a row tile an expert more
+    longest = max(int(m) for line in text.splitlines() for m in re.findall(r"= (?:bf16|f32|s32)\[(\d+),(?:\d+)\]", line))
+    assert longest <= -(-(T_ + E_ * tile) // 1024) * 1024 < max(T_ * most, 2 * T_)
+    assert text.count('custom_call_target="tpu_custom_call"') > 10
+
+
+@pytest.mark.parametrize("cell,parents_gb", [("sdar30b.save", 13.58), ("zaya1_8b.save", 14.98)])
+def test_a_routed_cells_step_fits_the_chip_on_the_tpu_compiler(v5e_2x2, monkeypatch, request, cell, parents_gb):
+    """The train step of the two routed cells that fill the chip, at their
+    published batch and sequence, compiled for a described v5e: what
+    ``memory_analysis()`` plans is under the chip's 15.75 GB (the list's
+    chunks and the backward pass' slab buffers are 0.2 to 0.3 GB over the
+    parent's loops), and the step holds the grouped products."""
+    import sys
+
+    chip = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks", "chip")
+    monkeypatch.syspath_prepend(chip)
+    harness = lambda: [m for m in sys.modules if m == "lib" or m.startswith("lib.")]  # noqa: E731
+    for name in harness():
+        monkeypatch.delitem(sys.modules, name)
+    from lib import model as chip_model, spec
+
+    request.addfinalizer(lambda: [sys.modules.pop(name) for name in harness()])  # the harness' modules leave with the test
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = spec.resolve_cell(spec.load_benchmark(), cell).config
+    model = chip_model.Model(cfg, 0, v5e_2x2[:1], None)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    state = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), model.shapes)
+    tokens = jax.ShapeDtypeStruct((model.batch_size, model.seq), jnp.int32, sharding=one)
+    compiled = model._step.lower(state, {"tokens": tokens, "targets": tokens}).compile()
+    m = compiled.memory_analysis()
+    planned = m.argument_size_in_bytes + m.temp_size_in_bytes + max(0, m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert parents_gb * 1e9 - 0.1e9 < planned < 15.75e9 - 0.3e9, planned
+    text = compiled.as_text()
+    assert "grouped_matmul_t" in text and "add_rows" in text
 
 
 CP = {"data": 2, "seq": 2, "model": 2}

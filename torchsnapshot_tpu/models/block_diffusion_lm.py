@@ -59,7 +59,6 @@ runs this family).
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -69,7 +68,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..ops.attention import BlockDiffusionMask, causal_attention_route
-from ..ops.moe import held_tile_stats, softmax_topk_routed
+from ..ops.moe import held_row_tile, held_tile_stats, softmax_topk_routed
 from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
 
 Params = Dict[str, Any]
@@ -241,14 +240,13 @@ def _mm(x, w):
 
 
 def expert_tile(cfg: BlockDiffusionLMConfig, positions: int) -> int:
-    """Rows a held expert's loop multiplies a trip: the power of two that
-    holds twice the even load (``positions * top_k / n_experts``), 256 at
-    least. A tile the size of the even load itself would leave every expert
-    a coin's toss from a second, nearly empty trip, by the seed and by the
-    step; at twice it each takes one trip while its load stays under twice
-    its share (``routing_stats`` reads how far it is from that)."""
-    even = positions * cfg.top_k / cfg.n_experts
-    return max(256, 1 << math.ceil(math.log2(2 * even)))
+    """Rows of a row tile of the held experts' list (``ops/moe.py``
+    ``_held_experts``): what an expert's load is rounded up to, and the unit
+    ``routing_stats`` counts ``trips`` in. It follows from ``positions``
+    alone: the experts' widths choose the products' column blocks, not the
+    row tile."""
+    del cfg
+    return held_row_tile(positions)
 
 
 def attention_mask(cfg: BlockDiffusionLMConfig, S: int) -> BlockDiffusionMask:
@@ -316,7 +314,7 @@ def _run_layers(cparams: Params, tokens: jax.Array, masked: jax.Array, cfg: Bloc
             )
             h = cs(h + _mm(out.reshape(B, 2 * S, c.n_heads * c.head_dim), w["o"]), stream)
         b = _rmsnorm(h, w["ln2_scale"], c.norm_eps)
-        y, chosen = softmax_topk_routed(w, b, top_k=c.top_k, held=c.held, tile=expert_tile(c, B * 2 * S))
+        y, chosen = softmax_topk_routed(w, b, top_k=c.top_k, held=c.held)
         return cs(h + y, stream), chosen
 
     x = jnp.concatenate([tokens, jnp.where(masked, c.mask_id, tokens)], axis=1)
@@ -363,7 +361,7 @@ def routing_stats(params: Params, tokens: jax.Array, masked: jax.Array, cfg: Blo
         "held_counts": counts,
         "held_share": jnp.sum(counts, axis=1) / (chosen.shape[1] * chosen.shape[2]),
         "max_over_mean": jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts.astype(jnp.float32), axis=1), 1e-9),
-        **held_tile_stats(counts, chosen.shape[1], expert_tile(cfg, chosen.shape[1])),
+        **held_tile_stats(counts, chosen.shape[1]),
     }
 
 

@@ -70,7 +70,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import telemetry
 from ..ops.attention import causal_attention_route
 from ..ops.cca import cca_qkv
-from ..ops.moe import held_tile_stats, mlp_top1_routed
+from ..ops.moe import held_row_tile, held_tile_stats, mlp_top1_routed
 from .block_diffusion_lm import _constrainer, _mm, _rmsnorm  # the same float32 norm, matmul and constraint
 from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
 
@@ -238,10 +238,10 @@ def compute_params(params: Params, cfg: CCAMoELMConfig) -> Params:
 
 
 def expert_tile(cfg: CCAMoELMConfig, positions: int) -> int:
-    """Rows a held expert's loop multiplies a trip: the power of two that
-    holds twice the even load (``positions / n_experts``), 256 at least, by
-    ``block_diffusion_lm.expert_tile``'s rule and for its reason."""
-    return max(256, 1 << math.ceil(math.log2(2 * positions / cfg.n_experts)))
+    """Rows of a row tile of the held experts' list, as
+    ``block_diffusion_lm.expert_tile`` says."""
+    del cfg
+    return held_row_tile(positions)
 
 
 def _attention_route(cfg: CCAMoELMConfig, mesh: Optional[Mesh], B: int, S: int):
@@ -277,7 +277,7 @@ def layer(
         out = _mm(out.reshape(B, S, c.n_heads * c.head_dim), w["o"])
     x = cs(_res_scale(w, "attn", x, out), stream)
     b = _rmsnorm(x, w["ln2_scale"], c.norm_eps)
-    y, chosen, r = mlp_top1_routed(w, b, r, held=c.held, norm_eps=c.norm_eps, tile=expert_tile(c, B * S))
+    y, chosen, r = mlp_top1_routed(w, b, r, held=c.held, norm_eps=c.norm_eps)
     return cs(_res_scale(w, "moe", x, y), stream), cs(r, stream), chosen
 
 
@@ -335,7 +335,7 @@ def routing_stats(params: Params, tokens: jax.Array, cfg: CCAMoELMConfig) -> Dic
         "held_counts": counts,
         "held_share": jnp.sum(counts, axis=1) / chosen.shape[1],
         "max_over_mean": jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts.astype(jnp.float32), axis=1), 1e-9),
-        **held_tile_stats(counts, chosen.shape[1], expert_tile(cfg, chosen.shape[1])),
+        **held_tile_stats(counts, chosen.shape[1]),
     }
 
 

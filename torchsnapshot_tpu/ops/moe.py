@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .pallas_add_rows import add_rows
+from .pallas_grouped import grouped_matmul, grouped_matmul_t, uninitialised
 
 # Above this many elements in the (T, E, cap) dispatch tensor, "auto"
 # switches to the sort-based dispatch (2**22 f32 elements = 16 MB).
@@ -365,10 +366,10 @@ def softmax_topk_route(x2: jax.Array, router: jax.Array, top_k: int) -> Tuple[ja
 
 
 # An expert is its matrices, the last one down: two of them are
-# ``relu2_ffn``, three are ``gated_ffn``. What differs between the two in the
-# backward pass is the hidden activation from the products ``x w`` of the
-# matrices ahead of the last, and its derivative: per kind, ``hs -> (a, da ->
-# the cotangent of each h)``, all float32.
+# ``relu2_ffn``, three are ``gated_ffn``. What differs between the two is the
+# hidden activation from the products ``x w`` of the matrices ahead of the
+# last, and its derivative: per kind, ``hs -> (a, da -> the cotangent of each
+# h)``, all float32.
 
 
 def _relu2_hidden(hs):
@@ -383,7 +384,72 @@ def _gated_hidden(hs):
     return act * u, lambda da: (da * u * (sig + act * (1.0 - sig)), da * act)
 
 
-_EXPERTS = {2: (relu2_ffn, _relu2_hidden), 3: (gated_ffn, _gated_hidden)}
+_HIDDEN = {2: _relu2_hidden, 3: _gated_hidden}
+
+_ROW_TILE = 128  # rows of a row tile of the held experts' list: why, under sigmoid_topk_routed
+_CHUNK_TILES = 8  # row tiles gathered, multiplied and combined at a time
+
+
+def _cdiv(a, b):
+    return -(-a // b)
+
+
+def held_row_tile(T: int) -> int:
+    """Rows of a row tile of the list ``_held_experts`` makes of ``T``
+    tokens: one expert's rows, and the granule a load is rounded up to."""
+    return min(_ROW_TILE, T)
+
+
+def _tile_plan(counts, tile):
+    """Where each expert's segment lies in the list of row tiles: ``(start,
+    end)``, ``(n,)`` each, in tiles. An expert has the tiles that hold its
+    ``counts`` rows, and one where it has none: its zero gradient is written
+    from that tile like any other."""
+    tiles = jnp.maximum(_cdiv(counts, tile), 1)
+    end = jnp.cumsum(tiles)
+    return end - tiles, end
+
+
+def _tile_groups(plan, first, k):
+    """The ``k`` row tiles of the list from tile ``first`` on: their numbers
+    and each one's expert (the last expert's past the list's end)."""
+    _, end = plan
+    t = first + jnp.arange(k, dtype=jnp.int32)
+    return t, jnp.minimum(jnp.sum(end[None, :] <= t[:, None], axis=1, dtype=jnp.int32), end.shape[0] - 1)
+
+
+def _list_tiles(plan, counts, order, w_held, first, k, limit, tile):
+    """The ``k`` row tiles of the list from tile ``first`` on: ``group (k,)``,
+    each tile's expert; ``own (k,)``, how many of its leading rows are the
+    expert's own (0 for a tile from ``limit`` on: past the list, or left to
+    another pass); ``idx (k * tile,)``, each row's token, and ``w_rows``, its
+    weight. A tile's rows past the expert's own are tokens of others, as
+    ``order`` lists them behind the expert's own (the last token again where
+    T is no multiple of the tile): real rows, at weight 0."""
+    t, group = _tile_groups(plan, first, k)
+    row0 = (t - plan[0][group]) * tile
+    pos = row0[:, None] + jnp.arange(tile, dtype=jnp.int32)
+    mine = pos < counts[group][:, None]
+    idx = order[group[:, None], jnp.minimum(pos, order.shape[1] - 1)]
+    w_rows = jnp.where(mine, w_held[group[:, None], idx], 0.0)
+    own = jnp.where(t < limit, jnp.sum(mine, axis=1, dtype=jnp.int32), 0)
+    return group, own, idx.reshape(-1), w_rows.reshape(-1)
+
+
+def _lane_aligned(m):
+    """``(matrix, swapped)``: a stack of matrices whose rows are no multiple
+    of the chip's 128 lanes long (1856) but whose columns are (2688) is taken
+    through its transpose, which the kernels can cut blocks from and copy;
+    the products below read ``swapped`` and ask for the other form. Where
+    the compiler keeps such a stack columns-first already, as it does a
+    parameter of that shape, the transpose moves nothing."""
+    swapped = m.shape[2] % 128 != 0 and m.shape[1] % 128 == 0
+    return (jnp.swapaxes(m, 1, 2) if swapped else m), swapped
+
+
+def _product(x, matrix, group, live, transpose=False, **kwargs):
+    m, swapped = matrix
+    return grouped_matmul(x, m, group, live, transpose_rhs=transpose != swapped, **kwargs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -394,21 +460,28 @@ def _held_experts(x2, w_held, order, counts, ws, tile):
     ``gated_ffn``: what is given chooses the expert.
 
     ``order[e]`` lists the tokens, those of expert e first (``counts[e]`` of
-    them), and ``w_held[e]`` is 0 on everyone else. Each expert walks its
-    list in tiles of ``tile`` tokens and stops after the last tile that
-    holds one of its own (that tile's tail is computed at weight 0): rows
-    are gathered and multiplied a tile at a time, so a step costs what its
-    routing sends here and nothing of size ``E x T`` is ever held. The trip
-    counts are data, which reverse-mode autodiff cannot transpose, so the
-    backward pass is written out below with the same loops.
+    them), and ``w_held[e]`` is 0 on everyone else. The own rows of all
+    experts are **one list sorted by expert**, each expert's segment begun
+    on a row tile of ``tile`` rows and its last tile filled from ``order[e]``
+    (rows at weight 0), so a row tile is one expert's. The list is never
+    held whole: ``_CHUNK_TILES`` row tiles at a time are gathered, multiplied
+    by **grouped matrix products** (``pallas_grouped.py``: one product a
+    matrix over the chunk, each row tile against its expert's matrix, the
+    matrix fetched once while its tiles follow each other), passed through
+    the hidden activation and combined, and the walk stops after the chunk
+    that holds the list's last tile. A step costs what its routing sends
+    here, rounded up to a tile an expert and a chunk a pass, and nothing of
+    size ``E x T`` or ``T x top_k`` is ever held. The trip counts are data,
+    which reverse-mode autodiff cannot transpose, so the backward pass is
+    written out below over the same list.
 
-    A tile's own rows are added into the float32 accumulator (``y`` here,
+    A chunk's own rows are added into the float32 accumulator (``y`` here,
     ``dx`` backward) where it lies, by ``add_rows``
-    (``pallas_add_rows.py``): the loops carry it as ``(T, 1, D)``, the rows
-    past the expert's last are not touched, and one relayout to ``(T, D)``
-    follows the scan. XLA's scatter-add in that place copied the whole
-    accumulator into VMEM and back every trip (PERF.md, PR 35).
-    ``held_tile_stats`` counts the trips and how full their tiles are."""
+    (``pallas_add_rows.py``), a row tile after the other: a token that
+    several experts hold is in several tiles, never twice in one. The loops
+    carry the accumulator as ``(T, 1, D)`` and one relayout to ``(T, D)``
+    follows. ``held_tile_stats`` counts the row tiles and how full they
+    are."""
     return _held_experts_fwd(x2, w_held, order, counts, ws, tile)[0]
 
 
@@ -418,68 +491,90 @@ def _row_accumulator(x2):
 
 
 def _held_experts_fwd(x2, w_held, order, counts, ws, tile):
-    ffn = _EXPERTS[len(ws)][0]
+    hidden, (*ins, down), k = _HIDDEN[len(ws)], [_lane_aligned(m) for m in ws], _CHUNK_TILES
+    plan = _tile_plan(counts, tile)
+    total = plan[1][-1]
 
-    def one(y, args):
-        w_e, order_e, n, w = args
+    def chunk(c, y):
+        group, own, idx, w_rows = _list_tiles(plan, counts, order, w_held, c * k, k, total, tile)
+        live = jnp.minimum(total - c * k, k)
+        rows = x2[idx]
+        act, _ = hidden([_product(rows, m, group, live) for m in ins])
+        o = _product(act.astype(x2.dtype), down, group, live, row_scale=w_rows)
+        return add_rows(y, idx, o, own)
 
-        def body(j, y):
-            idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
-            o = ffn(x2[idx], *w)
-            return add_rows(y, idx, w_e[idx][:, None] * o, jnp.minimum(n - j * tile, tile))
-
-        return jax.lax.fori_loop(0, (n + tile - 1) // tile, body, y), None
-
-    y, _ = jax.lax.scan(one, _row_accumulator(x2), (w_held, order, counts, ws))
+    y = jax.lax.fori_loop(0, _cdiv(total, k), chunk, _row_accumulator(x2))
     return y.reshape(x2.shape), (x2, w_held, order, counts, ws)
 
 
 def _held_experts_bwd(tile, res, g):
+    """The list again, in slabs of whole experts: a weight gradient is one
+    transposed grouped product over all of an expert's rows, which adds its
+    row tiles' products in VMEM and writes the expert's ``(D, F)`` once, in
+    the weights' dtype. So a slab's chunks leave their operands of those
+    products (the rows, the hidden activation, the two or three cotangents,
+    in the weights' dtype) in buffers a slab long, and the transposed
+    products follow the slab's last chunk. A slab is as many row tiles as
+    the largest expert possible (all T tokens) and a tile an expert more,
+    rounded up to chunks: the list of an even load is one slab, and of any
+    load at most one an expert."""
     x2, w_held, order, counts, ws = res
-    f32, T = jnp.float32, x2.shape[0]
-    hidden = _EXPERTS[len(ws)][1]
-    contract0 = (((0,), (0,)), ((), ()))  # a^T b
-    contract1 = (((1,), (1,)), ((), ()))  # a b^T
+    f32, (T, D), n = jnp.float32, x2.shape, counts.shape[0]
+    hidden, (*ins, down), k = _HIDDEN[len(ws)], [_lane_aligned(m) for m in ws], _CHUNK_TILES
+    F = ws[-1].shape[1]
+    plan = start, end = _tile_plan(counts, tile)
+    slab = _cdiv(_cdiv(T, tile) + n, k) * k  # tiles
 
-    def one(dx, args):
-        w_e, order_e, n, w = args
-        ins, down = w[:-1], w[-1]
+    def one_slab(carry):
+        e0, dx, dw, stacks = carry
+        t0 = start[e0]
+        e1 = jnp.sum(end <= t0 + slab, dtype=jnp.int32)  # experts [e0, e1) lie whole in this slab's tiles
+        limit = end[e1 - 1]
 
-        def body(j, carry):
-            dx, dw, dins, ddown = carry
-            idx = jax.lax.dynamic_slice_in_dim(order_e, j * tile, tile)
+        def chunk(c, carry):
+            dx, dw, kept = carry
+            first = t0 + c * k
+            group, own, idx, w_rows = _list_tiles(plan, counts, order, w_held, first, k, limit, tile)
+            live = jnp.minimum(limit - first, k)
+            product = functools.partial(_product, group=group, live=live)
             rows, go = x2[idx], g[idx]
-            act, d_hidden = hidden([jnp.matmul(rows, m, preferred_element_type=f32) for m in ins])
-            a = act.astype(down.dtype)
-            o = jnp.matmul(a, down, preferred_element_type=f32)
-            dw = dw.at[idx].set(jnp.sum(o * go, axis=-1), unique_indices=True)
-            do = (w_e[idx][:, None] * go).astype(down.dtype)
-            ddown = ddown + jax.lax.dot_general(a, do, contract0, preferred_element_type=f32)
-            dhs = d_hidden(jax.lax.dot_general(do, down, contract1, preferred_element_type=f32))
-            dhs = [dh.astype(m.dtype) for dh, m in zip(dhs, ins)]
-            dins = tuple(
-                acc + jax.lax.dot_general(rows, dh, contract0, preferred_element_type=f32)
-                for acc, dh in zip(dins, dhs)
+            act, d_hidden = hidden([product(rows, m) for m in ins])
+            a = act.astype(x2.dtype)
+            o = product(a, down)
+            # dw only where an expert's own rows are: the rows past them are tokens of others
+            mine = (jnp.arange(tile, dtype=jnp.int32) < own[:, None]).reshape(-1)
+            at = jnp.where(mine, jnp.repeat(group, tile, total_repeat_length=k * tile) * T + idx, n * T + jnp.arange(k * tile))
+            dw = dw.at[at].set(jnp.sum(o * go, axis=-1), mode="drop", unique_indices=True)
+            do = (w_rows[:, None] * go).astype(x2.dtype)
+            dhs = [dh.astype(x2.dtype) for dh in d_hidden(product(do, down, transpose=True))]
+            drows = functools.reduce(operator.add, [product(dh, m, transpose=True) for dh, m in zip(dhs, ins)])
+            kept = tuple(
+                jax.lax.dynamic_update_slice_in_dim(buf, new, c * k * tile, 0) for buf, new in zip(kept, (rows, a, do, *dhs))
             )
-            drows = functools.reduce(
-                operator.add,
-                [jax.lax.dot_general(dh, m, contract1, preferred_element_type=f32) for dh, m in zip(dhs, ins)],
-            )
-            return add_rows(dx, idx, drows, jnp.minimum(n - j * tile, tile)), dw, dins, ddown
+            return add_rows(dx, idx, drows, own), dw, kept
 
-        init = (dx, jnp.zeros((T,), f32), tuple(jnp.zeros(m.shape, f32) for m in ins), jnp.zeros(down.shape, f32))
-        dx, dw, dins, ddown = jax.lax.fori_loop(0, (n + tile - 1) // tile, body, init)
-        return dx, (dw, tuple(d.astype(m.dtype) for d, m in zip(dins, ins)) + (ddown.astype(down.dtype),))
+        kept = tuple(uninitialised((slab * tile, width), x2.dtype) for width in (D, F, D) + (F,) * len(ins))
+        dx, dw, (rows, a, do, *dhs) = jax.lax.fori_loop(0, _cdiv(limit - t0, k), chunk, (dx, dw, kept))
+        group = _tile_groups(plan, t0, slab)[1]
+        stacks = tuple(
+            grouped_matmul_t(*((rhs, lhs) if swapped else (lhs, rhs)), group, limit - t0, stack)
+            for lhs, rhs, (_, swapped), stack in zip((rows,) * len(ins) + (a,), (*dhs, do), (*ins, down), stacks)
+        )
+        return e1, dx, dw, stacks
 
-    dx, (dw, dws) = jax.lax.scan(one, _row_accumulator(x2), (w_held, order, counts, ws))
+    stacks = tuple(uninitialised(m.shape, m.dtype) for m, _ in (*ins, down))
+    _, dx, dw, dws = jax.lax.while_loop(
+        lambda carry: carry[0] < n, one_slab, (jnp.int32(0), _row_accumulator(x2), jnp.zeros((n * T,), f32), stacks)
+    )
+    dws = tuple(jnp.swapaxes(d, 1, 2) if swapped else d for d, (_, swapped) in zip(dws, (*ins, down)))
     none = lambda a: np.zeros(a.shape, jax.dtypes.float0)  # noqa: E731
-    return dx.reshape(x2.shape).astype(x2.dtype), dw, none(order), none(counts), dws
+    return dx.reshape(x2.shape).astype(x2.dtype), dw.reshape(n, T), none(order), none(counts), dws
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Array, ...], tile: int):
+def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Array, ...]):
     """What the experts held here add for ``x: (..., D)``: (float32 of the
     same shape, the chosen ids ``(T, k)``). ``route(x2) -> (ids, weights)``
     scores and chooses over all experts; ``ws`` are the held experts'
@@ -498,24 +593,20 @@ def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Ar
         # Each expert's list of all T tokens, its own first, in token order.
         order = jnp.argsort(~member, axis=-1, stable=True).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
-        y = _held_experts(
-            x2.astype(ws[0].dtype), w_held, order, counts, ws, math.gcd(T, tile),  # tiles cover the list exactly
-        )
+        y = _held_experts(x2.astype(ws[0].dtype), w_held, order, counts, ws, held_row_tile(T))
     return y.reshape(orig_shape), ids
 
 
-_TILE = 512  # rows a held expert multiplies a trip: why, under sigmoid_topk_routed
-
-
-def held_tile_stats(held_counts: jax.Array, T: int, tile: int = _TILE) -> Dict[str, jax.Array]:
-    """What ``_held_experts``' loops make of ``held_counts`` ``(..., n)``,
-    each held expert's own rows among ``T``, at the ``tile`` its layer was
-    given: ``trips``, the tiles walked in a pass over the layer, and
-    ``tile_fill``, own rows over the rows of those tiles: the share of a
-    tile's rows that ``add_rows`` adds and whose products are not padding."""
-    tile = math.gcd(T, tile)
-    trips = jnp.sum((held_counts + tile - 1) // tile, axis=-1)
-    return {"trips": trips, "tile_fill": jnp.sum(held_counts, axis=-1) / jnp.maximum(trips * tile, 1)}
+def held_tile_stats(held_counts: jax.Array, T: int) -> Dict[str, jax.Array]:
+    """What ``_held_experts``' list makes of ``held_counts`` ``(..., n)``,
+    each held expert's own rows among ``T``: ``trips``, the row tiles
+    (``held_row_tile(T)`` rows) the grouped products multiply in a pass over
+    the layer, an expert's own and no other's, one for an expert of no rows;
+    and ``tile_fill``, own rows over the rows of those tiles: the share of
+    the multiplied rows that is not padding."""
+    tile = held_row_tile(T)
+    trips = jnp.sum(jnp.maximum(_cdiv(held_counts, tile), 1), axis=-1)
+    return {"trips": trips, "tile_fill": jnp.sum(held_counts, axis=-1) / (trips * tile)}
 
 
 def sigmoid_topk_routed(
@@ -525,7 +616,6 @@ def sigmoid_topk_routed(
     top_k: int,
     held: Tuple[int, ...],
     routed_scale: float,
-    tile: int = _TILE,
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed part of a sigmoid top-k expert layer, for the experts held
     here: ``x: (..., D)`` -> (float32 of the same shape, the chosen ids
@@ -541,43 +631,48 @@ def sigmoid_topk_routed(
 
     **No token is dropped and every shape is static**: each held expert has
     a list of all T tokens with its own sorted to the front (a token
-    chooses an expert at most once) and computes the tiles of it that hold
-    some (``_held_experts``). A layer that nobody chooses costs its routing;
-    one that everybody chooses costs ``min(top_k, n) * T`` rows. ``tile``
-    rows are multiplied at a time, the last tile of an expert part-filled:
-    512 because the MXU's time for the padding is cheap beside what every
-    tile pays whatever its size, and because a step then costs the same
-    for any load up to 512 tokens an expert (256 and 1024 were measured on
-    the chip: PERF.md, PR 32). What a tile pays whatever its size, since
-    PR 35: the gathers of its rows, the slices of the expert's matrices,
-    two or three float32 weight-gradient accumulators zero-filled, read
-    and written, and ``add_rows``' pass over the tile's products (11 us
-    for 8 MB); what it pays by its own rows: two 8 KB DMAs each, 30 ns a
-    row. The ``(T, D)`` accumulator itself is no longer moved: XLA's
-    scatter-add copied all of it into VMEM and out again every trip, or
-    ran in HBM at 310 ns a row (PERF.md, PR 35).
+    chooses an expert at most once), and the own rows of all held experts
+    are walked as one list sorted by expert, a chunk of row tiles at a time
+    (``_held_experts``). A layer that nobody chooses costs its routing and
+    a row tile an expert; one that everybody chooses costs ``min(top_k, n)
+    * T`` rows. What a row tile pays: an expert's load is rounded up to row
+    tiles of 128 rows (``held_row_tile``), so at 512 tokens an expert a
+    tenth of the multiplied rows is padding (``held_tile_stats``: 0.84 to
+    0.94 full by layer on the chip, where the loops' tiles of 1024 rows
+    were 0.44 to 0.53 full: PERF.md, PR 39); a row tile costs its 128 rows
+    against the expert's matrix, and an expert's matrix is fetched once a
+    product and chunk however many of its row tiles follow each other. 256
+    rows a tile padded a fifth more rows and took the same time. What a
+    chunk (eight row tiles) pays
+    whatever its load: two gathers of scalars (the tokens' weights, 8 to 10
+    us) and a kernel launch a product; what it pays by its own rows: the
+    gathers of the rows, ``add_rows``' two 8 KB DMAs a row, 30 ns, and its
+    pass over the chunk's products (11 us for 8 MB). The weight gradients
+    are written once an expert, in the weights' dtype, from float32 blocks
+    in VMEM: nothing of an expert's shape is zero-filled, sliced or updated
+    in place (PERF.md, PR 39).
     """
     return _routed_to_held(
         x,
         lambda x2: sigmoid_topk_route(x2, params["router"], params["router_bias"], top_k, routed_scale),
-        held, (params["expert_up"], params["expert_down"]), tile,
+        held, (params["expert_up"], params["expert_down"]),
     )
 
 
 def softmax_topk_routed(
-    params: Dict[str, Any], x: jax.Array, *, top_k: int, held: Tuple[int, ...], tile: int = _TILE
+    params: Dict[str, Any], x: jax.Array, *, top_k: int, held: Tuple[int, ...]
 ) -> Tuple[jax.Array, jax.Array]:
     """The softmax sibling, with gated experts: ``router (D, E)`` over all
     E, ``expert_gate`` and ``expert_up (n, D, F)``, ``expert_down (n, F,
     D)`` of the held ones. Scores are ``softmax`` over all E, the weights
     the ``top_k`` chosen probabilities over their sum, the result ``sum of
     w_i (silu(x G_i) * (x U_i)) D_i`` over the chosen experts held here;
-    dropless and tiled as ``sigmoid_topk_routed`` says, and a tile pays
-    what it says there, with three weight-gradient accumulators for two."""
+    dropless, and one list of rows sorted by expert as ``sigmoid_topk_routed``
+    says, with three grouped products a chunk for two."""
     return _routed_to_held(
         x,
         lambda x2: softmax_topk_route(x2, params["router"], top_k),
-        held, (params["expert_gate"], params["expert_up"], params["expert_down"]), tile,
+        held, (params["expert_gate"], params["expert_up"], params["expert_down"]),
     )
 
 
@@ -619,7 +714,7 @@ def mlp_top1_route(
 
 
 def mlp_top1_routed(
-    params: Dict[str, Any], x: jax.Array, r_prev: jax.Array, *, held: Tuple[int, ...], norm_eps: float, tile: int = _TILE
+    params: Dict[str, Any], x: jax.Array, r_prev: jax.Array, *, held: Tuple[int, ...], norm_eps: float
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The routed part of a top-1 layer whose router is ``mlp_top1_route``,
     for the gated experts held here: ``x: (..., D)`` and ``r_prev: (...,
@@ -628,8 +723,9 @@ def mlp_top1_routed(
     and ``expert_gate`` / ``expert_up (n, D, F)``, ``expert_down (n, F, D)``
     of the held experts. The result is ``w (silu(x G_e) * (x U_e)) D_e`` for
     a token whose expert e is held here and 0 for every other token;
-    dropless and tiled as ``sigmoid_topk_routed`` says. Every chip that
-    shares the layer computes the same route and the same ``r``."""
+    dropless, and one list of rows sorted by expert as ``sigmoid_topk_routed``
+    says. Every chip that shares the layer computes the same route and the
+    same ``r``."""
     router = {k[len("router_"):]: v for k, v in params.items() if k.startswith("router_")}
     carried = []
 
@@ -638,5 +734,5 @@ def mlp_top1_routed(
         carried.append(r)
         return ids, weights
 
-    y, ids = _routed_to_held(x, route, held, (params["expert_gate"], params["expert_up"], params["expert_down"]), tile)
+    y, ids = _routed_to_held(x, route, held, (params["expert_gate"], params["expert_up"], params["expert_down"]))
     return y, ids, carried[0].reshape(r_prev.shape)

@@ -40,17 +40,19 @@ _SLOTS = 2
 
 
 def _kernel(idx_ref, n_ref, acc_in, rows_ref, acc_out, buf, read_sem, write_sem, *, group):
-    """One group of ``group`` rows a grid step. ``acc_in`` is ``acc_out``
-    (aliased): every DMA names ``acc_out``. Group g owns slot ``g % 2`` of
-    ``buf`` from the start of its reads (a step early) to the end of its
-    writes (waited for a step late, before group g + 2's reads, or at the
-    last step). A group past the expert's last row moves and adds nothing."""
+    """One group of ``group`` rows a grid step, tile by tile (the grid's first
+    dimension). ``acc_in`` is ``acc_out`` (aliased): every DMA names
+    ``acc_out``. Group g owns slot ``g % 2`` of ``buf`` from the start of
+    its reads (a step early) to the end of its writes (waited for a step
+    late, before group g + 2's reads, or at the tile's last step: a tile
+    leaves no copy in flight). A group past the tile's last own row moves
+    and adds nothing."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     del acc_in
-    g, last = pl.program_id(0), pl.num_programs(0) - 1
-    n_own = n_ref[0]
+    g, last = pl.program_id(1), pl.num_programs(1) - 1
+    n_own, base = n_ref[pl.program_id(0)], pl.program_id(0) * (pl.num_programs(1) * group)
 
     def own(grp):  # own rows of group grp: 0 past the expert's last row
         return jnp.clip(n_own - grp * group, 0, group)
@@ -62,7 +64,7 @@ def _kernel(idx_ref, n_ref, acc_in, rows_ref, acc_out, buf, read_sem, write_sem,
         sem = (write_sem if back else read_sem).at[slot]
 
         def one(r, _):
-            row = acc_out.at[idx_ref[grp * group + r]]
+            row = acc_out.at[idx_ref[base + grp * group + r]]
             src, dst = (buf.at[slot, r], row) if back else (row, buf.at[slot, r])
             copy = pltpu.make_async_copy(src, dst, sem)
             copy.start() if start else copy.wait()
@@ -112,29 +114,44 @@ def _kernel(idx_ref, n_ref, acc_in, rows_ref, acc_out, buf, read_sem, write_sem,
 
 
 def add_rows(acc: jax.Array, idx: jax.Array, rows: jax.Array, n_own: jax.Array) -> jax.Array:
-    """``acc.at[idx[:n_own], 0].add(rows[:n_own])``, in place.
+    """``acc.at[idx[:n_own], 0].add(rows[:n_own])``, in place, a tile of the
+    rows at a time: tile t is ``idx`` and ``rows`` from ``t * tile`` on,
+    ``tile = len(idx) / len(n_own)``, and ``n_own[t]`` of its leading rows
+    are added.
 
-    ``acc: (T, 1, D)`` float32, donated to the result; ``idx: (tile,)``
-    int32, unique and in ``[0, T)``; ``rows: (tile, D)`` float32; ``n_own``:
-    an int32 scalar, how many leading rows are added. The rows past
-    ``n_own`` are neither read nor written, and their groups not fetched."""
+    ``acc: (T, 1, D)`` float32, donated to the result; ``idx: (S,)`` int32 in
+    ``[0, T)``, **unique within a tile's own rows**; ``rows: (S, D)``
+    float32; ``n_own``: int32, a scalar (one tile) or ``(tiles,)``. The rows
+    past a tile's ``n_own`` are neither read nor written, and their groups
+    not fetched. A tile's last writes have landed before the next tile's
+    first read, so two tiles may hold the same row of ``acc`` (a token that
+    two experts hold)."""
+    n_own = jnp.reshape(n_own, (-1,)).astype(jnp.int32)
+    return _add_rows(acc, idx, rows, n_own, interpret=jax.default_backend() != "tpu")
+
+
+# Jitted, so that the calls of one step (three loops a layer) are lowered to
+# Mosaic once: ``pallas_grouped.py`` says why.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _add_rows(acc, idx, rows, n_own, *, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    D, tile = acc.shape[-1], idx.shape[0]
+    D, tiles = acc.shape[-1], n_own.shape[0]
+    tile = idx.shape[0] // tiles
     group = math.gcd(tile, _GROUP)  # groups cover the tile exactly
 
-    def rows_block(g, idx_ref, n_ref):
+    def rows_block(t, g, idx_ref, n_ref):
         # Past the last group that holds own rows the block stays where it
         # is, and the pipeline does not fetch it again.
-        return jnp.minimum(g, jnp.maximum(n_ref[0] - 1, 0) // group), 0
+        return t * (tile // group) + jnp.minimum(g, jnp.maximum(n_ref[t] - 1, 0) // group), 0
 
     return pl.pallas_call(
         functools.partial(_kernel, group=group),
         out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(tile // group,),
+            grid=(tiles, tile // group),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec((group, D), rows_block)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
@@ -144,8 +161,7 @@ def add_rows(acc: jax.Array, idx: jax.Array, rows: jax.Array, n_own: jax.Array) 
             ],
         ),
         input_output_aliases={2: 0},  # acc, after the two prefetched scalars
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=jax.default_backend() != "tpu",
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
         name="add_rows",
-    )(idx, jnp.reshape(n_own, (1,)).astype(jnp.int32), acc, rows)
-
+    )(idx, n_own, acc, rows)
