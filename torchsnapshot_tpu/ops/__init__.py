@@ -21,7 +21,9 @@ state to snapshot:
   through two causal convolutions, a mean shared between them, an L2 norm
   and a temperature, v shifted by a position for half its heads;
 - selective-SSM sequence mixing via associative scan, with a
-  sequence-parallel cross-chunk carry.
+  sequence-parallel cross-chunk carry;
+- Kimi Delta Attention (``kda``): a gated delta rule with a decay a key
+  channel, position by position and in chunks in the WY form, float32.
 """
 
 from .attention import blockwise_attention, dense_attention

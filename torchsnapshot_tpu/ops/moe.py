@@ -337,18 +337,18 @@ def gated_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Arra
 
 
 def sigmoid_topk_route(
-    x2: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, scale: float
+    x2: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, scale: float, n_group: int = 1, topk_group: int = 1
 ) -> Tuple[jax.Array, jax.Array]:
     """``(T, D)`` tokens -> the ids ``(T, k)`` of the ``top_k`` experts with
     the largest ``sigmoid(x router) + bias`` and their weights ``(T, k)``:
     ``scale * s_i / (sum of the chosen s + 1e-20)``, from ``s`` and not from
     ``s + bias``. The bias only selects and takes no gradient. All of it in
     float32, the product at full precision (a bfloat16 pass moves scores by
-    1e-2, which is the distance between neighbours among 128 of them)."""
+    1e-2, which is the distance between neighbours among 128 of them). ``n_group`` > 1: ``group_limited``."""
     f32 = jnp.float32
     logits = jnp.matmul(x2.astype(f32), router.astype(f32), precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
-    _, ids = jax.lax.top_k(s + jax.lax.stop_gradient(bias.astype(f32)), top_k)
+    _, ids = jax.lax.top_k(group_limited(s + jax.lax.stop_gradient(bias.astype(f32)), n_group, topk_group), top_k)
     chosen = jnp.take_along_axis(s, ids, axis=-1)
     return ids, scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
@@ -452,12 +452,12 @@ def _product(x, matrix, group, live, transpose=False, **kwargs):
     return grouped_matmul(x, m, group, live, transpose_rhs=transpose != swapped, **kwargs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _held_experts(x2, w_held, order, counts, ws, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_experts(x2, w_held, order, counts, ws, tile, limit=0.0):
     """``sum_e w_held[e, t] f(x2[t]; ws[..][e])`` over the tokens that chose
     expert e: ``(T, D) -> (T, D)`` float32. ``ws`` are the experts' stacked
     matrices, ``(up, down)`` for ``relu2_ffn`` or ``(gate, up, down)`` for
-    ``gated_ffn``: what is given chooses the expert.
+    ``gated_ffn``: what is given chooses the expert (``limit``: its clamp).
 
     ``order[e]`` lists the tokens, those of expert e first (``counts[e]`` of
     them), and ``w_held[e]`` is 0 on everyone else. The own rows of all
@@ -482,7 +482,7 @@ def _held_experts(x2, w_held, order, counts, ws, tile):
     carry the accumulator as ``(T, 1, D)`` and one relayout to ``(T, D)``
     follows. ``held_tile_stats`` counts the row tiles and how full they
     are."""
-    return _held_experts_fwd(x2, w_held, order, counts, ws, tile)[0]
+    return _held_experts_fwd(x2, w_held, order, counts, ws, tile, limit)[0]
 
 
 def _row_accumulator(x2):
@@ -490,8 +490,8 @@ def _row_accumulator(x2):
     return jnp.zeros((x2.shape[0], 1, x2.shape[1]), jnp.float32)
 
 
-def _held_experts_fwd(x2, w_held, order, counts, ws, tile):
-    hidden, (*ins, down), k = _HIDDEN[len(ws)], [_lane_aligned(m) for m in ws], _CHUNK_TILES
+def _held_experts_fwd(x2, w_held, order, counts, ws, tile, limit=0.0):
+    hidden, (*ins, down), k = _expert_hidden(ws, limit), [_lane_aligned(m) for m in ws], _CHUNK_TILES
     plan = _tile_plan(counts, tile)
     total = plan[1][-1]
 
@@ -507,7 +507,7 @@ def _held_experts_fwd(x2, w_held, order, counts, ws, tile):
     return y.reshape(x2.shape), (x2, w_held, order, counts, ws)
 
 
-def _held_experts_bwd(tile, res, g):
+def _held_experts_bwd(tile, limit, res, g):
     """The list again, in slabs of whole experts: a weight gradient is one
     transposed grouped product over all of an expert's rows, which adds its
     row tiles' products in VMEM and writes the expert's ``(D, F)`` once, in
@@ -520,7 +520,7 @@ def _held_experts_bwd(tile, res, g):
     load at most one an expert."""
     x2, w_held, order, counts, ws = res
     f32, (T, D), n = jnp.float32, x2.shape, counts.shape[0]
-    hidden, (*ins, down), k = _HIDDEN[len(ws)], [_lane_aligned(m) for m in ws], _CHUNK_TILES
+    hidden, (*ins, down), k = _expert_hidden(ws, limit), [_lane_aligned(m) for m in ws], _CHUNK_TILES
     F = ws[-1].shape[1]
     plan = start, end = _tile_plan(counts, tile)
     slab = _cdiv(_cdiv(T, tile) + n, k) * k  # tiles
@@ -574,7 +574,7 @@ def _held_experts_bwd(tile, res, g):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Array, ...]):
+def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Array, ...], limit: float = 0.0):
     """What the experts held here add for ``x: (..., D)``: (float32 of the
     same shape, the chosen ids ``(T, k)``). ``route(x2) -> (ids, weights)``
     scores and chooses over all experts; ``ws`` are the held experts'
@@ -593,7 +593,7 @@ def _routed_to_held(x: jax.Array, route, held: Tuple[int, ...], ws: Tuple[jax.Ar
         # Each expert's list of all T tokens, its own first, in token order.
         order = jnp.argsort(~member, axis=-1, stable=True).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
-        y = _held_experts(x2.astype(ws[0].dtype), w_held, order, counts, ws, held_row_tile(T))
+        y = _held_experts(x2.astype(ws[0].dtype), w_held, order, counts, ws, held_row_tile(T), limit)
     return y.reshape(orig_shape), ids
 
 
@@ -736,3 +736,90 @@ def mlp_top1_routed(
 
     y, ids = _routed_to_held(x, route, held, (params["expert_gate"], params["expert_up"], params["expert_down"]))
     return y, ids, carried[0].reshape(r_prev.shape)
+
+
+# ------------------------------- group-limited selection and clamped gated experts (both off by default)
+
+
+def group_limited(scores: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """Selection scores ``(T, E)`` with every expert outside a token's
+    ``topk_group`` best groups set to ``-inf``: the E experts are ``n_group``
+    groups of ``E / n_group`` in id order, a group scores the sum of its two
+    best, and the ``topk_group`` best groups are kept (DeepSeek-V3's
+    ``noaux_tc``, arXiv:2412.19437, from memory). ``n_group`` 1 hands
+    ``scores`` back as they are, so ``sigmoid_topk_route`` lowers as it
+    did before the argument existed."""
+    if n_group == 1:
+        return scores
+    T, E = scores.shape
+    if E % n_group or not 0 < topk_group <= n_group:
+        raise ValueError(f"{E} experts in {n_group} groups, {topk_group} of them kept")
+    with jax.named_scope("group_select"):
+        grouped = scores.reshape(T, n_group, E // n_group)
+        _, keep = jax.lax.top_k(jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1), topk_group)  # (T, topk_group)
+        kept = jnp.any(jnp.arange(n_group)[None, :, None] == keep[:, None, :], axis=-1)  # (T, n_group)
+        return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(T, E)
+
+
+def _clamped_gated_hidden(hs, limit):
+    """``silu(min(g, l)) * clip(u, -l, l)``, the gated hidden with its
+    products clamped at ``l``, and its derivative: nothing reaches a product
+    past its clamp."""
+    g, u = hs
+    gc, uc = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+    sig = jax.nn.sigmoid(gc)
+    act = gc * sig  # silu(min(g, l))
+    d_act = jnp.where(g < limit, sig + act * (1.0 - sig), 0.0)
+    return act * uc, lambda da: (da * uc * d_act, jnp.where(jnp.abs(u) < limit, da * act, 0.0))
+
+
+def _expert_hidden(ws, limit):
+    """The hidden activation of an expert of ``ws``' matrices, clamped at
+    ``limit`` where it is not 0."""
+    if not limit:
+        return _HIDDEN[len(ws)]
+    if len(ws) != 3:
+        raise ValueError("only the gated (three-matrix) expert takes a clamp")
+    return functools.partial(_clamped_gated_hidden, limit=float(limit))
+
+
+def clamped_gated_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, limit: float) -> jax.Array:
+    """``gated_ffn`` with its two products clamped at ``limit`` as
+    ``_clamped_gated_hidden`` says; ``limit`` 0 is ``gated_ffn`` itself."""
+    if not limit:
+        return gated_ffn(x, w_gate, w_up, w_down)
+    x = x.astype(w_gate.dtype)
+    g = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+    a, _ = _clamped_gated_hidden((g, u), limit)
+    return jnp.matmul(a.astype(w_down.dtype), w_down, preferred_element_type=jnp.float32)
+
+
+def sigmoid_group_routed(
+    params: Dict[str, Any],
+    x: jax.Array,
+    *,
+    top_k: int,
+    held: Tuple[int, ...],
+    routed_scale: float,
+    n_group: int,
+    topk_group: int,
+    limit: float = 0.0,
+) -> Tuple[jax.Array, jax.Array]:
+    """``sigmoid_topk_routed`` with gated experts, a group-limited choice
+    and an optional clamp: ``router (D, E)`` and ``router_bias (E,)`` over
+    all E, ``expert_gate`` / ``expert_up (n, D, F)``, ``expert_down (n, F,
+    D)`` of the held ones. Every token keeps its ``topk_group`` best of
+    ``n_group`` groups by ``s + bias`` (``group_limited``) and takes the
+    ``top_k`` best experts among them; the weights are ``routed_scale *
+    s_i / sum of the chosen s``, the result ``sum of w_i E_i(x)`` over the
+    chosen experts held here, an expert ``(silu(min(x G_i, l)) * clip(x U_i,
+    -l, l)) D_i`` (``limit`` l 0: no clamp). Dropless, one list of rows
+    sorted by expert (``_held_experts``)."""
+    return _routed_to_held(
+        x,
+        lambda x2: sigmoid_topk_route(
+            x2, params["router"], params["router_bias"], top_k, routed_scale, n_group, topk_group
+        ),
+        held, (params["expert_gate"], params["expert_up"], params["expert_down"]), limit,
+    )
