@@ -506,6 +506,54 @@ def test_the_blocked_head_and_kda_head_blocks_are_the_whole(head_block, monkeypa
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
 
 
+def _objective_value_and_grad(cfg):
+    """The objective's value and gradient at the tests' batch, and the tree
+    it takes. A new function each call: jit would reuse the trace of one it
+    has seen, whatever ``KDA_LAYER_POLICY`` says now."""
+    batch = _batch()
+    return jax.value_and_grad(lambda p: M._objective(p, batch, cfg, None)), M.compute_params(_params(cfg), cfg)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_keeping_the_kda_head_blocks_output_is_the_same_arithmetic(dtype, monkeypatch):
+    """A KDA layer's checkpoint keeps ``kda_heads`` in place of running the
+    head blocks forward again: the loss and every gradient leaf are the
+    ones of the layer checkpoints that keep nothing, bit for bit."""
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    f, params = _objective_value_and_grad(cfg)
+    loss, grads = jax.jit(f)(params)
+    monkeypatch.setattr(M, "KDA_LAYER_POLICY", jax.checkpoint_policies.nothing_saveable)
+    f, params = _objective_value_and_grad(cfg)
+    want_loss, want = jax.jit(f)(params)
+    assert float(loss) == float(want_loss)
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path))
+
+
+def _eqns_named(jaxpr, name):
+    """Equations of primitive ``name`` in ``jaxpr`` and every jaxpr inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _eqns_named(sub, name)
+    return n
+
+
+def test_the_kda_chunk_algebra_runs_forward_twice_a_step():
+    """Two solves a chunk algebra's forward, four in its backward: in each
+    of ``CFG``'s three KDA layer bodies (the dense layer, the scan's body,
+    the last layer) a forward, the head blocks' recomputation and their
+    backward are 8 solves; a third forward, in the layer's recomputation,
+    would make 10 and the whole 30."""
+    f, params = _objective_value_and_grad(CFG)
+    solves = _eqns_named(jax.make_jaxpr(f)(params).jaxpr, "triangular_solve")
+    assert solves == 24, solves
+
+
 def test_routing_stats_count_what_the_routers_chose():
     params, toks = _params(CFG), _batch()["tokens"]
     stats = M.routing_stats(params, toks, CFG)
@@ -560,6 +608,20 @@ def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
         telemetry.set_enabled(False)
     assert gauges["kda_mla_lm.layers"] == 6 and gauges["kda_mla_lm.experts_held"] == 4
     assert gauges["kda_mla_lm.matmul_params_per_token"] == CFG.matmul_params_per_token
+
+
+def test_the_traced_step_reports_the_bytes_the_kda_checkpoints_keep():
+    """Each of the five KDA layers keeps its head blocks' output, ``(B, S,
+    H d)`` in bfloat16, across its checkpoint."""
+    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
+    state = jax.eval_shape(lambda k: M.init_state(k, cfg, tx), jax.random.PRNGKey(0))
+    telemetry.set_enabled(True)
+    try:
+        jax.eval_shape(M.make_train_step(cfg, tx), state, jax.eval_shape(_batch))
+        gauges = telemetry.gauges()
+    finally:
+        telemetry.set_enabled(False)
+    assert gauges["kda_mla_lm.saved_heads_bytes"] == B * S * CFG.n_heads * CFG.head_dim * 2 * 5
 
 
 def test_the_named_scopes_reach_the_lowered_step():

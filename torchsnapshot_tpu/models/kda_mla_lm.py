@@ -48,7 +48,11 @@ How it is compiled: the layers of one kind (mixer and MLP) are **stacked**
 in one subtree, ``kda_dense``, ``kda_moe``, ``mla_moe``, and run in the
 published order: a run of consecutive layers of one kind (and one clamp)
 is a ``lax.scan`` over its slice of the stack, a run of one layer a call,
-each layer a ``jax.checkpoint``. Head and loss run in blocks of
+each layer a ``jax.checkpoint``. A KDA layer's checkpoint keeps the head
+blocks' output (``kda_heads``, ``KDA_LAYER_POLICY``), so the layer's
+recomputation does not run them: they run forward twice a step, in the
+forward pass and under their own checkpoint in the backward pass; an MLA
+layer's saves nothing. Head and loss run in blocks of
 ``head_block`` positions under ``jax.checkpoint``. The matrices are cast to
 the compute dtype once a step and the train step differentiates that tree,
 as ``looped_lm.py`` says; float32 stay the stream, the norms, the
@@ -63,6 +67,7 @@ family).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -70,6 +75,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
@@ -185,6 +191,13 @@ class KDAMLALMConfig:
     def matmul_params_per_token(self) -> int:
         return sum(self.layer_matmul_params(k) for k in self.kinds) + self.vocab_size * self.d_model
 
+    def saved_heads_bytes(self, batch: int, seq: int) -> int:
+        """Bytes the KDA layers' checkpoints keep for a step of ``(batch,
+        seq)``: each KDA layer's head-block output, ``(B, S, H d)`` in the
+        compute dtype."""
+        n_kda = sum(k.startswith("kda") for k in self.kinds)
+        return n_kda * batch * seq * self.n_heads * self.head_dim * jnp.dtype(self.dtype).itemsize
+
 
 # The residual branches write small: every last matrix of a branch (KDA's
 # and MLA's o, the MLPs' down) at 0.3 of fan_in^-0.5, times the embedding's
@@ -299,9 +312,12 @@ def _unit(x: jax.Array) -> jax.Array:
 
 _KDA_COLUMNS = {"q", "k", "v", "f", "g", "q_conv", "k_conv", "v_conv", "dt_bias", "b", "A_log"}  # cut by head
 # Heads of a pass of the KDA mixer: at the cell's B 1 x S 8192 all 32 heads
-# at once plan 17.45 GB of a chip's 15.75, 8 at a time 13.55, 4 at a time
-# 12.44 (compiled for a described v5e, PERF.md PR 40).
+# at once plan 17.28 GB of a chip's 15.75, 8 at a time 12.81, 4 at a time
+# 13.00 (compiled for a described v5e, PERF.md §4).
 KDA_HEAD_BLOCK = 8
+# What a KDA layer's checkpoint keeps for the backward pass: the head
+# blocks' output alone. Module-level so that tests can swap it.
+KDA_LAYER_POLICY = jax.checkpoint_policies.save_only_these_names("kda_heads")
 
 
 def kda_mixer(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
@@ -310,8 +326,10 @@ def kda_mixer(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
     ``KDA_HEAD_BLOCK`` heads at a time (all of them where there are no
     more), each block under ``jax.checkpoint``: the backward pass holds one
     block's projections, convolutions and chunk algebra (a dozen float32
-    tensors of ``(B, S, h, d)``) and computes that block's forward a third
-    time."""
+    tensors of ``(B, S, h, d)``) and computes that block's forward once
+    more. Their output, in the compute dtype ``W_o`` reads, is named
+    ``kda_heads``: the layer's checkpoint keeps it, so the blocks run
+    forward twice a step, not three times."""
     c = cfg
     B, S, _ = a.shape
     H = c.n_heads
@@ -320,7 +338,7 @@ def kda_mixer(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
     cut = lambda t: jnp.moveaxis(t.reshape(*t.shape[:-1], H // h, -1), -2, 0)  # noqa: E731  (.., H x) -> (H/h, .., h x)
     blocks = {k: cut(v) for k, v in w.items() if k in _KDA_COLUMNS}
     o = jax.lax.map(jax.checkpoint(lambda wb: _kda_heads({**wb, "o_norm": w["o_norm"]}, a, c)), blocks)
-    o = jnp.moveaxis(o, 0, 2).reshape(B, S, H * c.head_dim)
+    o = checkpoint_name(jnp.moveaxis(o, 0, 2).reshape(B, S, H * c.head_dim).astype(c.dtype), "kda_heads")
     with jax.named_scope("kda_out"):
         return _mm(o, w["o"])
 
@@ -442,7 +460,7 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: KDAMLALMConfig, mesh: O
     chosen = []
     for kind, first, end, limits in cfg.runs:
 
-        @jax.checkpoint
+        @functools.partial(jax.checkpoint, policy=KDA_LAYER_POLICY if kind.startswith("kda") else None)
         def one(x, w, kind=kind, limits=limits):
             x, ids = layer(w, x, kind, cfg, attend, limits, stats)
             return cs(x, stream), ids
@@ -572,6 +590,8 @@ def make_train_step(
         # the matrices' come in the compute dtype (looped_lm.py says why);
         # adamw's moments and update are float32.
         params = state["params"]
+        # Set as the step is traced, where the batch's shape is first known.
+        telemetry.gauge_set("kda_mla_lm.saved_heads_bytes", cfg.saved_heads_bytes(*batch["tokens"].shape))
         loss, grads = jax.value_and_grad(_objective)(compute_params(params, cfg), batch, cfg, mesh)
         updates, opt_state = tx.update(grads, state["opt_state"], params)
         new_params = optax.apply_updates(params, updates)
