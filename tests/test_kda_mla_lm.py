@@ -612,7 +612,8 @@ def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
 
 def test_the_traced_step_reports_the_bytes_the_kda_checkpoints_keep():
     """Each of the five KDA layers keeps its head blocks' output, ``(B, S,
-    H d)`` in bfloat16, across its checkpoint."""
+    H d)`` in bfloat16, across its checkpoint; on the CPU no KDA layer runs
+    the Pallas kernels (``tests/test_pallas_kda.py`` forces them)."""
     cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
     state = jax.eval_shape(lambda k: M.init_state(k, cfg, tx), jax.random.PRNGKey(0))
     telemetry.set_enabled(True)
@@ -622,6 +623,7 @@ def test_the_traced_step_reports_the_bytes_the_kda_checkpoints_keep():
     finally:
         telemetry.set_enabled(False)
     assert gauges["kda_mla_lm.saved_heads_bytes"] == B * S * CFG.n_heads * CFG.head_dim * 2 * 5
+    assert gauges["kda_mla_lm.kda_kernel_layers"] == 0
 
 
 def test_the_named_scopes_reach_the_lowered_step():

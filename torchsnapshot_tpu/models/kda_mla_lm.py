@@ -21,7 +21,7 @@ dense iff ``p < first_k_dense``::
     KDA   q, k, v = silu(conv4(a W_q)), silu(conv4(a W_k)), silu(conv4(a W_v))      depthwise, causal, no bias
           q <- q / |q| / sqrt(d_k),  k <- k / |k|  a head;   beta = sigmoid(a W_b)   one a head
           g = L sigmoid(exp(A_h) (a W_f + b_dt))                                     (-5, 0) a key channel
-          o = kda_chunked(q, k, v, g, beta)                                          ops/kda.py
+          o = kda(q, k, v, g, beta)                                                  ops/kda.py
           out = (rms_head(o; g_o) * sigmoid(a W_g)) W_o
     MLA   q = a W_q = [q_nope ; q_rope] a head;  [c ; k_r] = a W_kva;  c <- rms(c; g_c)
           [k_nope ; v] = c W_kvb a head;  k_h = [k_nope,h ; k_r]                     k_r shared by every head
@@ -80,7 +80,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..ops.attention import causal_attention_route
-from ..ops.kda import kda_chunked
+from ..ops import kda as kda_ops
 from ..ops.moe import clamped_gated_ffn, gated_ffn, group_limited, held_tile_stats, sigmoid_group_routed
 from .block_diffusion_lm import _constrainer, _mm, _rmsnorm  # the same float32 norm, matmul and constraint
 from .hybrid_lm import _causal_conv  # the same depthwise causal convolution, here with no bias
@@ -198,6 +198,12 @@ class KDAMLALMConfig:
         n_kda = sum(k.startswith("kda") for k in self.kinds)
         return n_kda * batch * seq * self.n_heads * self.head_dim * jnp.dtype(self.dtype).itemsize
 
+    def kda_kernel_layers(self, seq: int) -> int:
+        """KDA layers whose chunk algebra runs in the Pallas kernels at
+        ``seq`` positions (``ops/kda.py`` ``kda_route``): all or none."""
+        n_kda = sum(k.startswith("kda") for k in self.kinds)
+        return n_kda if kda_ops.kda_route(seq, self.head_dim, self.head_dim, self.chunk) == "pallas" else 0
+
 
 # The residual branches write small: every last matrix of a branch (KDA's
 # and MLA's o, the MLPs' down) at 0.3 of fan_in^-0.5, times the embedding's
@@ -311,9 +317,9 @@ def _unit(x: jax.Array) -> jax.Array:
 
 
 _KDA_COLUMNS = {"q", "k", "v", "f", "g", "q_conv", "k_conv", "v_conv", "dt_bias", "b", "A_log"}  # cut by head
-# Heads of a pass of the KDA mixer: at the cell's B 1 x S 8192 all 32 heads
-# at once plan 17.28 GB of a chip's 15.75, 8 at a time 12.81, 4 at a time
-# 13.00 (compiled for a described v5e, PERF.md §4).
+# Heads of a pass of the KDA mixer: at the cell's B 1 x S 8192, 8 at a time
+# plan 13.26 GB of a chip's 15.75 with the chunk algebra's Pallas kernels
+# (XLA's form: 12.81; 32 at once 17.28, 4 at a time 13.00; PERF.md §4).
 KDA_HEAD_BLOCK = 8
 # What a KDA layer's checkpoint keeps for the backward pass: the head
 # blocks' output alone. Module-level so that tests can swap it.
@@ -358,7 +364,7 @@ def _kda_heads(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
         beta = jax.nn.sigmoid(_mm(a, w["b"]))  # (B, S, H)
         rate = jnp.repeat(jnp.exp(w["A_log"].astype(f32)), hd)  # A_h over the head's channels
         g = c.kda_lower_bound * jax.nn.sigmoid(rate * (_mm(a, w["f"]) + w["dt_bias"].astype(f32)))
-    o = kda_chunked(q, k, heads(v), heads(g), beta, chunk=c.chunk)
+    o = kda_ops.kda(q, k, heads(v), heads(g), beta, chunk=c.chunk)
     with jax.named_scope("kda_out"):
         return _rmsnorm(o, w["o_norm"], c.norm_eps).reshape(B, S, H * hd) * jax.nn.sigmoid(_mm(a, w["g"]))
 
@@ -592,6 +598,7 @@ def make_train_step(
         params = state["params"]
         # Set as the step is traced, where the batch's shape is first known.
         telemetry.gauge_set("kda_mla_lm.saved_heads_bytes", cfg.saved_heads_bytes(*batch["tokens"].shape))
+        telemetry.gauge_set("kda_mla_lm.kda_kernel_layers", cfg.kda_kernel_layers(batch["tokens"].shape[1]))
         loss, grads = jax.value_and_grad(_objective)(compute_params(params, cfg), batch, cfg, mesh)
         updates, opt_state = tx.update(grads, state["opt_state"], params)
         new_params = optax.apply_updates(params, updates)

@@ -41,7 +41,10 @@ at ``HIGHEST``; what is computed once for all chunks at a time
 (``kda_inter``) carries ``S`` alone, two products a chunk.
 
 The backward pass is the algebra's own derivative (the model checkpoints
-the layer around it): no term of it is hand-written.
+the layer around it): no term of it is hand-written. ``kda`` runs either
+this form or the same algebra as a pair of Pallas kernels
+(``ops/pallas_kda.py``, with a hand-written backward pass), as
+``kda_route`` decides from the backend and the shapes.
 """
 
 from __future__ import annotations
@@ -139,3 +142,22 @@ def _chunked(q, k, v, g, beta, C, sb):
         _, starts = jax.lax.scan(step, jnp.zeros((B, H, K, V), F32), xs)
         o = p1 + jnp.einsum("bhnrk,nbhkv->bhnrv", p2, starts, precision=HI)
     return jnp.moveaxis(o, 1, 3).reshape(B, S, H, V)
+
+
+def kda_route(S: int, d_k: int, d_v: int, chunk: int) -> str:
+    """Which form ``kda`` runs: ``pallas`` (``ops/pallas_kda.py``) on a TPU
+    where both widths tile the lanes (multiples of 128) and S is a whole
+    number of chunks, else ``xla`` (``kda_chunked``: the CPU, other
+    widths, a sequence shorter than a chunk)."""
+    if jax.default_backend() == "tpu" and d_k % 128 == 0 and d_v % 128 == 0 and S % chunk == 0:
+        return "pallas"
+    return "xla"
+
+
+def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *, chunk: int = 64) -> jax.Array:
+    """``kda_chunked``'s output by the form ``kda_route`` picks."""
+    if kda_route(q.shape[1], q.shape[-1], v.shape[-1], chunk) == "pallas":
+        from .pallas_kda import kda_pallas
+
+        return kda_pallas(q, k, v, g, beta, chunk=chunk)
+    return kda_chunked(q, k, v, g, beta, chunk=chunk)
