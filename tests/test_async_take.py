@@ -217,7 +217,7 @@ def test_warmup_staging_prefaults_exact_sizes(tmp_path, monkeypatch):
     assert warmup_staging(state) == 0
 
 
-def test_warmup_staging_sharded_piece_sizes():
+def test_warmup_staging_sharded_piece_sizes(monkeypatch):
     """For a GSPMD-sharded array, warmup sizes the pool from the owned
     write pieces, not the full array."""
     import jax
@@ -225,7 +225,13 @@ def test_warmup_staging_sharded_piece_sizes():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from torchsnapshot_tpu import StateDict, warmup_staging
+    from torchsnapshot_tpu.io_preparers import array as array_mod
     from torchsnapshot_tpu.io_preparers.sharded import ShardedArrayIOPreparer
+
+    # A pool of the test's own, as above: free slabs of the sizes of these
+    # pieces, left by a test file this worker ran before, are not warmed
+    # again and would count against the pieces.
+    monkeypatch.setattr(array_mod, "_staging_pool", array_mod._StagingPool(array_mod._pool_limit()))
 
     devs = jax.devices()
     if len(devs) < 2:

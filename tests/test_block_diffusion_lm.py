@@ -10,7 +10,6 @@ train step and ``CheckpointManager`` as the other families go.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import importlib.util
 import os
 
@@ -20,12 +19,22 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
+from torchsnapshot_tpu import CheckpointManager, StateDict
 from torchsnapshot_tpu.models import block_diffusion_lm as M
 from torchsnapshot_tpu.ops.attention import BlockDiffusionMask, causal_attention_route
 from torchsnapshot_tpu.ops import moe
 from torchsnapshot_tpu.ops.moe import _held_experts, gated_ffn, softmax_topk_route, softmax_topk_routed
 from torchsnapshot_tpu.parallel import make_mesh
+from model_kit_cases import (  # noqa: F401  (the cases this file runs)
+    Model,
+    test_routing_stats_count_what_the_routers_chose,
+    test_the_named_scopes_reach_the_lowered_step,
+    test_the_sharded_loss_equals_the_one_device_loss,
+    test_the_step_puts_what_it_compiles_on_the_telemetry_bus,
+    test_the_step_reports_a_finite_loss_and_keeps_its_layout,
+    test_the_train_state_goes_through_the_checkpoint_manager_bit_exact,
+    test_the_train_steps_gradient_is_the_losss,
+)
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "benchmarks", "chip", "reference", "block_diffusion_lm.py")
@@ -256,28 +265,6 @@ def test_the_gated_experts_backward_pass_is_autodiffs_of_the_dense_form(tile):
     assert float(jnp.abs(got[2][0][2]).max()) == 0.0  # the expert nobody chose takes no gradient
 
 
-def test_routing_stats_count_what_the_routers_chose():
-    params, batch = _params(CFG), _batch()
-    masked, _ = _noise()
-    stats = M.routing_stats(params, batch["tokens"], masked, CFG)
-    chosen = np.asarray(M.chosen_experts(params, batch["tokens"], masked, CFG))
-    assert chosen.shape == (CFG.n_layers, B * 2 * S, CFG.top_k)
-    counts = np.stack([[np.sum(layer == e) for e in CFG.held] for layer in chosen])
-    np.testing.assert_array_equal(stats["held_counts"], counts)
-    np.testing.assert_allclose(stats["held_share"], counts.sum(1) / chosen[0].size, rtol=1e-6)
-    np.testing.assert_allclose(stats["max_over_mean"], counts.max(1) / counts.mean(1), rtol=1e-6)
-    # the list's row tiles: an expert's own rows rounded up to tiles, one tile for an expert of no rows
-    tile = M.expert_tile(CFG, B * 2 * S)
-    assert tile == min(128, B * 2 * S)
-    trips = np.sum(np.maximum(-(-counts // tile), 1), axis=1)
-    np.testing.assert_array_equal(stats["trips"], trips)
-    np.testing.assert_allclose(stats["tile_fill"], counts.sum(1) / (trips * tile), rtol=1e-6)
-    assert (trips >= len(CFG.held)).all()
-    want = R.chosen_experts(params, batch["tokens"], masked=masked, **_ref_args(CFG))
-    for got_layer, want_layer in zip(chosen, want):
-        np.testing.assert_array_equal(np.sort(got_layer, -1), np.sort(np.asarray(want_layer).reshape(got_layer.shape), -1))
-
-
 # ----------------------------------------------------------------- the stack
 
 
@@ -369,16 +356,20 @@ def test_the_published_sizes_count_to_the_cells_state():
     assert M.attention_mask(cfg, 4096).live_tiles(512) == 80
 
 
+_LOADS_CFG = M.BlockDiffusionLMConfig(vocab_size=4096, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16,
+                                      n_experts=32, top_k=4, expert_ff=32, held=tuple(range(8)), dtype=jnp.float32)
+_loads_stats = jax.jit(lambda p, t, m: M.routing_stats(p, t, m, _LOADS_CFG))  # compiled once for the cases below
+
+
 def _route_loads(init, seed):
     """Held share over the even share and the fullest held expert over the
     mean, per layer, of a toy model of 32 experts, 8 held, top-4."""
-    cfg = M.BlockDiffusionLMConfig(vocab_size=4096, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16,
-                                   n_experts=32, top_k=4, expert_ff=32, held=tuple(range(8)), dtype=jnp.float32)
+    cfg = _LOADS_CFG
     key = jax.random.PRNGKey(seed)
     params = init(M.init_params(key, cfg), cfg)
     tokens = jax.random.randint(jax.random.fold_in(key, 1), (1, 512), 0, 4096)
     masked, _ = M.draw_noise(jax.random.fold_in(key, 2), 1, 512, cfg)
-    stats = jax.jit(lambda p, t, m: M.routing_stats(p, t, m, cfg))(params, tokens, masked)
+    stats = _loads_stats(params, tokens, masked)
     return np.asarray(stats["held_share"]) / 0.25, np.asarray(stats["max_over_mean"])
 
 
@@ -431,126 +422,56 @@ def test_a_bad_share_or_head_grouping_is_refused():
 # ------------------------------------------------------------ the train step
 
 
-def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
-    telemetry.set_enabled(True)
-    try:
-        M.make_train_step(CFG, M.make_optimizer())
-        gauges = telemetry.gauges()
-    finally:
-        telemetry.set_enabled(False)
-    assert gauges["block_diffusion_lm.layers"] == 3 and gauges["block_diffusion_lm.experts_held"] == 4
-    assert gauges["block_diffusion_lm.matmul_params_per_token"] == CFG.matmul_params_per_token
+def _routes():
+    params, tokens = _params(CFG), _batch()["tokens"]
+    masked, _ = _noise()
+    stats, chosen = jax.jit(lambda p, t, m: (M.routing_stats(p, t, m, CFG), M.chosen_experts(p, t, m, CFG)))(
+        params, tokens, masked)
+    chosen = np.asarray(chosen)
+    assert chosen.shape == (CFG.n_layers, B * 2 * S, CFG.top_k) and M.expert_tile(CFG, B * 2 * S) == min(128, B * 2 * S)
+    want = np.stack([np.asarray(w) for w in jax.jit(lambda p, t, m: R.chosen_experts(p, t, masked=m, **_ref_args(CFG)))(params, tokens, masked)])
+    return stats, chosen, want
 
 
-def test_the_named_scopes_reach_the_lowered_step():
-    tx = M.make_optimizer()
-    state = jax.eval_shape(lambda k: M.init_state(k, CFG, tx), jax.random.PRNGKey(0))
-    text = jax.jit(M.make_train_step(CFG, tx)).lower(state, jax.eval_shape(_batch)).as_text(debug_info=True)
-    for scope in ("attn_bd", "moe_route", "moe_experts", "bd_noise", "lm_head"):
-        assert scope in text, scope
+@pytest.fixture(scope="module")
+def model():
+    return Model(
+        M, CFG, _batch,
+        gauges={"block_diffusion_lm.layers": 3, "block_diffusion_lm.experts_held": 4,
+                "block_diffusion_lm.matmul_params_per_token": CFG.matmul_params_per_token},
+        scopes=("attn_bd", "moe_route", "moe_experts", "bd_noise", "lm_head"),
+        leaves=(48, 2),  # the noise key among them
+        descends=False,  # each step draws other masks
+        loss_kwargs=lambda state: {"key": M.noise_key(state)},
+        routes=_routes,
+    )
 
 
-def test_the_train_steps_gradient_is_the_losss_and_the_key_moves_on():
-    tx = M.make_optimizer(1e-2)
-    state = M.init_state(jax.random.PRNGKey(0), CFG, tx)
-    batch = _batch()
-    new, loss = jax.jit(M.make_train_step(CFG, tx))(state, batch)
-    want = M.loss_fn(state["params"], batch, CFG, key=M.noise_key(state))
-    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
-    assert int(new["step"]) == 1 and not np.array_equal(new["rng"], state["rng"])
+def test_the_train_steps_gradient_is_the_losss_and_the_key_moves_on(model):
+    """The step hands the noise key on; another key draws other masks and
+    gives another loss."""
+    state = model.state
+    new, loss = model.stepped
+    assert not np.array_equal(new["rng"], state["rng"])
     np.testing.assert_array_equal(new["rng"], jax.random.split(state["rng"])[0])
-    moved = [k for k, (a, b) in
-             {jax.tree_util.keystr(p): (a, b) for (p, a), b in
-              zip(jax.tree_util.tree_flatten_with_path(state["params"])[0], jax.tree_util.tree_leaves(new["params"]))}.items()
-             if not np.array_equal(a, b)]
-    assert len(moved) == 15  # every parameter leaf takes a gradient, the q- and k-norm scales among them
-    # another key, other masks, another loss; the same key, the same
     other = {**state, "rng": state["rng"] + jnp.uint32(1)}
-    assert float(jax.jit(M.make_train_step(CFG, tx))(other, batch)[1]) != float(loss)
+    assert float(model.compiled(other, model.batch())[1]) != float(loss)
 
 
-@pytest.mark.parametrize("mesh_axes", [None, {"data": 2, "model": 2}])
-def test_the_step_reports_a_finite_loss_and_keeps_its_layout(mesh_axes):
-    mesh = make_mesh(mesh_axes, devices=jax.devices()[:4]) if mesh_axes else None
-    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh)
-    before = [(x.shape, x.dtype, x.sharding) for x in jax.tree_util.tree_leaves(state)]
-    batch = _batch(mesh)
-    step = jax.jit(M.make_train_step(cfg, tx, mesh=mesh), donate_argnums=0).lower(state, batch).compile()
-    losses = []
-    for _ in range(3):  # compiled once: a drifted layout would be an error, not a recompile
-        state, loss = step(state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and int(state["step"]) == 3
-    for (path, x), (shape, dtype, sharding) in zip(jax.tree_util.tree_flatten_with_path(state)[0], before):
-        name = jax.tree_util.keystr(path)
-        assert (x.shape, x.dtype) == (shape, dtype), name
-        if mesh is not None:
-            assert x.sharding.is_equivalent_to(sharding, x.ndim), name
-
-
-def test_the_sharded_loss_equals_the_one_device_loss():
-    mesh = make_mesh({"data": 2, "model": 2}, devices=jax.devices()[:4])
-    key = jax.random.PRNGKey(4)
-    params = M.init_params(jax.random.split(jax.random.PRNGKey(0))[0], CFG)
-    want = jax.jit(lambda p, b: M.loss_fn(p, b, CFG, key=key))(params, _batch())
-    sharded = M.init_state(jax.random.PRNGKey(0), CFG, M.make_optimizer(), mesh=mesh)["params"]
-    assert sharded["embed"].sharding.spec == sharded["head"].sharding.spec == P("model", None)
-    got = jax.jit(lambda p, b: M.loss_fn(p, b, CFG, key=key, mesh=mesh))(sharded, _batch(mesh))
-    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted_step(cfg):
-    return jax.jit(M.make_train_step(cfg, M.make_optimizer()), donate_argnums=0)
-
-
-def _train(cfg, state, steps, first=1):
-    step = _jitted_step(cfg)  # compiled once for the cases below
-    loss = None
-    for n in range(first, first + steps):
-        state, loss = step(state, _batch(seed=n))
-    return state, float(loss)
-
-
-@pytest.mark.parametrize("async_save", [True, False])
-def test_the_train_state_goes_through_the_checkpoint_manager_bit_exact(tmp_path, async_save):
-    """Save, restore into a destination from another seed, leaf for leaf
-    equal (48 leaves, the noise key among them), and the next step's loss
-    equal to the uninterrupted one: the resumed job draws the same masks."""
-    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
-    state, _ = _train(cfg, M.init_state(jax.random.PRNGKey(0), cfg, tx), 2)
-    saved = jax.tree_util.tree_map(np.asarray, state)
-    mgr = CheckpointManager(str(tmp_path / "ckpt"), save_interval_steps=1, keep_last=1, async_save=async_save)
-    assert mgr.save(2, {"train": StateDict(**state)})
-    mgr.wait()
-    _, want_loss = _train(cfg, state, 1, first=3)
-
-    dst = StateDict(**M.init_state(jax.random.PRNGKey(1), cfg, tx))
-    assert not np.array_equal(dst["rng"], saved["rng"])
-    assert mgr.restore({"train": dst}) == 2
-    restored = dict(dst)
-    leaves = jax.tree_util.tree_flatten_with_path(saved)[0]
-    assert len(leaves) == 48
-    for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(restored)):
-        np.testing.assert_array_equal(a, np.asarray(b), err_msg=jax.tree_util.keystr(path))
-    _, got_loss = _train(cfg, restored, 1, first=3)
-    assert got_loss == want_loss
-
-
-def test_a_resumed_job_that_lost_the_noise_key_does_not_continue_the_run(tmp_path):
+def test_a_resumed_job_that_lost_the_noise_key_does_not_continue_the_run(model, tmp_path):
     """Everything restored but ``rng``: the parameters and the step are the
     saved ones and the next step's loss is another, because its masks are."""
-    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
-    state, _ = _train(cfg, M.init_state(jax.random.PRNGKey(0), cfg, tx), 2)
+    state, _ = model.train(model.fresh_state(), 2)
     mgr = CheckpointManager(str(tmp_path / "ckpt"), save_interval_steps=1, keep_last=1, async_save=False)
     assert mgr.save(2, {"train": StateDict(**state)})
-    _, want_loss = _train(cfg, state, 1, first=3)
-    fresh = M.init_state(jax.random.PRNGKey(1), cfg, tx)
+    saved_key = np.asarray(state["rng"])
+    _, want_loss = model.train(state, 1, first=3)
+    fresh = model.fresh_state(seed=1)
+    assert not np.array_equal(np.asarray(fresh["rng"]), saved_key)
     dst = StateDict(**fresh)
     assert mgr.restore({"train": dst}) == 2
+    np.testing.assert_array_equal(np.asarray(dst["rng"]), saved_key)
     lost = {**dict(dst), "rng": fresh["rng"]}
     assert int(lost["step"]) == 2
-    _, got_loss = _train(cfg, lost, 1, first=3)
+    _, got_loss = model.train(lost, 1, first=3)
     assert np.isfinite(got_loss) and got_loss != want_loss

@@ -22,14 +22,23 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from torchsnapshot_tpu import CheckpointManager, Snapshot, StateDict, telemetry
+from torchsnapshot_tpu import Snapshot
 from torchsnapshot_tpu.io_preparers import chunked
 from torchsnapshot_tpu.manifest import ChunkedArrayEntry
 from torchsnapshot_tpu.models import cca_moe_lm as M
 from torchsnapshot_tpu.ops import cca, moe
 from torchsnapshot_tpu.ops.attention import causal_attention_route
 from torchsnapshot_tpu.ops.moe import mlp_top1_route, mlp_top1_routed
-from torchsnapshot_tpu.parallel import make_mesh
+from model_kit_cases import (  # noqa: F401  (the cases this file runs)
+    Model,
+    checkpoint_round_trip,
+    test_routing_stats_count_what_the_routers_chose,
+    test_the_named_scopes_reach_the_lowered_step,
+    test_the_sharded_loss_equals_the_one_device_loss,
+    test_the_step_puts_what_it_compiles_on_the_telemetry_bus,
+    test_the_step_reports_a_finite_loss_and_keeps_its_layout,
+    test_the_train_steps_gradient_is_the_losss,
+)
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "benchmarks", "chip", "reference", "cca_moe_lm.py")
@@ -370,27 +379,6 @@ def test_the_two_disjoint_shares_add_up_to_the_uncut_layer():
     np.testing.assert_array_equal(ids[:, 0], want_ids.reshape(-1))
 
 
-def test_routing_stats_count_what_the_routers_chose():
-    params, batch = _params(CFG), _batch()
-    stats = M.routing_stats(params, batch["tokens"], CFG)
-    chosen = np.asarray(M.chosen_experts(params, batch["tokens"], CFG))
-    assert chosen.shape == (CFG.n_layers, B * S, 1)
-    counts = np.stack([[np.sum(layer == e) for e in CFG.held] for layer in chosen])
-    np.testing.assert_array_equal(stats["held_counts"], counts)
-    np.testing.assert_allclose(stats["held_share"], counts.sum(1) / (B * S), rtol=1e-6)
-    np.testing.assert_allclose(stats["max_over_mean"], counts.max(1) / counts.mean(1), rtol=1e-6)
-    # the list's row tiles: an expert's own rows rounded up to tiles, one tile for an expert of no rows
-    tile = M.expert_tile(CFG, B * S)
-    assert tile == min(128, B * S)
-    trips = np.sum(np.maximum(-(-counts // tile), 1), axis=1)
-    np.testing.assert_array_equal(stats["trips"], trips)
-    np.testing.assert_allclose(stats["tile_fill"], counts.sum(1) / (trips * tile), rtol=1e-6)
-    assert (trips >= len(CFG.held)).all() and 0 < counts.sum() < CFG.n_layers * B * S  # some positions' experts are elsewhere
-    want = R.chosen_experts(params, batch["tokens"], **_ref_args(CFG))
-    for got_layer, want_layer in zip(chosen, want):
-        np.testing.assert_array_equal(got_layer[:, 0], np.asarray(want_layer).reshape(-1))
-
-
 @pytest.mark.parametrize("positions,n_experts,tile", [(8192, 16, 128), (16384, 16, 128), (256, 8, 128), (64, 64, 64)])
 def test_the_experts_row_tile_follows_the_positions_alone(positions, n_experts, tile):
     """128 rows whatever the even load, by ``block_diffusion_lm``'s rule."""
@@ -432,7 +420,7 @@ def test_the_scan_carries_both_streams_as_an_unrolled_loop_does():
             x, r, c = M.layer({k: v[i] for k, v in cp["layers"].items()}, x, r, CFG, attend)
             ids.append(c)
             r = r if keep_carry else jnp.zeros_like(r)
-        return M._rmsnorm(x, cp["ln_f_scale"], CFG.norm_eps), jnp.stack(ids)
+        return M.rmsnorm(x, cp["ln_f_scale"], CFG.norm_eps), jnp.stack(ids)
 
     want, want_chosen = unrolled(True)
     np.testing.assert_allclose(got, want, atol=2e-6)
@@ -445,6 +433,14 @@ def test_the_scan_carries_both_streams_as_an_unrolled_loop_does():
     np.testing.assert_array_equal(g["layers"]["router_decay"][0], 0.0)  # the first layer's gamma multiplies zeros
 
 
+@functools.lru_cache(maxsize=None)
+def _program_value_and_grad(n_layers):
+    """The program's loss and gradient at the tests' weights and batch,
+    computed once for the tests below that read them."""
+    cfg, batch = dataclasses.replace(CFG, n_layers=n_layers), _batch()
+    return jax.value_and_grad(lambda p: M.loss_fn(p, batch, cfg))(_params(cfg))
+
+
 @pytest.mark.parametrize("n_layers", [1, 3])
 def test_loss_and_every_gradient_leaf_agree_with_the_reference(n_layers):
     """The program's loss and ``jax.grad`` of it against the reference's,
@@ -455,7 +451,7 @@ def test_loss_and_every_gradient_leaf_agree_with_the_reference(n_layers):
     side: the selection bias, and with one layer the only ``gamma``."""
     cfg = dataclasses.replace(CFG, n_layers=n_layers)
     params, batch = _params(cfg), _batch()
-    got, got_grads = jax.value_and_grad(lambda p: M.loss_fn(p, batch, cfg))(params)
+    got, got_grads = _program_value_and_grad(n_layers)
     want, want_grads = jax.value_and_grad(lambda p: R.loss(p, batch["tokens"], batch["targets"], **_ref_args(cfg)))(params)
     np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
     flat_want = dict(jax.tree_util.tree_flatten_with_path(want_grads)[0])
@@ -502,7 +498,7 @@ def test_the_tied_embeddings_gradient_is_the_sum_of_the_lookups_and_the_heads():
         return M._cross_entropy(h, head, batch["targets"], CFG, lambda x, spec: x)
 
     d_lookup, d_head = jax.grad(untied, (0, 1))(cp["embed"], cp["embed"])
-    tied = jax.grad(lambda p: M.loss_fn(p, batch, CFG))(params)["embed"]
+    tied = _program_value_and_grad(CFG.n_layers)[1]["embed"]
     assert tied.dtype == jnp.float32 and float(jnp.abs(d_lookup).max()) > 0 and float(jnp.abs(d_head).max()) > 0
     np.testing.assert_allclose(tied, d_lookup + d_head, atol=1e-6)
     unseen = np.setdiff1d(np.arange(V), np.asarray(batch["tokens"]))
@@ -613,114 +609,43 @@ def test_a_bad_share_or_head_grouping_is_refused():
 # ---------------------------------------------------------------- the train step
 
 
-def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
-    telemetry.set_enabled(True)
-    try:
-        M.make_train_step(CFG, M.make_optimizer())
-        gauges = telemetry.gauges()
-    finally:
-        telemetry.set_enabled(False)
-    assert gauges["cca_moe_lm.layers"] == 3 and gauges["cca_moe_lm.experts_held"] == 4
-    assert gauges["cca_moe_lm.matmul_params_per_token"] == CFG.matmul_params_per_token
+def _routes():
+    params, tokens = _params(CFG), _batch()["tokens"]
+    stats, chosen = jax.jit(lambda p, t: (M.routing_stats(p, t, CFG), M.chosen_experts(p, t, CFG)))(params, tokens)
+    chosen = np.asarray(chosen)
+    assert chosen.shape == (CFG.n_layers, B * S, 1) and M.expert_tile(CFG, B * S) == min(128, B * S)
+    want = np.stack([np.asarray(w).reshape(-1, 1) for w in jax.jit(lambda p, t: R.chosen_experts(p, t, **_ref_args(CFG)))(params, tokens)])
+    return stats, chosen, want
 
 
-def test_the_named_scopes_reach_the_lowered_step():
-    tx = M.make_optimizer()
-    state = jax.eval_shape(lambda k: M.init_state(k, CFG, tx), jax.random.PRNGKey(0))
-    text = jax.jit(M.make_train_step(CFG, tx)).lower(state, jax.eval_shape(_batch)).as_text(debug_info=True)
-    for scope in ("cca_proj", "cca_conv", "cca_qkmean", "cca_norm_rope", "cca_attn", "res_scale", "moe_route",
-                  "moe_route/router_mlp", "moe_experts", "lm_head"):
-        assert scope in text, scope
-
-
-def test_the_train_steps_gradient_is_the_losss():
-    tx = M.make_optimizer(1e-2)
-    state = M.init_state(jax.random.PRNGKey(0), CFG, tx)
-    batch = _batch()
-    new, loss = jax.jit(M.make_train_step(CFG, tx))(state, batch)
-    np.testing.assert_allclose(float(loss), float(M.loss_fn(state["params"], batch, CFG)), rtol=1e-6)
-    assert int(new["step"]) == 1
-    before = dict(jax.tree_util.tree_flatten_with_path(state["params"])[0])
-    still = [jax.tree_util.keystr(p) for p, b in jax.tree_util.tree_flatten_with_path(new["params"])[0]
-             if np.array_equal(before[p], b)]
-    # every leaf moves but the selection bias: no gradient, and weight decay of zeros is zero
-    assert still == ["['layers']['router_bias']"]
-
-
-@pytest.mark.parametrize("mesh_axes", [None, {"data": 2, "model": 2}])
-def test_the_step_reports_a_finite_loss_and_keeps_its_layout(mesh_axes):
-    mesh = make_mesh(mesh_axes, devices=jax.devices()[:4]) if mesh_axes else None
-    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh)
-    before = [(x.shape, x.dtype, x.sharding) for x in jax.tree_util.tree_leaves(state)]
-    batch = _batch(mesh)
-    step = jax.jit(M.make_train_step(cfg, tx, mesh=mesh), donate_argnums=0).lower(state, batch).compile()
-    losses = []
-    for _ in range(3):  # compiled once: a drifted layout would be an error, not a recompile
-        state, loss = step(state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and int(state["step"]) == 3
-    for (path, x), (shape, dtype, sharding) in zip(jax.tree_util.tree_flatten_with_path(state)[0], before):
-        name = jax.tree_util.keystr(path)
-        assert (x.shape, x.dtype) == (shape, dtype), name
-        if mesh is not None:
-            assert x.sharding.is_equivalent_to(sharding, x.ndim), name
-
-
-def test_the_sharded_loss_equals_the_one_device_loss():
-    mesh = make_mesh({"data": 2, "model": 2}, devices=jax.devices()[:4])
-    params = M.init_params(jax.random.PRNGKey(0), CFG)
-    want = jax.jit(lambda p, b: M.loss_fn(p, b, CFG))(params, _batch())
-    sharded = M.init_state(jax.random.PRNGKey(0), CFG, M.make_optimizer(), mesh=mesh)["params"]
-    assert sharded["embed"].sharding.spec == P("model", None)
-    got = jax.jit(lambda p, b: M.loss_fn(p, b, CFG, mesh=mesh))(sharded, _batch(mesh))
-    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
-    assert M.select_attention(CFG, mesh, B, S) == M.select_attention(CFG, None, B, S) == "dense"
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted_step(cfg):
-    return jax.jit(M.make_train_step(cfg, M.make_optimizer()), donate_argnums=0)
-
-
-def _train(cfg, state, steps, first=1):
-    step = _jitted_step(cfg)  # compiled once for the cases below
-    loss = None
-    for n in range(first, first + steps):
-        state, loss = step(state, _batch(seed=n))
-    return state, float(loss)
+@pytest.fixture(scope="module")
+def model():
+    return Model(
+        M, CFG, _batch,
+        gauges={"cca_moe_lm.layers": 3, "cca_moe_lm.experts_held": 4,
+                "cca_moe_lm.matmul_params_per_token": CFG.matmul_params_per_token},
+        scopes=("cca_proj", "cca_conv", "cca_qkmean", "cca_norm_rope", "cca_attn", "res_scale", "moe_route",
+                "moe_route/router_mlp", "moe_experts", "lm_head"),
+        leaves=(107, 3 * 2),  # the temperatures: 2 floats a layer
+        # every leaf moves but the selection bias: no gradient, and weight decay of zeros is zero
+        still=("['layers']['router_bias']",),
+        routes=_routes,
+    )
 
 
 @pytest.mark.parametrize("async_save", [True, False])
-def test_the_chunked_state_resumes_the_uninterrupted_run(tmp_path, monkeypatch, async_save):
+def test_the_chunked_state_resumes_the_uninterrupted_run(model, tmp_path, monkeypatch, async_save):
     """Save with the chunk limit shrunk so that the nine expert-stack
     leaves are cut (beside the 8-byte temperatures of a layer and the
     4-byte step), restore into a destination from another seed, leaf for
     leaf equal (107 leaves), and the next step's loss is the
     uninterrupted run's."""
     monkeypatch.setattr(chunked, "DEFAULT_MAX_CHUNK_SIZE_BYTES", 32768)  # over the embedding's 18 KiB
-    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
-    state, _ = _train(cfg, M.init_state(jax.random.PRNGKey(0), cfg, tx), 2)
-    saved = jax.tree_util.tree_map(np.asarray, state)
-    mgr = CheckpointManager(str(tmp_path / "ckpt"), save_interval_steps=1, keep_last=1, async_save=async_save)
-    assert mgr.save(2, {"train": StateDict(**state)})
-    mgr.wait()
-    _, want_loss = _train(cfg, state, 1, first=3)
-
+    mgr = checkpoint_round_trip(model, tmp_path / "ckpt", async_save)
     manifest = Snapshot(mgr.path_for(2)).get_manifest()
     cut = sorted(k for k, e in manifest.items() if isinstance(e, ChunkedArrayEntry) and len(e.chunks) > 1)
     assert len(cut) == 9 and all("expert_" in k for k in cut)  # 3 stacks x (param, mu, nu): 54 KiB each
-    sizes = {k: int(np.prod(v.shape)) * v.dtype.itemsize for k, v in
-             ((jax.tree_util.keystr(p), x) for p, x in jax.tree_util.tree_flatten_with_path(saved)[0])}
+    shapes = jax.eval_shape(model.fresh_state)
+    sizes = {jax.tree_util.keystr(p): int(np.prod(x.shape)) * x.dtype.itemsize
+             for p, x in jax.tree_util.tree_flatten_with_path(shapes)[0]}
     assert min(sizes.values()) == 4 and sizes["['params']['layers']['temp']"] == 3 * 8 and max(sizes.values()) == 3 * 4 * 48 * 24 * 4
-
-    dst = StateDict(**M.init_state(jax.random.PRNGKey(1), cfg, tx))
-    assert mgr.restore({"train": dst}) == 2
-    restored = dict(dst)
-    leaves = jax.tree_util.tree_flatten_with_path(saved)[0]
-    assert len(leaves) == 107
-    for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(restored)):
-        np.testing.assert_array_equal(a, np.asarray(b), err_msg=jax.tree_util.keystr(path))
-    _, got_loss = _train(cfg, restored, 1, first=3)
-    assert got_loss == want_loss

@@ -9,7 +9,6 @@ as the other families go.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import importlib.util
 import os
 
@@ -19,13 +18,21 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
 from torchsnapshot_tpu.models import hybrid_lm as M
 from torchsnapshot_tpu.ops.attention import causal_attention_route, dense_attention
 from torchsnapshot_tpu.ops import moe
 from torchsnapshot_tpu.ops.moe import relu2_ffn, sigmoid_topk_routed
 from torchsnapshot_tpu.ops.ssm import _within_chunk_sum, mamba2_chunked
-from torchsnapshot_tpu.parallel import make_mesh
+from model_kit_cases import (  # noqa: F401  (the cases this file runs)
+    Model,
+    test_routing_stats_count_what_the_routers_chose,
+    test_the_named_scopes_reach_the_lowered_step,
+    test_the_sharded_loss_equals_the_one_device_loss,
+    test_the_step_puts_what_it_compiles_on_the_telemetry_bus,
+    test_the_step_reports_a_finite_loss_and_keeps_its_layout,
+    test_the_train_state_goes_through_the_checkpoint_manager_bit_exact,
+    test_the_train_steps_gradient_is_the_losss,
+)
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "benchmarks", "chip", "reference", "hybrid_lm.py")
@@ -395,30 +402,6 @@ def test_routing_weights_come_from_the_scores_not_from_the_biased_scores():
     assert (np.sort(want_ids, -1) != np.sort(np.argsort(-s, axis=-1)[:, : cfg.top_k], -1)).any()  # the bias matters here
 
 
-def test_routing_stats_count_what_the_routers_chose():
-    params, tokens = _params(CFG), _batch()["tokens"]
-    stats = jax.jit(lambda p, t: M.routing_stats(p, t, CFG))(params, tokens)
-    chosen = jax.jit(lambda p, t: M.chosen_experts(p, t, CFG))(params, tokens)
-    want = R.chosen_experts(params, tokens, **_ref_args(CFG))
-    names = [n for n in CFG.layer_names if n.endswith("moe")]
-    assert list(stats) == list(chosen) == names and len(want) == 4
-    for name, ref_ids in zip(names, want):
-        ids = np.asarray(chosen[name])
-        assert ids.shape == (B * S, CFG.top_k)
-        # float32 on both sides: the same sets, bar an exact tie
-        same = (np.sort(ids, -1) == np.sort(np.asarray(ref_ids).reshape(ids.shape), -1)).all(-1)
-        assert same.mean() >= 0.98, name
-        counts = np.array([(ids == e).sum() for e in CFG.held])
-        np.testing.assert_allclose(float(stats[name]["held_share"]), counts.sum() / ids.size, rtol=1e-6)
-        np.testing.assert_allclose(float(stats[name]["max_over_mean"]), counts.max() / counts.mean(), rtol=1e-5)
-        assert 0 < float(stats[name]["held_share"]) < 1
-        # the list's row tiles: an expert's own rows rounded up to tiles, one tile for an expert of no rows
-        tile = min(128, B * S)
-        trips = int(np.sum(np.maximum(-(-counts // tile), 1)))
-        assert int(stats[name]["trips"]) == trips >= len(CFG.held)
-        np.testing.assert_allclose(float(stats[name]["tile_fill"]), counts.sum() / (trips * tile), rtol=1e-6)
-
-
 # -------------------------------------------------------------- attention
 
 
@@ -612,117 +595,46 @@ def test_a_bad_pattern_or_share_is_refused():
 # ------------------------------------------- the train step and the state
 
 
-def test_the_train_steps_gradient_is_the_losss_and_the_bias_stays():
-    """The step differentiates the tree the layers read (matrices cast once
-    a step); in float32 the cast is the identity and the update must be the
-    one ``loss_fn``'s gradient gives, but for the selection bias, which
-    the step holds fixed against adamw's weight decay."""
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), CFG, tx)
-    batch = _batch()
-    grads = jax.jit(jax.grad(lambda p: M.loss_fn(p, batch, CFG)))(state["params"])
-    updates, _ = jax.jit(tx.update)(grads, state["opt_state"], state["params"])
-    want = jax.tree_util.tree_map(lambda p, u: p + u, state["params"], updates)
-    got, _ = jax.jit(M.make_train_step(CFG, tx))(state, batch)
+def _routes():
+    params, tokens = _params(CFG), _batch()["tokens"]
+    stats, chosen = jax.jit(lambda p, t: (M.routing_stats(p, t, CFG), M.chosen_experts(p, t, CFG)))(params, tokens)
+    names = [n for n in CFG.layer_names if n.endswith("moe")]
+    assert list(stats) == list(chosen) == names
+    stats = {k: np.stack([np.asarray(stats[n][k]) for n in names]) for k in stats[names[0]]}
+    want = jax.jit(lambda p, t: R.chosen_experts(p, t, **_ref_args(CFG)))(params, tokens)
+    assert len(want) == len(names) == 4
+    chosen = np.stack([np.asarray(chosen[n]) for n in names])
+    assert chosen.shape == (len(names), B * S, CFG.top_k)
+    return stats, chosen, np.stack([np.asarray(w) for w in want])
+
+
+@pytest.fixture(scope="module")
+def model():
+    return Model(
+        M, CFG, _batch,
+        gauges={"hybrid_lm.layers": 9, "hybrid_lm.experts_held": 4,
+                "hybrid_lm.matmul_params_per_token": CFG.matmul_params_per_token},
+        scopes=("layer0/mamba2", "layer1/moe_route", "layer1/moe_experts", "layer1/moe_shared", "layer5/gqa",
+                "layer8/moe_experts", "lm_head"),
+        # the per-head 8-float leaves and the routers' biases among them
+        leaves=(218, 8),
+        still=tuple(f"['layers']['{n}']['router_bias']" for n in CFG.layer_names if n.endswith("moe")),
+        routes=_routes,
+        route_agreement=0.98,  # float32 on both sides: the same sets, bar an exact tie
+        ratio_rtol=1e-5,
+    )
+
+
+def test_the_train_steps_gradient_is_the_losss_and_the_bias_stays(model):
+    """The selection bias, which the step holds fixed against adamw's weight
+    decay: it comes back as it went in, where adamw alone would move it."""
+    old, opt_state = model.state["params"], model.state["opt_state"]
+    new = model.stepped[0]["params"]
+    decay, _ = jax.jit(model.tx.update)(jax.tree_util.tree_map(jnp.zeros_like, old), opt_state, old)
     seen = 0
-    for (path, w), g, old in zip(jax.tree_util.tree_flatten_with_path(want)[0],
-                                 jax.tree_util.tree_leaves(got["params"]), jax.tree_util.tree_leaves(state["params"])):
-        name = jax.tree_util.keystr(path)
-        if "router_bias" in name:
+    for name, w in old["layers"].items():
+        if "router_bias" in w:
             seen += 1
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(old), err_msg=name)
-            assert float(jnp.max(jnp.abs(w - old))) > 0  # adamw alone would have moved it
-            continue
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0, atol=5e-6, err_msg=name)
+            np.testing.assert_array_equal(np.asarray(new["layers"][name]["router_bias"]), np.asarray(w["router_bias"]))
+            assert float(jnp.max(jnp.abs(decay["layers"][name]["router_bias"]))) > 0, name
     assert seen == 4
-
-
-def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
-    telemetry.set_enabled(True)
-    try:
-        M.make_train_step(CFG, M.make_optimizer())
-        gauges = telemetry.gauges()
-    finally:
-        telemetry.set_enabled(False)
-    assert gauges["hybrid_lm.layers"] == 9 and gauges["hybrid_lm.experts_held"] == 4
-    assert gauges["hybrid_lm.matmul_params_per_token"] == CFG.matmul_params_per_token
-
-
-def test_the_named_scopes_reach_the_lowered_step():
-    tx = M.make_optimizer()
-    state = jax.eval_shape(lambda k: M.init_state(k, CFG, tx), jax.random.PRNGKey(0))
-    batch = jax.eval_shape(_batch)
-    text = jax.jit(M.make_train_step(CFG, tx)).lower(state, batch).as_text(debug_info=True)
-    for scope in ("layer0/mamba2", "layer1/moe_route", "layer1/moe_experts", "layer1/moe_shared",
-                  "layer5/gqa", "layer8/moe_experts", "lm_head"):
-        assert scope in text, scope
-
-
-@pytest.mark.parametrize("mesh_axes", [None, {"data": 2, "model": 2}])
-def test_the_step_reports_a_finite_loss_and_keeps_its_layout(mesh_axes):
-    mesh = make_mesh(mesh_axes, devices=jax.devices()[:4]) if mesh_axes else None
-    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh)
-    before = [(x.shape, x.dtype, x.sharding) for x in jax.tree_util.tree_leaves(state)]
-    batch = _batch(mesh)
-    step = jax.jit(M.make_train_step(cfg, tx, mesh=mesh), donate_argnums=0).lower(state, batch).compile()
-    losses = []
-    for _ in range(3):  # compiled once: a drifted layout would be an error, not a recompile
-        state, loss = step(state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assert int(state["step"]) == 3
-    for (path, x), (shape, dtype, sharding) in zip(jax.tree_util.tree_flatten_with_path(state)[0], before):
-        name = jax.tree_util.keystr(path)
-        assert (x.shape, x.dtype) == (shape, dtype), name
-        if mesh is not None:
-            assert x.sharding.is_equivalent_to(sharding, x.ndim), name
-
-
-def test_the_sharded_loss_equals_the_one_device_loss():
-    mesh = make_mesh({"data": 2, "model": 2}, devices=jax.devices()[:4])
-    params = M.init_params(jax.random.PRNGKey(0), CFG)
-    want = jax.jit(lambda p, b: M.loss_fn(p, b, CFG))(params, _batch())
-    sharded = M.init_state(jax.random.PRNGKey(0), CFG, M.make_optimizer(), mesh=mesh)["params"]
-    assert sharded["embed"].sharding.spec == sharded["head"].sharding.spec == P("model", None)
-    got = jax.jit(lambda p, b: M.loss_fn(p, b, CFG, mesh=mesh))(sharded, _batch(mesh))
-    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted_step(cfg):
-    return jax.jit(M.make_train_step(cfg, M.make_optimizer()), donate_argnums=0)
-
-
-def _train(cfg, state, steps, first=1):
-    step = _jitted_step(cfg)  # compiled once for both cases below
-    loss = None
-    for n in range(first, first + steps):
-        state, loss = step(state, _batch(seed=n))
-    return state, float(loss)
-
-
-@pytest.mark.parametrize("async_save", [True, False])
-def test_the_train_state_goes_through_the_checkpoint_manager_bit_exact(tmp_path, async_save):
-    """Save, restore into a destination from another seed, leaf for leaf
-    equal (218 leaves of the toy size, the per-head 8-float leaves and the
-    routers' biases among them), and the next step's loss equal to the
-    uninterrupted one."""
-    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
-    state, _ = _train(cfg, M.init_state(jax.random.PRNGKey(0), cfg, tx), 2)
-    saved = jax.tree_util.tree_map(np.asarray, state)
-    mgr = CheckpointManager(str(tmp_path / "ckpt"), save_interval_steps=1, keep_last=1, async_save=async_save)
-    assert mgr.save(2, {"train": StateDict(**state)})
-    mgr.wait()
-    _, want_loss = _train(cfg, state, 1, first=3)
-
-    dst = StateDict(**M.init_state(jax.random.PRNGKey(1), cfg, tx))
-    assert mgr.restore({"train": dst}) == 2
-    restored = dict(dst)
-    leaves = jax.tree_util.tree_flatten_with_path(saved)[0]
-    assert len(leaves) == 218 and min(a.size for _, a in leaves if a.ndim) == 8
-    for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(restored)):
-        np.testing.assert_array_equal(a, np.asarray(b), err_msg=jax.tree_util.keystr(path))
-    _, got_loss = _train(cfg, restored, 1, first=3)
-    assert got_loss == want_loss

@@ -11,7 +11,6 @@ widths, seeded.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import importlib.util
 import itertools
 import os
@@ -22,12 +21,20 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
+from torchsnapshot_tpu import telemetry
 from torchsnapshot_tpu.models import kda_mla_lm as M
 from torchsnapshot_tpu.ops import moe
 from torchsnapshot_tpu.ops.attention import causal_attention_route
 from torchsnapshot_tpu.ops.kda import kda_chunked, kda_recurrent
-from torchsnapshot_tpu.parallel import make_mesh
+from model_kit_cases import (  # noqa: F401  (the cases this file runs)
+    Model,
+    checkpoint_round_trip,
+    test_routing_stats_count_what_the_routers_chose,
+    test_the_named_scopes_reach_the_lowered_step,
+    test_the_step_puts_what_it_compiles_on_the_telemetry_bus,
+    test_the_step_reports_a_finite_loss_and_keeps_its_layout,
+    test_the_train_steps_gradient_is_the_losss,
+)
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "benchmarks", "chip", "reference", "kda_mla_lm.py")
@@ -490,14 +497,28 @@ def test_loss_and_every_gradient_leaf_agree_with_the_reference():
     assert all(not np.any(np.asarray(v["router_bias"])) for k, v in grads["layers"].items() if k.endswith("_moe"))
 
 
+# The blocks of the head and of a KDA mixer's heads need the head and a KDA
+# layer, not the whole stage: the dense KDA layer alone.
+ONE_KDA = dataclasses.replace(CFG, layers=(0,), expert_limits=(0.0,), shared_limits=(0.0,))
+
+
+@pytest.fixture(scope="module")
+def whole_head():
+    """The loss and gradient with the head and loss in one block and the
+    KDA mixer's heads in one block, the same for every case below."""
+    batch = _batch()
+    return jax.jit(jax.value_and_grad(lambda p: M.loss_fn(p, batch, dataclasses.replace(ONE_KDA, head_block=S))))(
+        _params(ONE_KDA))
+
+
 @pytest.mark.parametrize("head_block", [4, 8, 16, 32])
-def test_the_blocked_head_and_kda_head_blocks_are_the_whole(head_block, monkeypatch):
+def test_the_blocked_head_and_kda_head_blocks_are_the_whole(head_block, monkeypatch, whole_head):
     """Head and loss in blocks of positions, and the KDA mixer in blocks of
     heads (one or two of four a block): the same loss and gradient (float32
     sums in another order: 2e-6)."""
-    params, batch = _params(CFG), _batch()
-    want, gw = jax.jit(jax.value_and_grad(lambda p: M.loss_fn(p, batch, dataclasses.replace(CFG, head_block=S))))(params)
-    cfg = dataclasses.replace(CFG, head_block=head_block)
+    params, batch = _params(ONE_KDA), _batch()
+    want, gw = whole_head
+    cfg = dataclasses.replace(ONE_KDA, head_block=head_block)
     for kda_block in (1, 2):
         monkeypatch.setattr(M, "KDA_HEAD_BLOCK", kda_block)
         got, gg = jax.jit(jax.value_and_grad(lambda p: M.loss_fn(p, batch, cfg)))(params)
@@ -519,7 +540,7 @@ def test_keeping_the_kda_head_blocks_output_is_the_same_arithmetic(dtype, monkey
     """A KDA layer's checkpoint keeps ``kda_heads`` in place of running the
     head blocks forward again: the loss and every gradient leaf are the
     ones of the layer checkpoints that keep nothing, bit for bit."""
-    cfg = dataclasses.replace(CFG, dtype=dtype)
+    cfg = dataclasses.replace(ONE_KDA, dtype=dtype)
     f, params = _objective_value_and_grad(cfg)
     loss, grads = jax.jit(f)(params)
     monkeypatch.setattr(M, "KDA_LAYER_POLICY", jax.checkpoint_policies.nothing_saveable)
@@ -554,19 +575,31 @@ def test_the_kda_chunk_algebra_runs_forward_twice_a_step():
     assert solves == 24, solves
 
 
-def test_routing_stats_count_what_the_routers_chose():
-    params, toks = _params(CFG), _batch()["tokens"]
-    stats = M.routing_stats(params, toks, CFG)
-    chosen = R.chosen_experts(params, toks, **_ref_args(CFG))
-    assert len(chosen) == 5 and stats["held_counts"].shape == (5, 4)
+# The train state's plumbing needs each kind of layer once, not the stage:
+# the dense KDA layer, a KDA expert layer and the period's MLA layer.
+PLUMBING = dataclasses.replace(CFG, layers=(0, 4, 5), expert_limits=(0.0, 1.0, 0.75), shared_limits=(0.0, 1.5, 1.25))
+
+
+def _routes():
+    params, toks = _params(PLUMBING), _batch()["tokens"]
+    stats, chosen = jax.jit(lambda p, t: (M.routing_stats(p, t, PLUMBING), M.chosen_experts(p, t, PLUMBING)))(params, toks)
+    chosen = np.stack([np.asarray(i) for i in chosen])
+    want = np.stack([np.asarray(i).reshape(-1, CFG.top_k) for i in jax.jit(lambda p, t: R.chosen_experts(p, t, **_ref_args(PLUMBING)))(params, toks)])
+    assert len(want) == 2 and stats["held_counts"].shape == (2, 4) and chosen.shape == (2, B * S, CFG.top_k)
+    return stats, chosen, want
+
+
+def test_the_group_share_counts_the_tokens_that_kept_the_held_group(model):
+    """A token holding a held expert kept its group; the share kept is at
+    least that."""
+    stats, chosen, _ = model.routed
     for layer, ids in enumerate(chosen):
-        ids = np.asarray(ids).reshape(-1, CFG.top_k)
-        counts = [int(np.sum(ids == e)) for e in CFG.held]
-        np.testing.assert_array_equal(np.asarray(stats["held_counts"][layer]), counts)
-        assert float(stats["held_share"][layer]) == pytest.approx(sum(counts) / ids.size)
-        # a token holding a held expert kept its group; the share kept is at least that
         assert float(stats["group_share"][layer]) >= np.mean(np.any(ids < 4, axis=-1)) - 1e-9
-    assert np.all((np.asarray(stats["tile_fill"]) > 0) & (np.asarray(stats["tile_fill"]) <= 1))
+
+
+_LOADS = dataclasses.replace(CFG, vocab_size=4096, d_model=64, n_experts=512, top_k=8, n_group=8, topk_group=4,
+                             held=tuple(range(8)), expert_limits=(), shared_limits=(), dtype=jnp.bfloat16)
+_loads_stats = jax.jit(lambda p, t: M.routing_stats(p, t, _LOADS))  # compiled once for the seeds below
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5])
@@ -577,11 +610,10 @@ def test_the_init_keeps_random_routers_near_even_loads(seed):
     x their even share in every layer (128 assignments expected a layer,
     over 1024 tokens of a vocabulary of 4096: a vocabulary of 96 would
     give the routers 96 distinct tokens to spread)."""
-    cfg = dataclasses.replace(CFG, vocab_size=4096, d_model=64, n_experts=512, top_k=8, n_group=8, topk_group=4,
-                              held=tuple(range(8)), expert_limits=(), shared_limits=(), dtype=jnp.bfloat16)
+    cfg = _LOADS
     params = M.init_params(jax.random.PRNGKey(seed), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(seed + 50), (4, 256), 0, cfg.vocab_size)
-    stats = M.routing_stats(params, toks, cfg)
+    stats = _loads_stats(params, toks)
     even = len(cfg.held) / cfg.n_experts
     assert np.all((np.asarray(stats["group_share"]) > 0.3) & (np.asarray(stats["group_share"]) < 0.7)), stats
     share = np.asarray(stats["held_share"]) / even
@@ -597,17 +629,6 @@ def test_a_bad_layout_is_refused():
         dataclasses.replace(CFG, expert_limits=(1.0,))
     with pytest.raises(ValueError, match="groups of"):
         dataclasses.replace(CFG, topk_group=1, top_k=8)
-
-
-def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
-    telemetry.set_enabled(True)
-    try:
-        M.make_train_step(CFG, M.make_optimizer())
-        gauges = telemetry.gauges()
-    finally:
-        telemetry.set_enabled(False)
-    assert gauges["kda_mla_lm.layers"] == 6 and gauges["kda_mla_lm.experts_held"] == 4
-    assert gauges["kda_mla_lm.matmul_params_per_token"] == CFG.matmul_params_per_token
 
 
 def test_the_traced_step_reports_the_bytes_the_kda_checkpoints_keep():
@@ -626,81 +647,35 @@ def test_the_traced_step_reports_the_bytes_the_kda_checkpoints_keep():
     assert gauges["kda_mla_lm.kda_kernel_layers"] == 0
 
 
-def test_the_named_scopes_reach_the_lowered_step():
-    tx = M.make_optimizer()
-    state = jax.eval_shape(lambda k: M.init_state(k, CFG, tx), jax.random.PRNGKey(0))
-    text = jax.jit(M.make_train_step(CFG, tx)).lower(state, jax.eval_shape(_batch)).as_text(debug_info=True)
-    for scope in ("kda_proj", "kda_conv", "kda_gate", "kda_intra", "kda_inter", "kda_out", "mla_proj", "mla_attn",
-                  "mla_gate", "moe_route", "moe_route/group_select", "moe_experts", "moe_shared", "lm_head"):
-        assert scope in text, scope
+@pytest.fixture(scope="module")
+def model():
+    return Model(
+        M, PLUMBING, _batch,
+        gauges={"kda_mla_lm.layers": 3, "kda_mla_lm.experts_held": 4,
+                "kda_mla_lm.matmul_params_per_token": PLUMBING.matmul_params_per_token},
+        scopes=("kda_proj", "kda_conv", "kda_gate", "kda_intra", "kda_inter", "kda_out", "mla_proj", "mla_attn",
+                "mla_gate", "moe_route", "moe_route/group_select", "moe_experts", "moe_shared", "lm_head"),
+        leaves=(188, 4),  # 62 parameters, both moments, the step and the count
+        still=("['layers']['kda_moe']['router_bias']", "['layers']['mla_moe']['router_bias']"),
+        update_atol=5e-6,  # the hybrid's bound: two programs that sum in other orders (one leaf read 3.6e-6)
+        routes=_routes,
+    )
 
 
-def test_the_train_steps_gradient_is_the_losss_and_the_bias_stays():
-    tx = M.make_optimizer(1e-2)
-    state = M.init_state(jax.random.PRNGKey(0), CFG, tx)
-    batch = _batch()
-    new, loss = jax.jit(M.make_train_step(CFG, tx))(state, batch)
-    np.testing.assert_allclose(float(loss), float(M.loss_fn(state["params"], batch, CFG)), rtol=1e-6)
-    assert int(new["step"]) == 1
-    before = dict(jax.tree_util.tree_flatten_with_path(state["params"])[0])
-    still = sorted(jax.tree_util.keystr(p) for p, b in jax.tree_util.tree_flatten_with_path(new["params"])[0]
-                   if np.array_equal(before[p], b))
-    # every leaf moves but the selection bias: held fixed against adamw's weight decay
-    assert still == ["['layers']['kda_moe']['router_bias']", "['layers']['mla_moe']['router_bias']"]
+def test_the_train_steps_gradient_is_the_losss_and_the_bias_stays(model):
+    """The selection bias, which the step holds fixed against adamw's weight
+    decay: it comes back as it went in, where adamw alone would move it."""
+    old, opt_state = model.state["params"], model.state["opt_state"]
+    new = model.stepped[0]["params"]
+    decay, _ = jax.jit(model.tx.update)(jax.tree_util.tree_map(jnp.zeros_like, old), opt_state, old)
+    for kind in ("kda_moe", "mla_moe"):
+        np.testing.assert_array_equal(np.asarray(new["layers"][kind]["router_bias"]),
+                                      np.asarray(old["layers"][kind]["router_bias"]))
+        assert float(jnp.max(jnp.abs(decay["layers"][kind]["router_bias"]))) > 0, kind
 
 
-@pytest.mark.parametrize("mesh_axes", [None, {"data": 2, "model": 2}])
-def test_the_step_reports_a_finite_loss_and_keeps_its_layout(mesh_axes):
-    mesh = make_mesh(mesh_axes, devices=jax.devices()[:4]) if mesh_axes else None
-    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh)
-    before = [(x.shape, x.dtype, x.sharding) for x in jax.tree_util.tree_leaves(state)]
-    batch = _batch(mesh)
-    step = jax.jit(M.make_train_step(cfg, tx, mesh=mesh), donate_argnums=0).lower(state, batch).compile()
-    losses = []
-    for _ in range(2):  # compiled once: a drifted layout would be an error, not a recompile
-        state, loss = step(state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and int(state["step"]) == 2
-    for (path, x), (shape, dtype, sharding) in zip(jax.tree_util.tree_flatten_with_path(state)[0], before):
-        name = jax.tree_util.keystr(path)
-        assert (x.shape, x.dtype) == (shape, dtype), name
-        if mesh is not None:
-            assert x.sharding.is_equivalent_to(sharding, x.ndim), name
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted_step(cfg):
-    return jax.jit(M.make_train_step(cfg, M.make_optimizer()), donate_argnums=0)
-
-
-def _train(cfg, state, steps, first=1):
-    step = _jitted_step(cfg)
-    loss = None
-    for n in range(first, first + steps):
-        state, loss = step(state, _batch(seed=n))
-    return state, float(loss)
-
-
-def test_save_restore_and_the_next_step_is_the_uninterrupted_runs(tmp_path):
+def test_save_restore_and_the_next_step_is_the_uninterrupted_runs(model, tmp_path):
     """Two steps, an async save through ``CheckpointManager``, a restore
-    into a state from another seed (every leaf equal, 188 of them: 62
-    parameters, both moments and the step and count), and the next step's
+    into a state from another seed (every leaf equal), and the next step's
     loss equal to the uninterrupted run's."""
-    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
-    state, _ = _train(cfg, M.init_state(jax.random.PRNGKey(0), cfg, tx), 2)
-    saved = jax.tree_util.tree_map(np.asarray, state)
-    mgr = CheckpointManager(str(tmp_path / "ckpt"), save_interval_steps=1, keep_last=1, async_save=True)
-    assert mgr.save(2, {"train": StateDict(**state)})
-    mgr.wait()
-    _, want_loss = _train(cfg, state, 1, first=3)
-    dst = StateDict(**M.init_state(jax.random.PRNGKey(1), cfg, tx))
-    assert mgr.restore({"train": dst}) == 2
-    restored = dict(dst)
-    leaves = jax.tree_util.tree_flatten_with_path(saved)[0]
-    assert len(leaves) == 188
-    for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(restored)):
-        np.testing.assert_array_equal(a, np.asarray(b), err_msg=jax.tree_util.keystr(path))
-    _, got_loss = _train(cfg, restored, 1, first=3)
-    assert got_loss == want_loss
+    checkpoint_round_trip(model, tmp_path / "ckpt", async_save=True)

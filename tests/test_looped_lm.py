@@ -16,10 +16,18 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from torchsnapshot_tpu import CheckpointManager, StateDict, telemetry
+from torchsnapshot_tpu import CheckpointManager, StateDict
 from torchsnapshot_tpu.models import looped_lm as M, transformer as T
 from torchsnapshot_tpu.ops.attention import causal_attention_route
 from torchsnapshot_tpu.parallel import make_mesh
+from model_kit_cases import (  # noqa: F401  (the cases this file runs)
+    Model,
+    test_the_named_scopes_reach_the_lowered_step,
+    test_the_step_puts_what_it_compiles_on_the_telemetry_bus,
+    test_the_step_reports_a_finite_loss_and_keeps_its_layout,
+    test_the_train_state_goes_through_the_checkpoint_manager_bit_exact,
+    test_the_train_steps_gradient_is_the_losss,
+)
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "benchmarks", "chip", "reference", "looped_lm.py")
@@ -118,23 +126,6 @@ def test_loss_and_every_gradient_leaf_agree_with_the_reference(ut_steps, n_layer
         assert err <= 5e-5, (name, err)
 
 
-def test_the_train_steps_gradient_is_the_losss():
-    """The step differentiates the tree the passes read (matrices cast once
-    a step); in float32 the cast is the identity and the update must be the
-    one ``loss_fn``'s gradient gives."""
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), CFG, tx)
-    batch = _batch()
-    grads = jax.grad(lambda p: M.loss_fn(p, batch, CFG))(state["params"])
-    updates, _ = tx.update(grads, state["opt_state"], state["params"])
-    want = jax.tree_util.tree_map(lambda p, u: p + u, state["params"], updates)
-    got, _ = jax.jit(M.make_train_step(CFG, tx))(state, batch)
-    for (path, w), g in zip(jax.tree_util.tree_flatten_with_path(want)[0],
-                            jax.tree_util.tree_leaves(got["params"])):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0, atol=2e-6,
-                                   err_msg=jax.tree_util.keystr(path))
-
-
 def test_one_pass_is_the_first_pass_of_four_and_exits_there():
     params, tokens = _params(CFG), _batch()["tokens"]
     four, _ = M.forward(params, tokens, CFG)
@@ -213,38 +204,15 @@ def test_the_blockwise_route_runs_the_same_block():
     assert float(err) <= 2e-5
 
 
-def test_the_step_puts_what_it_compiles_on_the_telemetry_bus():
-    telemetry.set_enabled(True)
-    try:
-        M.make_train_step(CFG, M.make_optimizer())
-        gauges = telemetry.gauges()
-    finally:
-        telemetry.set_enabled(False)
-    assert gauges["looped_lm.ut_steps"] == 4
-    assert gauges["looped_lm.layer_applications"] == 12
-    assert gauges["looped_lm.matmul_params_per_token"] == CFG.matmul_params_per_token
-
-
-@pytest.mark.parametrize("mesh_name", [None, "2x2"])
-def test_the_step_reports_a_finite_loss_and_keeps_its_layout(mesh_name):
-    mesh = _mesh(mesh_name) if mesh_name else None
-    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16)
-    tx = M.make_optimizer()
-    state = M.init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh)
-    before = [(x.shape, x.dtype, x.sharding) for x in jax.tree_util.tree_leaves(state)]
-    batch = _batch(mesh)
-    step = jax.jit(M.make_train_step(cfg, tx, mesh=mesh), donate_argnums=0).lower(state, batch).compile()
-    losses = []
-    for _ in range(3):  # compiled once: a drifted layout would be an error, not a recompile
-        state, loss = step(state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assert int(state["step"]) == 3
-    for (path, x), (shape, dtype, sharding) in zip(jax.tree_util.tree_flatten_with_path(state)[0], before):
-        name = jax.tree_util.keystr(path)
-        assert (x.shape, x.dtype) == (shape, dtype), name
-        if mesh is not None:
-            assert x.sharding.is_equivalent_to(sharding, x.ndim), name
+@pytest.fixture(scope="module")
+def model():
+    return Model(
+        M, CFG, _batch,
+        gauges={"looped_lm.ut_steps": 4, "looped_lm.layer_applications": 12,
+                "looped_lm.matmul_params_per_token": CFG.matmul_params_per_token},
+        scopes=("pass", "block", "exit_gate", "lm_head"),
+        leaves=(50, 32),
+    )
 
 
 # As tests/test_transformer_step.py holds the transformer: float32 leaves
@@ -269,26 +237,6 @@ def _train(cfg, tx, mesh, state, steps, first=1):
     for n in range(first, first + steps):
         state, loss = step(state, _batch(mesh, seed=n))
     return state, float(loss)
-
-
-def test_the_train_state_goes_through_the_checkpoint_manager_bit_exact(tmp_path):
-    """Async save, restore into a destination from another seed, leaf for
-    leaf equal, and the next step's loss equal to the uninterrupted one."""
-    cfg, tx = dataclasses.replace(CFG, dtype=jnp.bfloat16), M.make_optimizer()
-    state, _ = _train(cfg, tx, None, M.init_state(jax.random.PRNGKey(0), cfg, tx), 2)
-    saved = jax.tree_util.tree_map(np.asarray, state)
-    mgr = CheckpointManager(str(tmp_path / "ckpt"), save_interval_steps=1, keep_last=1, async_save=True)
-    assert mgr.save(2, {"train": StateDict(**state)})
-    mgr.wait()
-    _, want_loss = _train(cfg, tx, None, state, 1, first=3)
-
-    dst = StateDict(**M.init_state(jax.random.PRNGKey(1), cfg, tx))
-    assert mgr.restore({"train": dst}) == 2
-    restored = dict(dst)
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(saved)[0], jax.tree_util.tree_leaves(restored)):
-        np.testing.assert_array_equal(a, np.asarray(b), err_msg=jax.tree_util.keystr(path))
-    _, got_loss = _train(cfg, tx, None, restored, 1, first=3)
-    assert got_loss == want_loss
 
 
 def test_a_state_saved_under_2x2_resumes_under_1x4(tmp_path):

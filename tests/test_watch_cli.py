@@ -129,6 +129,40 @@ def test_publish_and_read_roundtrip_single_store():
         health.clear()
 
 
+def test_watch_ticks_bounds_the_run_and_exits_cleanly():
+    """``watch --ticks N`` renders N frames of the live fleet, closes its
+    store connection and exits 0."""
+    from torchsnapshot_tpu.dist_store import TCPStore
+
+    store = TCPStore("127.0.0.1", is_server=True, timeout=10.0)
+    pub = None
+    try:
+        health.clear()
+        health.update(phase="stage", written_bytes=123)
+        pub = health.HeartbeatPublisher(
+            store, rank=0, op="take", path="/tmp/s", cadence_s=0.05
+        ).start()
+        watch = subprocess.run(
+            [
+                sys.executable, "-m", "torchsnapshot_tpu", "watch", store.addr,
+                "--interval", "0.05", "--ticks", "2",
+            ],
+            capture_output=True,
+            text=True,
+            timeout=120,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+    finally:
+        if pub is not None:
+            pub.stop()
+        store.close()
+        health.clear()
+    assert watch.returncode == 0, watch.stderr[-2000:]
+    ticks = [ln for ln in watch.stdout.splitlines() if ln.startswith("--- watch ")]
+    assert ticks == [f"--- watch {store.addr} tick {n}" for n in (1, 2)]
+    assert "stage" in watch.stdout and "unreachable" not in watch.stdout
+
+
 # ------------------------------------------------- live w2 watch drill
 
 
@@ -152,8 +186,12 @@ def _throttled_take_worker(rank: int, world_size: int, root: str):
         with open(os.path.join(root, "store_addr.txt"), "w") as f:
             f.write(store.bootstrap_addr)
     # Let the watcher connect before the take begins (it must learn the
-    # replica set from live responses to survive the leader kill).
-    time.sleep(0.7)
+    # replica set from live responses to survive the leader kill): its
+    # first write is the forensic request for rank 1 (--dump 1), which
+    # stays in the store until rank 1's watchdog serves it mid-take.
+    from torchsnapshot_tpu.telemetry.forensics import FORENSIC_REQ_PREFIX
+
+    store.get(f"{FORENSIC_REQ_PREFIX}1", timeout=120.0)
     rng = np.random.default_rng(100 + rank)
     state = {
         "model": StateDict(
@@ -202,30 +240,42 @@ def test_watch_observes_live_take_flags_straggler_and_survives_failover(
 
     t = threading.Thread(target=drill)
     t.start()
+    watch = None
     try:
+        # Every wait here ends on an event of the drill, not on the wall
+        # clock: under a loaded machine the job's start, the straggler's
+        # stall, the forensic answer and the leader kill all come late,
+        # and a watcher that stops after a fixed number of frames can
+        # stop before them. The drill's own timeout bounds them all.
         addr_file = os.path.join(str(tmp_path), "store_addr.txt")
-        deadline = time.monotonic() + 60
         while not os.path.exists(addr_file):
-            assert time.monotonic() < deadline, "store addr never published"
-            assert t.is_alive() or not errors, errors
+            assert t.is_alive(), ("store addr never published", errors)
             time.sleep(0.05)
         addr = open(addr_file).read().strip()
-        watch = subprocess.run(
-            [
-                sys.executable, "-m", "torchsnapshot_tpu", "watch", addr,
-                "--interval", "0.15", "--stall", "0.5", "--ticks", "80",
-                "--dump", "1",
-            ],
-            capture_output=True,
-            text=True,
-            timeout=120,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        )
+        # Frames go to files: a pipe nobody reads until the end would
+        # fill and block the watcher mid-take.
+        with open(tmp_path / "watch.out", "w") as stdout, open(tmp_path / "watch.err", "w") as stderr:
+            watch = subprocess.Popen(
+                [
+                    sys.executable, "-m", "torchsnapshot_tpu", "watch", addr,
+                    "--interval", "0.15", "--stall", "0.5", "--dump", "1",
+                ],
+                stdout=stdout,
+                stderr=stderr,
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            )
+        t.join(timeout=240)
+        assert not t.is_alive(), "the drill outlived its own timeout"
+        # The watcher rode out the whole take, the leader kill among it.
+        alive = watch.poll() is None
     finally:
+        if watch is not None:
+            watch.terminate()
+            watch.wait(timeout=60)
         t.join(timeout=120)
+    out, err = (tmp_path / "watch.out").read_text(), (tmp_path / "watch.err").read_text()
+    assert alive, err[-2000:]
     assert not errors, errors
-    assert watch.returncode == 0, watch.stderr[-2000:]
-    out = watch.stdout
     # End-to-end: the take committed, each rank failed over exactly once
     # (the leader kill really happened mid-take).
     assert os.path.exists(tmp_path / "cur" / ".snapshot_metadata")
@@ -258,7 +308,7 @@ def test_watch_observes_live_take_flags_straggler_and_survives_failover(
     # unreachable frame was followed by a later successful one. (After
     # the JOB exits, the whole tier legitimately goes down — trailing
     # unreachable frames are the truthful render, not a failure.)
-    adopted = "adopted leader" in watch.stderr
+    adopted = "adopted leader" in err
     success_idx = [
         i for i, fr in enumerate(frames)
         if "take" in fr or "no in-flight operation" in fr
@@ -273,7 +323,7 @@ def test_watch_observes_live_take_flags_straggler_and_survives_failover(
     )
     assert adopted or recovered or not unreachable_idx or (
         min(unreachable_idx) > max(success_idx)
-    ), watch.stderr[-2000:]
+    ), err[-2000:]
 
     # --- ISSUE 13: stall forensics rode the same drill ---------------
     from torchsnapshot_tpu.telemetry import forensics

@@ -64,15 +64,14 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import telemetry
-from ..ops.attention import BlockDiffusionMask, causal_attention_route
+from ..ops.attention import BlockDiffusionMask
 from ..ops.moe import held_row_tile, held_tile_stats, softmax_topk_routed
-from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
+from . import kit
+from .kit import constrainer, make_optimizer, mm, rmsnorm  # noqa: F401  (make_optimizer: the same optimizer)
 
 Params = Dict[str, Any]
-_ATTN_BLOCK = 512  # the tiling every route targets, as TransformerConfig's default
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,9 +197,7 @@ def init_params(rng: jax.Array, cfg: BlockDiffusionLMConfig) -> Params:
 def param_specs(cfg: BlockDiffusionLMConfig) -> Params:
     """PartitionSpecs on a ('data','model') mesh: ``embed`` and ``head`` over
     the vocabulary, every layer leaf replicated (module docstring)."""
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    specs = jax.tree_util.tree_map(lambda x: P(*([None] * x.ndim)), shapes)
-    return {**specs, "embed": P("model", None), "head": P("model", None)}
+    return kit.vocab_sharded(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
 
 
 # Leaves that feed a matmul in the compute dtype; the router's product is
@@ -212,15 +209,7 @@ def compute_params(params: Params, cfg: BlockDiffusionLMConfig) -> Params:
     """The tree the layers read: the matrices and the head in the compute
     dtype, cast once a step; the embedding, the scales and the router as
     stored."""
-    layers = {k: v.astype(cfg.dtype) if k in _MATRICES else v for k, v in params["layers"].items()}
-    return {**params, "layers": layers, "head": params["head"].astype(cfg.dtype)}
-
-
-def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """Statistics, scaling and result in float32."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    return kit.cast_matrices(params, cfg.dtype, _MATRICES)
 
 
 def _rope(x: jax.Array, ids: jax.Array, theta: float) -> jax.Array:
@@ -233,10 +222,6 @@ def _rope(x: jax.Array, ids: jax.Array, theta: float) -> jax.Array:
     cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _mm(x, w):
-    return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
 def expert_tile(cfg: BlockDiffusionLMConfig, positions: int) -> int:
@@ -257,19 +242,13 @@ def attention_mask(cfg: BlockDiffusionLMConfig, S: int) -> BlockDiffusionMask:
 def _attention_route(cfg: BlockDiffusionLMConfig, mesh: Optional[Mesh], B: int, S: int):
     """The shared dispatch's "auto" under the block-diffusion mask: what the
     backend, the mesh and S allow."""
-    return causal_attention_route("auto", _ATTN_BLOCK, cfg.n_heads, mesh, B, 2 * S, mask=attention_mask(cfg, S))
+    return kit.attention_route(cfg.n_heads, mesh, B, 2 * S, mask=attention_mask(cfg, S))
 
 
 def select_attention(cfg: BlockDiffusionLMConfig, mesh: Optional[Mesh], B: int, S: int) -> str:
     """The name of the attention route the layers run for this mesh and
     sequences of ``S`` tokens (``2 S`` positions)."""
     return _attention_route(cfg, mesh, B, S)[0]
-
-
-def _constrainer(mesh: Optional[Mesh]):
-    if mesh is None:
-        return lambda x, spec: x
-    return lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def draw_noise(key: jax.Array, B: int, S: int, cfg: BlockDiffusionLMConfig) -> Tuple[jax.Array, jax.Array]:
@@ -291,7 +270,7 @@ def _run_layers(cparams: Params, tokens: jax.Array, masked: jax.Array, cfg: Bloc
     ``cparams`` is ``compute_params``' tree."""
     c = cfg
     B, S = tokens.shape
-    cs = _constrainer(mesh)
+    cs = constrainer(mesh)
     _, attend = _attention_route(c, mesh, B, S)
     ids = jnp.tile(jnp.arange(S), 2)  # both copies carry 0 .. S-1
     stream = P("data", None, None)
@@ -299,35 +278,28 @@ def _run_layers(cparams: Params, tokens: jax.Array, masked: jax.Array, cfg: Bloc
     @jax.checkpoint
     def layer(h, w):
         with jax.named_scope("attn_bd"):
-            a = _rmsnorm(h, w["ln1_scale"], c.norm_eps).astype(c.dtype)
+            a = rmsnorm(h, w["ln1_scale"], c.norm_eps).astype(c.dtype)
 
             def heads(t, n, scale):
                 t = t.reshape(B, 2 * S, n, c.head_dim)
                 if scale is not None:
-                    t = _rope(_rmsnorm(t, scale, c.norm_eps), ids, c.rope_theta)
+                    t = _rope(rmsnorm(t, scale, c.norm_eps), ids, c.rope_theta)
                 return t.astype(c.dtype)
 
             out = attend(
-                heads(_mm(a, w["q"]), c.n_heads, w["q_norm_scale"]),
-                heads(_mm(a, w["k"]), c.n_kv_heads, w["k_norm_scale"]),
-                heads(_mm(a, w["v"]), c.n_kv_heads, None),
+                heads(mm(a, w["q"]), c.n_heads, w["q_norm_scale"]),
+                heads(mm(a, w["k"]), c.n_kv_heads, w["k_norm_scale"]),
+                heads(mm(a, w["v"]), c.n_kv_heads, None),
             )
-            h = cs(h + _mm(out.reshape(B, 2 * S, c.n_heads * c.head_dim), w["o"]), stream)
-        b = _rmsnorm(h, w["ln2_scale"], c.norm_eps)
+            h = cs(h + mm(out.reshape(B, 2 * S, c.n_heads * c.head_dim), w["o"]), stream)
+        b = rmsnorm(h, w["ln2_scale"], c.norm_eps)
         y, chosen = softmax_topk_routed(w, b, top_k=c.top_k, held=c.held)
         return cs(h + y, stream), chosen
 
     x = jnp.concatenate([tokens, jnp.where(masked, c.mask_id, tokens)], axis=1)
     h = cs(cparams["embed"][x].astype(jnp.float32), stream)
     h, chosen = jax.lax.scan(layer, h, cparams["layers"])
-    return _rmsnorm(h[:, S:], cparams["ln_f_scale"], c.norm_eps), chosen
-
-
-def _head(h: jax.Array, head: jax.Array, cs) -> jax.Array:
-    """(B, S, vocab) float32 logits."""
-    with jax.named_scope("lm_head"):
-        logits = jnp.matmul(h.astype(head.dtype), head.T, preferred_element_type=jnp.float32)
-        return cs(logits, P("data", None, "model"))
+    return rmsnorm(h[:, S:], cparams["ln_f_scale"], c.norm_eps), chosen
 
 
 def forward(
@@ -336,7 +308,7 @@ def forward(
     """(B, S) int32 tokens and the (B, S) bool of masked positions ->
     (B, S, vocab) float32 logits at the noised copy's positions."""
     p = compute_params(params, cfg)
-    return _head(_run_layers(p, tokens, masked, cfg, mesh)[0], p["head"], _constrainer(mesh))
+    return kit.head(_run_layers(p, tokens, masked, cfg, mesh)[0], p["head"], constrainer(mesh))
 
 
 def chosen_experts(params: Params, tokens: jax.Array, masked: jax.Array, cfg: BlockDiffusionLMConfig) -> jax.Array:
@@ -366,13 +338,13 @@ def routing_stats(params: Params, tokens: jax.Array, masked: jax.Array, cfg: Blo
 
 
 def _objective(cparams: Params, tokens: jax.Array, key: jax.Array, cfg: BlockDiffusionLMConfig, mesh: Optional[Mesh]):
-    cs = _constrainer(mesh)
+    cs = constrainer(mesh)
     B, S = tokens.shape
     masked, t = draw_noise(key, B, S, cfg)
 
     @jax.checkpoint
     def head_and_ce(h, head):
-        logits = _head(h, head, cs)
+        logits = kit.head(h, head, cs)
         at_token = jnp.take_along_axis(logits, tokens[..., None], axis=-1)[..., 0]
         ce = jax.nn.logsumexp(logits, axis=-1) - at_token
         return jnp.sum(jnp.where(masked, ce / t, 0.0)) / (B * S)
@@ -399,85 +371,41 @@ def noise_key(state: Dict[str, Any]) -> jax.Array:
     return jax.random.fold_in(state["rng"], state["step"])
 
 
-def state_specs(cfg: BlockDiffusionLMConfig, state: Dict[str, Any]) -> Dict[str, Any]:
-    """PartitionSpec pytree matching ``init_state``'s output: adam moments
-    inherit their parameter's spec, the scalars and the key replicated."""
-    from ..parallel.mesh import optax_state_specs
+_KEY_SPEC = {"rng": P(None)}  # the noise key, replicated
 
-    p_specs = param_specs(cfg)
-    return {
-        "params": p_specs,
-        "opt_state": optax_state_specs(p_specs, state["opt_state"]),
-        "step": P(),
-        "rng": P(None),
-    }
+
+def state_specs(cfg: BlockDiffusionLMConfig, state: Dict[str, Any]) -> Dict[str, Any]:
+    """PartitionSpec pytree matching ``init_state``'s output
+    (``kit.state_specs``), the key replicated."""
+    return kit.state_specs(param_specs(cfg), state, _KEY_SPEC)
 
 
 def init_state(
-    rng: jax.Array,
-    cfg: BlockDiffusionLMConfig,
-    tx: optax.GradientTransformation,
-    *,
-    mesh: Optional[Mesh] = None,
+    rng: jax.Array, cfg: BlockDiffusionLMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Dict[str, Any]:
     """{params, opt_state, step, rng}, the whole of it placed per
     ``state_specs`` under a mesh. ``rng`` is a raw ``PRNGKey``; half of it
     becomes the noise key, raw key data (``uint32[2]``) and a leaf like
     any other."""
     k_params, k_noise = jax.random.split(rng)
-    params = init_params(k_params, cfg)
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        params = shard_pytree(params, param_specs(cfg), mesh)
-    state = {
-        "params": params,
-        "opt_state": tx.init(params),
-        "step": jnp.zeros((), jnp.int32),
-        "rng": k_noise,
-    }
-    if mesh is not None:
-        state = shard_pytree(state, state_specs(cfg, state), mesh)
-    return state
+    return kit.init_state(init_params(k_params, cfg), param_specs(cfg), tx, mesh, {"rng": k_noise}, _KEY_SPEC)
 
 
 def make_train_step(
-    cfg: BlockDiffusionLMConfig,
-    tx: optax.GradientTransformation,
-    *,
-    mesh: Optional[Mesh] = None,
+    cfg: BlockDiffusionLMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Callable:
-    """Returns train_step(state, batch) -> (state, loss), ready to jit.
-    ``batch["tokens"]`` is read, ``batch["targets"]`` is not. Under a mesh
-    the returned state is pinned to ``state_specs``."""
-    # What is about to be compiled, on the bus for `stats -v` and the exporters.
-    telemetry.gauge_set("block_diffusion_lm.layers", cfg.n_layers)
-    telemetry.gauge_set("block_diffusion_lm.experts_held", len(cfg.held))
-    telemetry.gauge_set("block_diffusion_lm.matmul_params_per_token", cfg.matmul_params_per_token)
-
-    def train_step(state, batch):
-        # Gradients are taken with respect to the tree the layers read, so
-        # the matrices' come in the compute dtype (looped_lm.py says why);
-        # adamw's moments and update are float32.
-        params = state["params"]
-        loss, grads = jax.value_and_grad(_objective)(
-            compute_params(params, cfg), batch["tokens"], noise_key(state), cfg, mesh
-        )
-        updates, opt_state = tx.update(grads, state["opt_state"], params)
-        new_state = {
-            "params": optax.apply_updates(params, updates),
-            "opt_state": opt_state,
-            "step": state["step"] + 1,
-            # Handed on, so that two jobs that reach one step number from
-            # different keys, or from one key by another path, do not share masks.
-            "rng": jax.random.split(state["rng"])[0],
-        }
-        if mesh is not None:
-            new_state = jax.tree_util.tree_map(
-                lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec)),
-                new_state,
-                state_specs(cfg, new_state),
-            )
-        return new_state, loss
-
-    return train_step
+    """Returns train_step(state, batch) -> (state, loss), ready to jit
+    (``kit.make_train_step``). ``batch["tokens"]`` is read, ``batch["targets"]``
+    is not. The noise key is handed on, so that two jobs that reach one step
+    number from different keys, or from one key by another path, do not
+    share masks."""
+    gauges = {
+        "block_diffusion_lm.layers": cfg.n_layers,
+        "block_diffusion_lm.experts_held": len(cfg.held),
+        "block_diffusion_lm.matmul_params_per_token": cfg.matmul_params_per_token,
+    }
+    return kit.make_train_step(
+        _objective, lambda p: compute_params(p, cfg),
+        lambda state, batch: (batch["tokens"], noise_key(state), cfg, mesh), tx, mesh,
+        param_specs(cfg), gauges, extra=lambda state: {"rng": jax.random.split(state["rng"])[0]}, extra_specs=_KEY_SPEC,
+    )

@@ -59,23 +59,19 @@ cell runs this family).
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import telemetry
-from ..ops.attention import causal_attention_route
 from ..ops.cca import cca_qkv
 from ..ops.moe import held_row_tile, held_tile_stats, mlp_top1_routed
-from .block_diffusion_lm import _constrainer, _mm, _rmsnorm  # the same float32 norm, matmul and constraint
-from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
+from . import kit
+from .kit import constrainer, make_optimizer, mm, rmsnorm  # noqa: F401  (make_optimizer: the same optimizer)
 
 Params = Dict[str, Any]
-_ATTN_BLOCK = 512  # the tiling every route targets, as TransformerConfig's default
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,9 +215,7 @@ def init_params(rng: jax.Array, cfg: CCAMoELMConfig) -> Params:
 def param_specs(cfg: CCAMoELMConfig) -> Params:
     """PartitionSpecs on a ('data','model') mesh: ``embed`` over the
     vocabulary, every layer leaf replicated (module docstring)."""
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    specs = jax.tree_util.tree_map(lambda x: P(*([None] * x.ndim)), shapes)
-    return {**specs, "embed": P("model", None)}
+    return kit.vocab_sharded(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
 
 
 # Leaves that feed a matmul in the compute dtype; the convolutions' and the
@@ -233,8 +227,7 @@ def compute_params(params: Params, cfg: CCAMoELMConfig) -> Params:
     """The tree the layers read: the matrices in the compute dtype, cast
     once a step; the embedding (the head casts it: module docstring), the
     scales, the convolutions and the router as stored."""
-    layers = {k: v.astype(cfg.dtype) if k in _MATRICES else v for k, v in params["layers"].items()}
-    return {**params, "layers": layers}
+    return kit.cast_matrices(params, cfg.dtype, _MATRICES, head=False)
 
 
 def expert_tile(cfg: CCAMoELMConfig, positions: int) -> int:
@@ -244,13 +237,9 @@ def expert_tile(cfg: CCAMoELMConfig, positions: int) -> int:
     return held_row_tile(positions)
 
 
-def _attention_route(cfg: CCAMoELMConfig, mesh: Optional[Mesh], B: int, S: int):
-    return causal_attention_route("auto", _ATTN_BLOCK, cfg.n_heads, mesh, B, S)
-
-
 def select_attention(cfg: CCAMoELMConfig, mesh: Optional[Mesh], B: int, S: int) -> str:
     """The name of the attention route the layers run for this mesh and shape."""
-    return _attention_route(cfg, mesh, B, S)[0]
+    return kit.route_name(cfg.n_heads, mesh, B, S)
 
 
 def _res_scale(w: Params, sub: str, x: jax.Array, branch: jax.Array) -> jax.Array:
@@ -270,13 +259,13 @@ def layer(
     c = cfg
     B, S, _ = x.shape
     stream = P("data", None, None)
-    a = _rmsnorm(x, w["ln1_scale"], c.norm_eps).astype(c.dtype)
+    a = rmsnorm(x, w["ln1_scale"], c.norm_eps).astype(c.dtype)
     q, k, v = cca_qkv(w, a, rope_theta=c.rope_theta, rotary_dim=c.rotary_dim)
     with jax.named_scope("cca_attn"):
         out = attend(q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype))
-        out = _mm(out.reshape(B, S, c.n_heads * c.head_dim), w["o"])
+        out = mm(out.reshape(B, S, c.n_heads * c.head_dim), w["o"])
     x = cs(_res_scale(w, "attn", x, out), stream)
-    b = _rmsnorm(x, w["ln2_scale"], c.norm_eps)
+    b = rmsnorm(x, w["ln2_scale"], c.norm_eps)
     y, chosen, r = mlp_top1_routed(w, b, r, held=c.held, norm_eps=c.norm_eps)
     return cs(_res_scale(w, "moe", x, y), stream), cs(r, stream), chosen
 
@@ -286,8 +275,8 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: CCAMoELMConfig, mesh: O
     and the chosen expert ids ``(L, B S, 1)``. ``cparams`` is
     ``compute_params``' tree. The scan carries the stream and the router's."""
     B, S = tokens.shape
-    cs = _constrainer(mesh)
-    _, attend = _attention_route(cfg, mesh, B, S)
+    cs = constrainer(mesh)
+    _, attend = kit.attention_route(cfg.n_heads, mesh, B, S)
 
     @jax.checkpoint
     def scanned(carry, w):
@@ -297,7 +286,7 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: CCAMoELMConfig, mesh: O
     x = cs(cparams["embed"][tokens].astype(jnp.float32), P("data", None, None))
     r = jnp.zeros((B, S, cfg.router_dim), jnp.float32)
     (x, _), chosen = jax.lax.scan(scanned, (x, r), cparams["layers"])
-    return _rmsnorm(x, cparams["ln_f_scale"], cfg.norm_eps), chosen
+    return rmsnorm(x, cparams["ln_f_scale"], cfg.norm_eps), chosen
 
 
 def _head(h: jax.Array, embed: jax.Array, cfg: CCAMoELMConfig, cs) -> jax.Array:
@@ -312,7 +301,7 @@ def _head(h: jax.Array, embed: jax.Array, cfg: CCAMoELMConfig, cs) -> jax.Array:
 def forward(params: Params, tokens: jax.Array, cfg: CCAMoELMConfig, mesh: Optional[Mesh] = None) -> jax.Array:
     """(B, S) int32 tokens -> (B, S, vocab) float32 logits."""
     p = compute_params(params, cfg)
-    return _head(_run_layers(p, tokens, cfg, mesh)[0], p["embed"], cfg, _constrainer(mesh))
+    return _head(_run_layers(p, tokens, cfg, mesh)[0], p["embed"], cfg, constrainer(mesh))
 
 
 def chosen_experts(params: Params, tokens: jax.Array, cfg: CCAMoELMConfig) -> jax.Array:
@@ -340,30 +329,13 @@ def routing_stats(params: Params, tokens: jax.Array, cfg: CCAMoELMConfig) -> Dic
 
 
 def _cross_entropy(h: jax.Array, embed: jax.Array, targets: jax.Array, cfg: CCAMoELMConfig, cs) -> jax.Array:
-    """Mean next-token cross-entropy of the closed hidden state ``h (B, S,
-    D)``, head and loss in blocks of ``head_block`` positions of each
-    sequence, each block recomputed in the backward pass: one block's
-    ``(B, head_block, vocab)`` logits alive at a time, and the embedding's
-    gradient summed over the blocks in float32."""
-    B, S, D = h.shape
-    block = math.gcd(S, cfg.head_block)
-
-    @jax.checkpoint
-    def block_ce(embed, h, targets):
-        logits = _head(h, embed, cfg, cs)
-        at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - at_target)
-
-    if block == S:
-        return block_ce(embed, h, targets) / (B * S)
-    blocks = lambda t: jnp.moveaxis(t.reshape(B, S // block, block, *t.shape[2:]), 1, 0)  # noqa: E731
-    sums = jax.lax.map(lambda args: block_ce(embed, *args), (blocks(h), blocks(targets)))
-    return jnp.sum(sums) / (B * S)
+    """``kit.blocked_cross_entropy`` against the tied embedding."""
+    return kit.blocked_cross_entropy(h, embed, targets, cfg.head_block, lambda h, e: _head(h, e, cfg, cs))
 
 
 def _objective(cparams: Params, batch: Dict[str, jax.Array], cfg: CCAMoELMConfig, mesh: Optional[Mesh]):
     h, _ = _run_layers(cparams, batch["tokens"], cfg, mesh)
-    return _cross_entropy(h, cparams["embed"], batch["targets"], cfg, _constrainer(mesh))
+    return _cross_entropy(h, cparams["embed"], batch["targets"], cfg, constrainer(mesh))
 
 
 def loss_fn(
@@ -375,12 +347,8 @@ def loss_fn(
 
 
 def state_specs(cfg: CCAMoELMConfig, state: Dict[str, Any]) -> Dict[str, Any]:
-    """PartitionSpec pytree matching ``init_state``'s output: adam moments
-    inherit their parameter's spec, the scalars replicated."""
-    from ..parallel.mesh import optax_state_specs
-
-    p_specs = param_specs(cfg)
-    return {"params": p_specs, "opt_state": optax_state_specs(p_specs, state["opt_state"]), "step": P()}
+    """PartitionSpec pytree matching ``init_state``'s output (``kit.state_specs``)."""
+    return kit.state_specs(param_specs(cfg), state)
 
 
 def init_state(
@@ -388,45 +356,21 @@ def init_state(
 ) -> Dict[str, Any]:
     """{params, opt_state, step}, the whole of it placed per ``state_specs``
     under a mesh."""
-    params = init_params(rng, cfg)
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        params = shard_pytree(params, param_specs(cfg), mesh)
-    state = {"params": params, "opt_state": tx.init(params), "step": jnp.zeros((), jnp.int32)}
-    if mesh is not None:
-        state = shard_pytree(state, state_specs(cfg, state), mesh)
-    return state
+    return kit.init_state(init_params(rng, cfg), param_specs(cfg), tx, mesh)
 
 
 def make_train_step(
     cfg: CCAMoELMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Callable:
-    """Returns train_step(state, batch) -> (state, loss), ready to jit.
-    Under a mesh the returned state is pinned to ``state_specs``."""
-    # What is about to be compiled, on the bus for `stats -v` and the exporters.
-    telemetry.gauge_set("cca_moe_lm.layers", cfg.n_layers)
-    telemetry.gauge_set("cca_moe_lm.experts_held", len(cfg.held))
-    telemetry.gauge_set("cca_moe_lm.matmul_params_per_token", cfg.matmul_params_per_token)
-
-    def train_step(state, batch):
-        # Gradients are taken with respect to the tree the layers read, so
-        # the matrices' come in the compute dtype (looped_lm.py says why);
-        # adamw's moments and update are float32.
-        params = state["params"]
-        loss, grads = jax.value_and_grad(_objective)(compute_params(params, cfg), batch, cfg, mesh)
-        updates, opt_state = tx.update(grads, state["opt_state"], params)
-        new_state = {
-            "params": optax.apply_updates(params, updates),
-            "opt_state": opt_state,
-            "step": state["step"] + 1,
-        }
-        if mesh is not None:
-            new_state = jax.tree_util.tree_map(
-                lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec)),
-                new_state,
-                state_specs(cfg, new_state),
-            )
-        return new_state, loss
-
-    return train_step
+    """Returns train_step(state, batch) -> (state, loss), ready to jit
+    (``kit.make_train_step``). Every leaf takes adamw's update, the selection
+    bias too."""
+    gauges = {
+        "cca_moe_lm.layers": cfg.n_layers,
+        "cca_moe_lm.experts_held": len(cfg.held),
+        "cca_moe_lm.matmul_params_per_token": cfg.matmul_params_per_token,
+    }
+    return kit.make_train_step(
+        _objective, lambda p: compute_params(p, cfg), lambda state, batch: (batch, cfg, mesh), tx, mesh,
+        param_specs(cfg), gauges,
+    )

@@ -69,16 +69,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import telemetry
-from ..ops.attention import causal_attention_route
 from ..ops.moe import held_tile_stats, relu2_ffn, sigmoid_topk_routed
 from ..ops.ssm import mamba2_chunked
-from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
+from . import kit
+from .kit import causal_conv, constrainer, make_optimizer, mm, rmsnorm  # noqa: F401  (make_optimizer: the same optimizer)
 
 Params = Dict[str, Any]
-_ATTN_BLOCK = 512  # the tiling every route targets, as TransformerConfig's default
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 NEMOTRON_H_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
@@ -230,9 +228,7 @@ def init_params(rng: jax.Array, cfg: HybridLMConfig) -> Params:
 def param_specs(cfg: HybridLMConfig) -> Params:
     """PartitionSpecs on a ('data','model') mesh: ``embed`` and ``head`` over
     the vocabulary, every layer leaf replicated (module docstring)."""
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    specs = jax.tree_util.tree_map(lambda x: P(*([None] * x.ndim)), shapes)
-    return {**specs, "embed": P("model", None), "head": P("model", None)}
+    return kit.vocab_sharded(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
 
 
 # Leaves that feed a matmul in the compute dtype. The router's product is
@@ -244,30 +240,7 @@ def compute_params(params: Params, cfg: HybridLMConfig) -> Params:
     """The tree the layers read: every matrix that feeds a matmul (and the
     head) in the compute dtype, cast once a step; the embedding, the
     scales, the convolution, the per-head scalars and the router as stored."""
-    layers = {
-        name: {k: v.astype(cfg.dtype) if k in _MATRICES else v for k, v in w.items()}
-        for name, w in params["layers"].items()
-    }
-    return {**params, "layers": layers, "head": params["head"].astype(cfg.dtype)}
-
-
-def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """Statistics, scaling and result in float32."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
-
-
-def _mm(x, w):
-    return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise causal convolution along axis 1: ``y_t = sum_j w[j]
-    x_{t-(K-1)+j} + b`` with zeros before the sequence. float32."""
-    K, S = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return sum(padded[:, j:j + S] * w[j].astype(jnp.float32) for j in range(K)) + b.astype(jnp.float32)
+    return kit.cast_matrices(params, cfg.dtype, _MATRICES)
 
 
 def _group_rms(y: jax.Array, groups: int, eps: float) -> jax.Array:
@@ -292,9 +265,9 @@ def mamba_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig) -> jax.Array:
     with jax.named_scope("mamba2"):
         # The leaf cut by columns, not the product (module docstring): z | x | B C | dt.
         inner = c.mamba_inner
-        z, x, bc, dt = (_mm(a, cols) for cols in jnp.split(w["in_proj"], [inner, 2 * inner, inner + c.conv_width], axis=1))
-        u = jax.nn.silu(_causal_conv(x, w["conv_w"][:, :inner], w["conv_b"][:inner])).reshape(B, S, H, Pd)
-        b, cmat = jnp.split(jax.nn.silu(_causal_conv(bc, w["conv_w"][:, inner:], w["conv_b"][inner:])), 2, axis=-1)
+        z, x, bc, dt = (mm(a, cols) for cols in jnp.split(w["in_proj"], [inner, 2 * inner, inner + c.conv_width], axis=1))
+        u = jax.nn.silu(causal_conv(x, w["conv_w"][:, :inner], w["conv_b"][:inner])).reshape(B, S, H, Pd)
+        b, cmat = jnp.split(jax.nn.silu(causal_conv(bc, w["conv_w"][:, inner:], w["conv_b"][inner:])), 2, axis=-1)
         delta = jax.nn.softplus(dt + w["dt_bias"].astype(f32))  # time_step_limit (0, inf) clamps nothing
         decay = -jnp.exp(w["A_log"].astype(f32))
         y = mamba2_chunked(
@@ -304,7 +277,7 @@ def mamba_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig) -> jax.Array:
         )
         y = (y + w["D"].astype(f32)[:, None] * u).reshape(B, S, inner) * jax.nn.silu(z)
         y = _group_rms(y, G, c.norm_eps)  # the norm's statistics are a group's
-        return _mm(y * w["gnorm_scale"].astype(f32), w["out_proj"])
+        return mm(y * w["gnorm_scale"].astype(f32), w["out_proj"])
 
 
 def moe_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig) -> Tuple[jax.Array, jax.Array]:
@@ -321,34 +294,23 @@ def attention_mixer(w: Params, a: jax.Array, cfg: HybridLMConfig, attend: Callab
     c = cfg
     B, S, _ = a.shape
     with jax.named_scope("gqa"):
-        q = _mm(a, w["q"]).astype(c.dtype).reshape(B, S, c.n_heads, c.head_dim)
-        k = _mm(a, w["k"]).astype(c.dtype).reshape(B, S, c.n_kv_heads, c.head_dim)
-        v = _mm(a, w["v"]).astype(c.dtype).reshape(B, S, c.n_kv_heads, c.head_dim)
-        return _mm(attend(q, k, v).reshape(B, S, c.n_heads * c.head_dim), w["o"])
-
-
-def _attention_route(cfg: HybridLMConfig, mesh: Optional[Mesh], B: int, S: int):
-    """The shared dispatch's "auto": what the backend, the mesh and S allow."""
-    return causal_attention_route("auto", _ATTN_BLOCK, cfg.n_heads, mesh, B, S)
+        q = mm(a, w["q"]).astype(c.dtype).reshape(B, S, c.n_heads, c.head_dim)
+        k = mm(a, w["k"]).astype(c.dtype).reshape(B, S, c.n_kv_heads, c.head_dim)
+        v = mm(a, w["v"]).astype(c.dtype).reshape(B, S, c.n_kv_heads, c.head_dim)
+        return mm(attend(q, k, v).reshape(B, S, c.n_heads * c.head_dim), w["o"])
 
 
 def select_attention(cfg: HybridLMConfig, mesh: Optional[Mesh], B: int, S: int) -> str:
     """The name of the attention route the ``*`` layers run for this mesh and shape."""
-    return _attention_route(cfg, mesh, B, S)[0]
-
-
-def _constrainer(mesh: Optional[Mesh]):
-    if mesh is None:
-        return lambda x, spec: x
-    return lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return kit.route_name(cfg.n_heads, mesh, B, S)
 
 
 def _run_layers(cparams: Params, tokens: jax.Array, cfg: HybridLMConfig, mesh: Optional[Mesh]):
     """The closed hidden state ``rms(x; w_f)`` in float32 and, per ``E``
     layer, the chosen expert ids. ``cparams`` is ``compute_params``' tree."""
     B, S = tokens.shape
-    cs = _constrainer(mesh)
-    _, attend = _attention_route(cfg, mesh, B, S)
+    cs = constrainer(mesh)
+    _, attend = kit.attention_route(cfg.n_heads, mesh, B, S)
     chosen = []
     x = cs(cparams["embed"][tokens].astype(jnp.float32), P("data", None, None))
     for i, (name, kind) in enumerate(zip(cfg.layer_names, cfg.kinds)):
@@ -356,7 +318,7 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: HybridLMConfig, mesh: O
         @jax.checkpoint
         def layer(x, w, kind=kind, i=i):
             with jax.named_scope(f"layer{i}"):
-                a = _rmsnorm(x, w["norm_scale"], cfg.norm_eps)
+                a = rmsnorm(x, w["norm_scale"], cfg.norm_eps)
                 if kind == "M":
                     return x + mamba_mixer(w, a, cfg), None
                 if kind == "E":
@@ -368,14 +330,7 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: HybridLMConfig, mesh: O
         x = cs(x, P("data", None, None))
         if ids is not None:
             chosen.append(ids)
-    return _rmsnorm(x, cparams["ln_f_scale"], cfg.norm_eps), chosen
-
-
-def _head(h: jax.Array, head: jax.Array, cs) -> jax.Array:
-    """(B, S, vocab) float32 logits."""
-    with jax.named_scope("lm_head"):
-        logits = jnp.matmul(h.astype(head.dtype), head.T, preferred_element_type=jnp.float32)
-        return cs(logits, P("data", None, "model"))
+    return rmsnorm(x, cparams["ln_f_scale"], cfg.norm_eps), chosen
 
 
 def forward(
@@ -383,7 +338,7 @@ def forward(
 ) -> jax.Array:
     """(B, S) int32 -> (B, S, vocab) float32 logits."""
     p = compute_params(params, cfg)
-    return _head(_run_layers(p, tokens, cfg, mesh)[0], p["head"], _constrainer(mesh))
+    return kit.head(_run_layers(p, tokens, cfg, mesh)[0], p["head"], constrainer(mesh))
 
 
 def chosen_experts(params: Params, tokens: jax.Array, cfg: HybridLMConfig) -> Dict[str, jax.Array]:
@@ -414,12 +369,12 @@ def routing_stats(params: Params, tokens: jax.Array, cfg: HybridLMConfig) -> Dic
 
 
 def _objective(cparams: Params, batch: Dict[str, jax.Array], cfg: HybridLMConfig, mesh: Optional[Mesh]):
-    cs = _constrainer(mesh)
+    cs = constrainer(mesh)
     targets = batch["targets"]
 
     @jax.checkpoint
     def head_and_ce(h, head):
-        logits = _head(h, head, cs)
+        logits = kit.head(h, head, cs)
         at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - at_target)
 
@@ -439,74 +394,31 @@ def loss_fn(
 
 
 def state_specs(cfg: HybridLMConfig, state: Dict[str, Any]) -> Dict[str, Any]:
-    """PartitionSpec pytree matching ``init_state``'s output: adam moments
-    inherit their parameter's spec, scalars replicated."""
-    from ..parallel.mesh import optax_state_specs
-
-    p_specs = param_specs(cfg)
-    return {
-        "params": p_specs,
-        "opt_state": optax_state_specs(p_specs, state["opt_state"]),
-        "step": P(),
-    }
+    """PartitionSpec pytree matching ``init_state``'s output (``kit.state_specs``)."""
+    return kit.state_specs(param_specs(cfg), state)
 
 
 def init_state(
-    rng: jax.Array,
-    cfg: HybridLMConfig,
-    tx: optax.GradientTransformation,
-    *,
-    mesh: Optional[Mesh] = None,
+    rng: jax.Array, cfg: HybridLMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Dict[str, Any]:
-    """{params, opt_state, step}, the whole of it placed per
-    ``state_specs`` under a mesh (scalars too: see
-    ``transformer.init_state``)."""
-    params = init_params(rng, cfg)
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        params = shard_pytree(params, param_specs(cfg), mesh)
-    state = {"params": params, "opt_state": tx.init(params), "step": jnp.zeros((), jnp.int32)}
-    if mesh is not None:
-        state = shard_pytree(state, state_specs(cfg, state), mesh)
-    return state
+    """{params, opt_state, step}, the whole of it placed per ``state_specs``
+    under a mesh."""
+    return kit.init_state(init_params(rng, cfg), param_specs(cfg), tx, mesh)
 
 
 def make_train_step(
-    cfg: HybridLMConfig,
-    tx: optax.GradientTransformation,
-    *,
-    mesh: Optional[Mesh] = None,
+    cfg: HybridLMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Callable:
-    """Returns train_step(state, batch) -> (state, loss), ready to jit.
-    Under a mesh the returned state is pinned to ``state_specs`` (see
-    ``transformer.make_train_step``)."""
-    # What is about to be compiled, on the bus for `stats -v` and the exporters.
-    telemetry.gauge_set("hybrid_lm.layers", cfg.n_layers)
-    telemetry.gauge_set("hybrid_lm.experts_held", len(cfg.held))
-    telemetry.gauge_set("hybrid_lm.matmul_params_per_token", cfg.matmul_params_per_token)
-
-    def train_step(state, batch):
-        # Gradients are taken with respect to the tree the layers read, so
-        # the matrices' come in the compute dtype (looped_lm.py says why);
-        # adamw's moments and update are float32.
-        params = state["params"]
-        loss, grads = jax.value_and_grad(_objective)(compute_params(params, cfg), batch, cfg, mesh)
-        updates, opt_state = tx.update(grads, state["opt_state"], params)
-        new_params = optax.apply_updates(params, updates)
-        # The selection bias is held fixed: it takes no gradient, its
-        # balancing update has no rate in the config, and adamw's weight
-        # decay would otherwise shrink it.
-        for name, w in params["layers"].items():
-            if "router_bias" in w:
-                new_params["layers"][name]["router_bias"] = w["router_bias"]
-        new_state = {"params": new_params, "opt_state": opt_state, "step": state["step"] + 1}
-        if mesh is not None:
-            new_state = jax.tree_util.tree_map(
-                lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec)),
-                new_state,
-                state_specs(cfg, new_state),
-            )
-        return new_state, loss
-
-    return train_step
+    """Returns train_step(state, batch) -> (state, loss), ready to jit
+    (``kit.make_train_step``). The selection bias is held fixed: it takes no
+    gradient, its balancing update has no rate in the config, and adamw's
+    weight decay would otherwise shrink it."""
+    gauges = {
+        "hybrid_lm.layers": cfg.n_layers,
+        "hybrid_lm.experts_held": len(cfg.held),
+        "hybrid_lm.matmul_params_per_token": cfg.matmul_params_per_token,
+    }
+    return kit.make_train_step(
+        _objective, lambda p: compute_params(p, cfg), lambda state, batch: (batch, cfg, mesh), tx, mesh,
+        param_specs(cfg), gauges, frozen=("router_bias",),
+    )

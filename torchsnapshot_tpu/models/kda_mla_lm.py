@@ -76,18 +76,15 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import telemetry
-from ..ops.attention import causal_attention_route
 from ..ops import kda as kda_ops
 from ..ops.moe import clamped_gated_ffn, gated_ffn, group_limited, held_tile_stats, sigmoid_group_routed
-from .block_diffusion_lm import _constrainer, _mm, _rmsnorm  # the same float32 norm, matmul and constraint
-from .hybrid_lm import _causal_conv  # the same depthwise causal convolution, here with no bias
-from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
+from . import kit
+from .kit import causal_conv, constrainer, make_optimizer, mm, rmsnorm  # noqa: F401  (make_optimizer: the same optimizer)
 
 Params = Dict[str, Any]
-_ATTN_BLOCK = 512  # the tiling every route targets, as TransformerConfig's default
 L2_FLOOR = 1e-6  # under the L2 norm of a head's q and k (flash-linear-attention's l2norm eps, assumed)
 
 
@@ -289,9 +286,7 @@ def init_params(rng: jax.Array, cfg: KDAMLALMConfig) -> Params:
 def param_specs(cfg: KDAMLALMConfig) -> Params:
     """PartitionSpecs on a ('data','model') mesh: ``embed`` and ``head`` over
     the vocabulary, every layer leaf replicated (module docstring)."""
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    specs = jax.tree_util.tree_map(lambda x: P(*([None] * x.ndim)), shapes)
-    return {**specs, "embed": P("model", None), "head": P("model", None)}
+    return kit.vocab_sharded(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
 
 
 # Leaves that feed a matmul in the compute dtype. The router's product is
@@ -304,11 +299,7 @@ def compute_params(params: Params, cfg: KDAMLALMConfig) -> Params:
     """The tree the layers read: every matrix that feeds a matmul (and the
     head) in the compute dtype, cast once a step; the embedding, the
     scales, the convolutions, the per-head scalars and the router as stored."""
-    layers = {
-        kind: {k: v.astype(cfg.dtype) if k in _MATRICES else v for k, v in w.items()}
-        for kind, w in params["layers"].items()
-    }
-    return {**params, "layers": layers, "head": params["head"].astype(cfg.dtype)}
+    return kit.cast_matrices(params, cfg.dtype, _MATRICES)
 
 
 def _unit(x: jax.Array) -> jax.Array:
@@ -346,7 +337,7 @@ def kda_mixer(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
     o = jax.lax.map(jax.checkpoint(lambda wb: _kda_heads({**wb, "o_norm": w["o_norm"]}, a, c)), blocks)
     o = checkpoint_name(jnp.moveaxis(o, 0, 2).reshape(B, S, H * c.head_dim).astype(c.dtype), "kda_heads")
     with jax.named_scope("kda_out"):
-        return _mm(o, w["o"])
+        return mm(o, w["o"])
 
 
 def _kda_heads(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
@@ -356,17 +347,17 @@ def _kda_heads(w: Params, a: jax.Array, cfg: KDAMLALMConfig) -> jax.Array:
     H, hd = w["b"].shape[-1], c.head_dim
     heads = lambda t: t.reshape(B, S, H, hd)  # noqa: E731
     with jax.named_scope("kda_proj"):
-        q, k, v = (_mm(a, w[n]) for n in ("q", "k", "v"))
+        q, k, v = (mm(a, w[n]) for n in ("q", "k", "v"))
     with jax.named_scope("kda_conv"):
-        q, k, v = (jax.nn.silu(_causal_conv(t, w[f"{n}_conv"], jnp.zeros((), f32))) for t, n in ((q, "q"), (k, "k"), (v, "v")))
+        q, k, v = (jax.nn.silu(causal_conv(t, w[f"{n}_conv"], jnp.zeros((), f32))) for t, n in ((q, "q"), (k, "k"), (v, "v")))
         q, k = _unit(heads(q)) * hd**-0.5, _unit(heads(k))
     with jax.named_scope("kda_gate"):
-        beta = jax.nn.sigmoid(_mm(a, w["b"]))  # (B, S, H)
+        beta = jax.nn.sigmoid(mm(a, w["b"]))  # (B, S, H)
         rate = jnp.repeat(jnp.exp(w["A_log"].astype(f32)), hd)  # A_h over the head's channels
-        g = c.kda_lower_bound * jax.nn.sigmoid(rate * (_mm(a, w["f"]) + w["dt_bias"].astype(f32)))
+        g = c.kda_lower_bound * jax.nn.sigmoid(rate * (mm(a, w["f"]) + w["dt_bias"].astype(f32)))
     o = kda_ops.kda(q, k, heads(v), heads(g), beta, chunk=c.chunk)
     with jax.named_scope("kda_out"):
-        return _rmsnorm(o, w["o_norm"], c.norm_eps).reshape(B, S, H * hd) * jax.nn.sigmoid(_mm(a, w["g"]))
+        return rmsnorm(o, w["o_norm"], c.norm_eps).reshape(B, S, H * hd) * jax.nn.sigmoid(mm(a, w["g"]))
 
 
 def _rope_tail(x: jax.Array, rot: int, theta: float) -> jax.Array:
@@ -388,22 +379,22 @@ def mla_mixer(w: Params, a: jax.Array, cfg: KDAMLALMConfig, attend: Callable) ->
     B, S, _ = a.shape
     H, R, nope, rope, dv = c.n_heads, c.kv_lora_rank, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
     with jax.named_scope("mla_proj"):
-        q = _mm(a, w["q"]).reshape(B, S, H, nope + rope)
-        latent = _rmsnorm(_mm(a, w["kv_a"][:, :R]), w["kv_a_norm"], c.norm_eps)  # the weight cut, not the product
-        k_r = _mm(a, w["kv_a"][:, R:])  # (B, S, rope): one for every head
+        q = mm(a, w["q"]).reshape(B, S, H, nope + rope)
+        latent = rmsnorm(mm(a, w["kv_a"][:, :R]), w["kv_a_norm"], c.norm_eps)  # the weight cut, not the product
+        k_r = mm(a, w["kv_a"][:, R:])  # (B, S, rope): one for every head
         kv_b = w["kv_b"].reshape(R, H, nope + dv)
         lat = latent.astype(c.dtype)
         k_nope = jnp.einsum("bsr,rhd->bshd", lat, kv_b[..., :nope], preferred_element_type=jnp.float32)
         v = jnp.einsum("bsr,rhd->bshd", lat, kv_b[..., nope:], preferred_element_type=jnp.float32)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_r[:, :, None], (B, S, H, rope))], axis=-1)
-        q = _rope_tail(_rmsnorm(q, w["q_norm"], c.norm_eps), rope, c.rope_theta)
-        k = _rope_tail(_rmsnorm(k, w["k_norm"], c.norm_eps), rope, c.rope_theta)
+        q = _rope_tail(rmsnorm(q, w["q_norm"], c.norm_eps), rope, c.rope_theta)
+        k = _rope_tail(rmsnorm(k, w["k_norm"], c.norm_eps), rope, c.rope_theta)
     with jax.named_scope("mla_attn"):
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, nope + rope - dv)))
         out = attend(q.astype(c.dtype), k.astype(c.dtype), v.astype(c.dtype))[..., :dv]
     with jax.named_scope("mla_gate"):
-        out = out.astype(jnp.float32) * jax.nn.sigmoid(_mm(a, w["gate"]))[..., None]
-        return _mm(out.reshape(B, S, H * dv), w["o"])
+        out = out.astype(jnp.float32) * jax.nn.sigmoid(mm(a, w["gate"]))[..., None]
+        return mm(out.reshape(B, S, H * dv), w["o"])
 
 
 def moe_mlp(w: Params, b: jax.Array, cfg: KDAMLALMConfig, limits: Tuple[float, float]):
@@ -435,9 +426,9 @@ def layer(w: Params, x: jax.Array, kind: str, cfg: KDAMLALMConfig, attend: Calla
     ``_held_groups_kept``)."""
     c = cfg
     mixer, mlp = kind.split("_")
-    a = _rmsnorm(x, w["ln1_scale"], c.norm_eps)
+    a = rmsnorm(x, w["ln1_scale"], c.norm_eps)
     x = x + (kda_mixer(w, a, c) if mixer == "kda" else mla_mixer(w, a, c, attend))
-    b = _rmsnorm(x, w["ln2_scale"], c.norm_eps)
+    b = rmsnorm(x, w["ln2_scale"], c.norm_eps)
     if mlp == "dense":
         with jax.named_scope("dense_mlp"):
             return x + gated_ffn(b, w["mlp_gate"], w["mlp_up"], w["mlp_down"]), None
@@ -445,13 +436,9 @@ def layer(w: Params, x: jax.Array, kind: str, cfg: KDAMLALMConfig, attend: Calla
     return x + y, ((ids, _held_groups_kept(w, b, c)) if stats else ids)
 
 
-def _attention_route(cfg: KDAMLALMConfig, mesh: Optional[Mesh], B: int, S: int):
-    return causal_attention_route("auto", _ATTN_BLOCK, cfg.n_heads, mesh, B, S)
-
-
 def select_attention(cfg: KDAMLALMConfig, mesh: Optional[Mesh], B: int, S: int) -> str:
     """The name of the attention route the MLA layers run for this mesh and shape."""
-    return _attention_route(cfg, mesh, B, S)[0]
+    return kit.route_name(cfg.n_heads, mesh, B, S)
 
 
 def _run_layers(cparams: Params, tokens: jax.Array, cfg: KDAMLALMConfig, mesh: Optional[Mesh], stats: bool = False):
@@ -459,9 +446,9 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: KDAMLALMConfig, mesh: O
     per expert layer in order, the chosen ids (with ``stats``, the ids and
     ``_held_groups_kept``). ``cparams`` is ``compute_params``' tree."""
     B, S = tokens.shape
-    cs = _constrainer(mesh)
+    cs = constrainer(mesh)
     stream = P("data", None, None)
-    _, attend = _attention_route(cfg, mesh, B, S)
+    _, attend = kit.attention_route(cfg.n_heads, mesh, B, S)
     x = cs(cparams["embed"][tokens].astype(jnp.float32), stream)
     chosen = []
     for kind, first, end, limits in cfg.runs:
@@ -479,20 +466,13 @@ def _run_layers(cparams: Params, tokens: jax.Array, cfg: KDAMLALMConfig, mesh: O
             x, ids = jax.lax.scan(one, x, jax.tree_util.tree_map(lambda t: t[first:end], stack))
         if ids is not None:
             chosen += [jax.tree_util.tree_map(lambda t: t[i], ids) for i in range(end - first)]
-    return _rmsnorm(x, cparams["ln_f_scale"], cfg.norm_eps), chosen
-
-
-def _head(h: jax.Array, head: jax.Array, cs) -> jax.Array:
-    """(B, positions, vocab) float32 logits."""
-    with jax.named_scope("lm_head"):
-        logits = jnp.matmul(h.astype(head.dtype), head.T, preferred_element_type=jnp.float32)
-        return cs(logits, P("data", None, "model"))
+    return rmsnorm(x, cparams["ln_f_scale"], cfg.norm_eps), chosen
 
 
 def forward(params: Params, tokens: jax.Array, cfg: KDAMLALMConfig, mesh: Optional[Mesh] = None) -> jax.Array:
     """(B, S) int32 tokens -> (B, S, vocab) float32 logits."""
     p = compute_params(params, cfg)
-    return _head(_run_layers(p, tokens, cfg, mesh)[0], p["head"], _constrainer(mesh))
+    return kit.head(_run_layers(p, tokens, cfg, mesh)[0], p["head"], constrainer(mesh))
 
 
 def chosen_experts(params: Params, tokens: jax.Array, cfg: KDAMLALMConfig) -> List[jax.Array]:
@@ -523,29 +503,10 @@ def routing_stats(params: Params, tokens: jax.Array, cfg: KDAMLALMConfig) -> Dic
     }
 
 
-def _cross_entropy(h: jax.Array, head: jax.Array, targets: jax.Array, cfg: KDAMLALMConfig, cs) -> jax.Array:
-    """Mean next-token cross-entropy of the closed hidden state ``h (B, S,
-    D)``, head and loss in blocks of ``head_block`` positions, each block
-    recomputed in the backward pass (``cca_moe_lm._cross_entropy``)."""
-    B, S, D = h.shape
-    block = math.gcd(S, cfg.head_block)
-
-    @jax.checkpoint
-    def block_ce(head, h, targets):
-        logits = _head(h, head, cs)
-        at_target = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - at_target)
-
-    if block == S:
-        return block_ce(head, h, targets) / (B * S)
-    blocks = lambda t: jnp.moveaxis(t.reshape(B, S // block, block, *t.shape[2:]), 1, 0)  # noqa: E731
-    sums = jax.lax.map(lambda args: block_ce(head, *args), (blocks(h), blocks(targets)))
-    return jnp.sum(sums) / (B * S)
-
-
 def _objective(cparams: Params, batch: Dict[str, jax.Array], cfg: KDAMLALMConfig, mesh: Optional[Mesh]):
     h, _ = _run_layers(cparams, batch["tokens"], cfg, mesh)
-    return _cross_entropy(h, cparams["head"], batch["targets"], cfg, _constrainer(mesh))
+    cs = constrainer(mesh)
+    return kit.blocked_cross_entropy(h, cparams["head"], batch["targets"], cfg.head_block, lambda h, w: kit.head(h, w, cs))
 
 
 def loss_fn(
@@ -557,12 +518,8 @@ def loss_fn(
 
 
 def state_specs(cfg: KDAMLALMConfig, state: Dict[str, Any]) -> Dict[str, Any]:
-    """PartitionSpec pytree matching ``init_state``'s output: adam moments
-    inherit their parameter's spec, the scalars replicated."""
-    from ..parallel.mesh import optax_state_specs
-
-    p_specs = param_specs(cfg)
-    return {"params": p_specs, "opt_state": optax_state_specs(p_specs, state["opt_state"]), "step": P()}
+    """PartitionSpec pytree matching ``init_state``'s output (``kit.state_specs``)."""
+    return kit.state_specs(param_specs(cfg), state)
 
 
 def init_state(
@@ -570,51 +527,29 @@ def init_state(
 ) -> Dict[str, Any]:
     """{params, opt_state, step}, the whole of it placed per ``state_specs``
     under a mesh."""
-    params = init_params(rng, cfg)
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        params = shard_pytree(params, param_specs(cfg), mesh)
-    state = {"params": params, "opt_state": tx.init(params), "step": jnp.zeros((), jnp.int32)}
-    if mesh is not None:
-        state = shard_pytree(state, state_specs(cfg, state), mesh)
-    return state
+    return kit.init_state(init_params(rng, cfg), param_specs(cfg), tx, mesh)
 
 
 def make_train_step(
     cfg: KDAMLALMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Callable:
-    """Returns train_step(state, batch) -> (state, loss), ready to jit.
-    Under a mesh the returned state is pinned to ``state_specs``."""
-    # What is about to be compiled, on the bus for `stats -v` and the exporters.
-    telemetry.gauge_set("kda_mla_lm.layers", len(cfg.layers))
-    telemetry.gauge_set("kda_mla_lm.experts_held", len(cfg.held))
-    telemetry.gauge_set("kda_mla_lm.matmul_params_per_token", cfg.matmul_params_per_token)
+    """Returns train_step(state, batch) -> (state, loss), ready to jit
+    (``kit.make_train_step``). The selection bias is held fixed: it takes no
+    gradient, its balancing update has no rate in the config, and adamw's
+    weight decay would otherwise shrink it."""
+    gauges = {
+        "kda_mla_lm.layers": len(cfg.layers),
+        "kda_mla_lm.experts_held": len(cfg.held),
+        "kda_mla_lm.matmul_params_per_token": cfg.matmul_params_per_token,
+    }
 
-    def train_step(state, batch):
-        # Gradients are taken with respect to the tree the layers read, so
-        # the matrices' come in the compute dtype (looped_lm.py says why);
-        # adamw's moments and update are float32.
-        params = state["params"]
+    def objective_args(state, batch):
         # Set as the step is traced, where the batch's shape is first known.
         telemetry.gauge_set("kda_mla_lm.saved_heads_bytes", cfg.saved_heads_bytes(*batch["tokens"].shape))
         telemetry.gauge_set("kda_mla_lm.kda_kernel_layers", cfg.kda_kernel_layers(batch["tokens"].shape[1]))
-        loss, grads = jax.value_and_grad(_objective)(compute_params(params, cfg), batch, cfg, mesh)
-        updates, opt_state = tx.update(grads, state["opt_state"], params)
-        new_params = optax.apply_updates(params, updates)
-        # The selection bias is held fixed: it takes no gradient, its
-        # balancing update has no rate in the config, and adamw's weight
-        # decay would otherwise shrink it.
-        for kind, w in params["layers"].items():
-            if "router_bias" in w:
-                new_params["layers"][kind]["router_bias"] = w["router_bias"]
-        new_state = {"params": new_params, "opt_state": opt_state, "step": state["step"] + 1}
-        if mesh is not None:
-            new_state = jax.tree_util.tree_map(
-                lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec)),
-                new_state,
-                state_specs(cfg, new_state),
-            )
-        return new_state, loss
+        return batch, cfg, mesh
 
-    return train_step
+    return kit.make_train_step(
+        _objective, lambda p: compute_params(p, cfg), objective_args, tx, mesh,
+        param_specs(cfg), gauges, frozen=("router_bias",),
+    )

@@ -40,14 +40,12 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import telemetry
-from ..ops.attention import causal_attention_route
-from .transformer import make_optimizer  # noqa: F401  (the same optimizer)
+from . import kit
+from .kit import constrainer, make_optimizer, rmsnorm  # noqa: F401  (make_optimizer: the same optimizer)
 
 Params = Dict[str, Any]
-_ATTN_BLOCK = 512  # the tiling every route targets, as TransformerConfig's default
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,13 +135,6 @@ def param_specs(cfg: LoopedLMConfig) -> Params:
     }
 
 
-def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """Statistics, scaling and result in float32."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
-
-
 def _rope(x: jax.Array, theta: float) -> jax.Array:
     """Rotary positions on (B, S, H, hd), half-split pairing (i, i + hd/2),
     angles, rotation and result in float32."""
@@ -166,20 +157,12 @@ def exit_distribution(gate_logits: jax.Array) -> jax.Array:
     return jnp.exp(log_p)
 
 
-def _attention_route(cfg: LoopedLMConfig, mesh: Optional[Mesh], B: int, S: int):
-    """The shared dispatch's "auto": what the backend, the mesh and S allow."""
-    return causal_attention_route("auto", _ATTN_BLOCK, cfg.n_heads, mesh, B, S)
-
-
 def select_attention(cfg: LoopedLMConfig, mesh: Optional[Mesh], B: int, S: int) -> str:
     """The name of the attention route the block runs for this mesh and shape."""
-    return _attention_route(cfg, mesh, B, S)[0]
+    return kit.route_name(cfg.n_heads, mesh, B, S)
 
 
-def _constrainer(mesh: Optional[Mesh]):
-    if mesh is None:
-        return lambda x, spec: x
-    return lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+_MATRICES = {"q", "k", "v", "o", "gate", "up", "down"}  # every layer leaf but the norms' scales
 
 
 def compute_params(params: Params, cfg: LoopedLMConfig) -> Params:
@@ -187,10 +170,7 @@ def compute_params(params: Params, cfg: LoopedLMConfig) -> Params:
     layers' seven stacks and the head) in the compute dtype, cast once a
     step and not once per application; the embedding, the norm scales and
     the gate stay as stored."""
-    layers = {
-        k: v if k.startswith("ln") else v.astype(cfg.dtype) for k, v in params["layers"].items()
-    }
-    return {**params, "layers": layers, "head": params["head"].astype(cfg.dtype)}
+    return kit.cast_matrices(params, cfg.dtype, _MATRICES)
 
 
 def _run_passes(
@@ -212,8 +192,8 @@ def _run_passes(
     the logits' range at the 99th percentile (CPU, widths 256 and 512)."""
     c = cfg
     B, S = tokens.shape
-    cs = _constrainer(mesh)
-    _, attend = _attention_route(c, mesh, B, S)
+    cs = constrainer(mesh)
+    _, attend = kit.attention_route(c.n_heads, mesh, B, S)
     residual = P("data", "model", None)  # sequence-sharded between sublayers (Megatron sp)
 
     def mm(x, w):
@@ -223,7 +203,7 @@ def _run_passes(
     def block(u, w):
         with jax.named_scope("block"):
             u = cs(u, residual)
-            a = cs(_rmsnorm(u, w["ln1_scale"], c.norm_eps).astype(c.dtype), P("data", None, None))
+            a = cs(rmsnorm(u, w["ln1_scale"], c.norm_eps).astype(c.dtype), P("data", None, None))
 
             def heads(t, rotate):
                 t = cs(t, P("data", None, "model")).reshape(B, S, c.n_heads, c.head_dim)
@@ -232,18 +212,18 @@ def _run_passes(
 
             attn = attend(heads(mm(a, w["q"]), True), heads(mm(a, w["k"]), True), heads(mm(a, w["v"]), False))
             attn = cs(attn.reshape(B, S, c.n_heads * c.head_dim), P("data", None, "model"))
-            u = u + _rmsnorm(cs(mm(attn, w["o"]), residual), w["ln2_scale"], c.norm_eps)
+            u = u + rmsnorm(cs(mm(attn, w["o"]), residual), w["ln2_scale"], c.norm_eps)
 
-            m = cs(_rmsnorm(u, w["ln3_scale"], c.norm_eps).astype(c.dtype), P("data", None, None))
+            m = cs(rmsnorm(u, w["ln3_scale"], c.norm_eps).astype(c.dtype), P("data", None, None))
             ff = (jax.nn.silu(mm(m, w["gate"])) * mm(m, w["up"])).astype(c.dtype)
             ff = cs(ff, P("data", None, "model"))
-            u = u + _rmsnorm(cs(mm(ff, w["down"]), residual), w["ln4_scale"], c.norm_eps)
+            u = u + rmsnorm(cs(mm(ff, w["down"]), residual), w["ln4_scale"], c.norm_eps)
             return u, None
 
     def one_pass(h, _):
         with jax.named_scope("pass"):
             u, _ = jax.lax.scan(block, h, cparams["layers"])
-            h = _rmsnorm(cs(u, P("data", None, None)), cparams["ln_f_scale"], c.norm_eps)
+            h = rmsnorm(cs(u, P("data", None, None)), cparams["ln_f_scale"], c.norm_eps)
             return h, per_pass(h)
 
     h0 = cs(cparams["embed"][tokens].astype(jnp.float32), residual)
@@ -269,7 +249,7 @@ def pass_outputs(
 ) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 -> every pass's logits (T, B, S, vocab) and gate logits
     (T, B, S), both float32."""
-    cs = _constrainer(mesh)
+    cs = constrainer(mesh)
     p = compute_params(params, cfg)
     return _run_passes(
         p, tokens, cfg, mesh,
@@ -287,7 +267,7 @@ def forward(
 
 
 def _objective(cparams: Params, batch: Dict[str, jax.Array], cfg: LoopedLMConfig, mesh: Optional[Mesh]):
-    cs = _constrainer(mesh)
+    cs = constrainer(mesh)
     targets = batch["targets"]
 
     @jax.checkpoint
@@ -318,74 +298,30 @@ def loss_fn(
 
 
 def state_specs(cfg: LoopedLMConfig, state: Dict[str, Any]) -> Dict[str, Any]:
-    """PartitionSpec pytree matching ``init_state``'s output: adam moments
-    inherit their parameter's spec, scalars replicated."""
-    from ..parallel.mesh import optax_state_specs
-
-    p_specs = param_specs(cfg)
-    return {
-        "params": p_specs,
-        "opt_state": optax_state_specs(p_specs, state["opt_state"]),
-        "step": P(),
-    }
+    """PartitionSpec pytree matching ``init_state``'s output (``kit.state_specs``)."""
+    return kit.state_specs(param_specs(cfg), state)
 
 
 def init_state(
-    rng: jax.Array,
-    cfg: LoopedLMConfig,
-    tx: optax.GradientTransformation,
-    *,
-    mesh: Optional[Mesh] = None,
+    rng: jax.Array, cfg: LoopedLMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Dict[str, Any]:
-    """{params, opt_state, step}, the whole of it placed per
-    ``state_specs`` under a mesh (scalars too: see
-    ``transformer.init_state``)."""
-    params = init_params(rng, cfg)
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        params = shard_pytree(params, param_specs(cfg), mesh)
-    state = {"params": params, "opt_state": tx.init(params), "step": jnp.zeros((), jnp.int32)}
-    if mesh is not None:
-        state = shard_pytree(state, state_specs(cfg, state), mesh)
-    return state
+    """{params, opt_state, step}, the whole of it placed per ``state_specs``
+    under a mesh."""
+    return kit.init_state(init_params(rng, cfg), param_specs(cfg), tx, mesh)
 
 
 def make_train_step(
-    cfg: LoopedLMConfig,
-    tx: optax.GradientTransformation,
-    *,
-    mesh: Optional[Mesh] = None,
+    cfg: LoopedLMConfig, tx: optax.GradientTransformation, *, mesh: Optional[Mesh] = None
 ) -> Callable:
-    """Returns train_step(state, batch) -> (state, loss), ready to jit.
-    Under a mesh the returned state is pinned to ``state_specs`` (see
-    ``transformer.make_train_step``)."""
-    # What is about to be compiled, on the bus for `stats -v` and the exporters.
-    telemetry.gauge_set("looped_lm.ut_steps", cfg.ut_steps)
-    telemetry.gauge_set("looped_lm.layer_applications", cfg.ut_steps * cfg.n_layers)
-    telemetry.gauge_set("looped_lm.matmul_params_per_token", cfg.matmul_params_per_token)
-
-    def train_step(state, batch):
-        # Gradients are taken with respect to the tree the passes read, so
-        # the matrices' come in the compute dtype, summed over the passes
-        # in it: float32 copies of them (and float32 accumulators in both
-        # backward scans) do not fit beside 12 B/param and the recompute
-        # working set. adamw's moments and update are float32.
-        loss, grads = jax.value_and_grad(_objective)(
-            compute_params(state["params"], cfg), batch, cfg, mesh
-        )
-        updates, opt_state = tx.update(grads, state["opt_state"], state["params"])
-        new_state = {
-            "params": optax.apply_updates(state["params"], updates),
-            "opt_state": opt_state,
-            "step": state["step"] + 1,
-        }
-        if mesh is not None:
-            new_state = jax.tree_util.tree_map(
-                lambda x, spec: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec)),
-                new_state,
-                state_specs(cfg, new_state),
-            )
-        return new_state, loss
-
-    return train_step
+    """Returns train_step(state, batch) -> (state, loss), ready to jit
+    (``kit.make_train_step``): the matrices' gradients come in the compute dtype,
+    summed over the passes in it."""
+    gauges = {
+        "looped_lm.ut_steps": cfg.ut_steps,
+        "looped_lm.layer_applications": cfg.ut_steps * cfg.n_layers,
+        "looped_lm.matmul_params_per_token": cfg.matmul_params_per_token,
+    }
+    return kit.make_train_step(
+        _objective, lambda p: compute_params(p, cfg), lambda state, batch: (batch, cfg, mesh), tx, mesh,
+        param_specs(cfg), gauges,
+    )

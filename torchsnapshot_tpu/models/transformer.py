@@ -37,6 +37,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import CP_ROUTES, causal_attention_route
 from ..parallel.mesh import data_replicas, with_replica_dim
+from . import kit
+from .kit import make_optimizer  # noqa: F401  (the zoo's optimizer)
 
 Params = Dict[str, Any]
 
@@ -330,10 +332,6 @@ def loss_fn(
     return -jnp.mean(ll) + cfg.moe_aux_weight * aux
 
 
-def make_optimizer(lr: float = 1e-3) -> optax.GradientTransformation:
-    return optax.adamw(lr, b1=0.9, b2=0.95, weight_decay=0.01)
-
-
 def make_train_step(
     cfg: TransformerConfig,
     tx: optax.GradientTransformation,
@@ -381,40 +379,11 @@ def init_state(
     *,
     mesh: Optional[Mesh] = None,
 ) -> Dict[str, Any]:
-    """Initialize {params, opt_state, step}; shard onto `mesh` if given.
-
-    The FULL state is placed per ``state_specs`` — including replicated
-    scalars (optimizer count, step). Leaving scalars uncommitted works for
-    the first jit call but breaks resume-after-restore: a restored scalar
-    comes back committed to its destination's sharding, and a
-    single-device scalar next to mesh-committed params is an invalid jit
-    input mix.
-    """
-    params = init_params(rng, cfg)
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        params = shard_pytree(params, param_specs(cfg), mesh)
-    opt_state = tx.init(params)
-    state = {
-        "params": params,
-        "opt_state": opt_state,
-        "step": jnp.zeros((), jnp.int32),
-    }
-    if mesh is not None:
-        from ..parallel.mesh import shard_pytree
-
-        state = shard_pytree(state, state_specs(cfg, state), mesh)
-    return state
+    """Initialize {params, opt_state, step}; the whole of it placed per
+    ``state_specs`` under a mesh, scalars too (``kit`` says why)."""
+    return kit.init_state(init_params(rng, cfg), param_specs(cfg), tx, mesh)
 
 
 def state_specs(cfg: TransformerConfig, state: Dict[str, Any]) -> Dict[str, Any]:
-    """PartitionSpec pytree matching init_state's output.
-
-    Adam moments inherit their param's spec; scalars replicated.
-    """
-    from ..parallel.mesh import optax_state_specs
-
-    p_specs = param_specs(cfg)
-    opt_spec = optax_state_specs(p_specs, state["opt_state"])
-    return {"params": p_specs, "opt_state": opt_spec, "step": P()}
+    """PartitionSpec pytree matching init_state's output (``kit.state_specs``)."""
+    return kit.state_specs(param_specs(cfg), state)
