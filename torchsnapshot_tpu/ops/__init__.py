@@ -23,7 +23,9 @@ state to snapshot:
 - selective-SSM sequence mixing via associative scan, with a
   sequence-parallel cross-chunk carry;
 - Kimi Delta Attention (``kda``): a gated delta rule with a decay a key
-  channel, position by position and in chunks in the WY form, float32.
+  channel, position by position and in chunks in the WY form, float32;
+- Gated DeltaNet (``gdn``): the delta rule with one unbounded decay a
+  head, in the same chunks, its decay matrix from each span's own sum.
 """
 
 from .attention import blockwise_attention, dense_attention

@@ -122,26 +122,45 @@ def _chunked(q, k, v, g, beta, C, sb):
         a = jnp.where(pos[:, None] > pos[None, :], beta * scores(k, k), 0.0)
         p = jnp.where(lower, scores(q, k), 0.0)
         decay = jnp.exp(gamma)
-        solve = lambda rhs: jax.lax.linalg.triangular_solve(  # noqa: E731
-            a, rhs, left_side=True, lower=True, unit_diagonal=True
-        )
-        u = solve(beta * v)  # (B, H, n, C, V)
-        w_k = solve(beta * k * decay)  # (B, H, n, C, K)
-        p1 = jnp.einsum("bhnrj,bhnjv->bhnrv", p, u, precision=HI)
-        p2 = q * decay - jnp.einsum("bhnrj,bhnjk->bhnrk", p, w_k, precision=HI)
+        u, w_k, p1, p2 = wy_solve(q, k, v, beta, decay, a, p)
         last = gamma[..., -1:, :]
         k_bar = k * jnp.exp(last - gamma)  # (B, H, n, C, K)
     with jax.named_scope("kda_inter"):
-
-        def step(s, xs):  # s: (B, H, K, V), the state at the chunk's start
-            u_c, w_c, k_c, d_c = xs
-            w = u_c - jnp.einsum("bhrk,bhkv->bhrv", w_c, s, precision=HI)
-            return d_c[..., 0, :, None] * s + jnp.einsum("bhrk,bhrv->bhkv", k_c, w, precision=HI), s
-
-        xs = tuple(jnp.moveaxis(t, 2, 0) for t in (u, w_k, k_bar, jnp.exp(last)))
-        _, starts = jax.lax.scan(step, jnp.zeros((B, H, K, V), F32), xs)
-        o = p1 + jnp.einsum("bhnrk,nbhkv->bhnrv", p2, starts, precision=HI)
+        o = chunk_scan(u, w_k, k_bar, jnp.exp(last), p1, p2)
     return jnp.moveaxis(o, 1, 3).reshape(B, S, H, V)
+
+
+def wy_solve(q, k, v, beta, decay, a, p):
+    """The WY / UT solve of a chunked delta rule, all chunks at once, on
+    ``(B, H, n, C, X)`` float32 chunks: ``decay`` is ``exp(gamma)`` (a key
+    channel's or, broadcast, a head's), ``a`` the strictly lower ``A``, ``p``
+    the lower ``P`` (the module docstring) -> ``U``, ``W_k`` and the two
+    factors of the output, ``P U`` and ``Q~ - P W_k``."""
+    solve = lambda rhs: jax.lax.linalg.triangular_solve(  # noqa: E731
+        a, rhs, left_side=True, lower=True, unit_diagonal=True
+    )
+    u = solve(beta * v)  # (B, H, n, C, V)
+    w_k = solve(beta * k * decay)  # (B, H, n, C, K)
+    p1 = jnp.einsum("bhnrj,bhnjv->bhnrv", p, u, precision=HI)
+    p2 = q * decay - jnp.einsum("bhnrj,bhnjk->bhnrk", p, w_k, precision=HI)
+    return u, w_k, p1, p2
+
+
+def chunk_scan(u, w_k, k_bar, d_last, p1, p2):
+    """The scan over chunks, carrying the state ``(B, H, K, V)`` from 0 and
+    nothing else: ``k_bar`` is ``K-``, ``d_last`` ``exp(gamma_C)`` ``(B, H,
+    n, 1, K or 1)`` -> the output ``(B, H, n, C, V)``, ``P U + (Q~ - P W_k)
+    S_0`` a chunk."""
+    B, H, _, _, K = w_k.shape
+
+    def step(s, xs):  # s: (B, H, K, V), the state at the chunk's start
+        u_c, w_c, k_c, d_c = xs
+        w = u_c - jnp.einsum("bhrk,bhkv->bhrv", w_c, s, precision=HI)
+        return d_c[..., 0, :, None] * s + jnp.einsum("bhrk,bhrv->bhkv", k_c, w, precision=HI), s
+
+    xs = tuple(jnp.moveaxis(t, 2, 0) for t in (u, w_k, k_bar, d_last))
+    _, starts = jax.lax.scan(step, jnp.zeros((B, H, K, u.shape[-1]), F32), xs)
+    return p1 + jnp.einsum("bhnrk,nbhkv->bhnrv", p2, starts, precision=HI)
 
 
 def kda_route(S: int, d_k: int, d_v: int, chunk: int) -> str:

@@ -823,3 +823,16 @@ def sigmoid_group_routed(
         ),
         held, (params["expert_gate"], params["expert_up"], params["expert_down"]), limit,
     )
+
+
+# ------------------------------- a shared expert scaled a token by a sigmoid gate
+
+
+def sigmoid_gated_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, w_scale: jax.Array) -> jax.Array:
+    """``sigmoid(x w_scale) * gated_ffn(x, w_gate, w_up, w_down)``: a shared
+    expert whose output each token scales by one gate of its own (``w_scale
+    (D, 1)``; Qwen3-Next's ``shared_expert_gate``, from memory of its
+    modelling code). The gate's product accumulates in float32, the gate and
+    the scaled output are float32."""
+    scale = jax.nn.sigmoid(jnp.matmul(x.astype(w_scale.dtype), w_scale, preferred_element_type=jnp.float32))
+    return scale * gated_ffn(x, w_gate, w_up, w_down)
